@@ -42,9 +42,8 @@ QUICK = os.environ.get("REPRO_BENCH_QUICK", "0") == "1"
 SWEEP_WORKERS = int(os.environ.get("REPRO_SWEEP_WORKERS", "1"))
 
 # replay backend for every UVM sweep cell (run.py --backend overrides):
-# "auto" = pallas multi-lane kernels only where they compile natively
-# (TPU, or REPRO_PALLAS_COMPILE=1 on other accelerators), the NumPy
-# engine everywhere else; cells record the backend that actually ran in
+# "auto" = pallas multi-lane kernels on a TPU, the NumPy engine
+# everywhere else; cells record the backend that actually ran in
 # their rows, so fallbacks stay visible in the emitted results.
 # Validated here so a typo fails at import, not mid-sweep after the
 # training suites already burned their wall-clock.

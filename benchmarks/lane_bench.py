@@ -10,17 +10,16 @@ Two measurements, both on the serve trace family:
   same lanes.  Every lane is cross-checked against the numpy backend on
   all replay counters — **any drift aborts the bench** (exit 1), the same
   contract as ``sim_throughput``.
-* **End-to-end serve-smoke sweep** — a fresh ``repro.uvm.sweep
-  --scenario serve-smoke --backend pallas`` subprocess with a throwaway
-  results dir, measured after one warmup run so the kernel-executable
-  cache (``REPRO_KERNEL_CACHE``) is hot: the steady-state wall time a CI
-  host pays per sweep, and the number the ≥1.5x PR-8 acceptance
-  criterion is recorded against.
+* **End-to-end serve-smoke sweep** — ``run_sweep`` over the
+  ``serve-smoke`` scenario on ``--backend pallas`` with a throwaway
+  results dir, in this process (one process holds the chip), measured
+  after one warmup sweep so every lane program is compiled: the
+  steady-state wall time of a sweep.
 
 CLI::
 
-    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.lane_bench
-    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.lane_bench \
+    PYTHONPATH=src python -m benchmarks.lane_bench
+    PYTHONPATH=src python -m benchmarks.lane_bench \
         --emit-json BENCH_lanes.json      # trajectory point
     ... --skip-e2e                        # micro rows only (fast)
 
@@ -33,9 +32,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import tempfile
 import time
 from typing import Dict, List, Optional
@@ -127,35 +123,27 @@ def family_rows() -> List[Dict]:
     return rows
 
 
-def _sweep_once(out_dir: str) -> float:
-    """One fresh serve-smoke sweep subprocess; returns wall seconds."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if env.get("PYTHONPATH") else "")
+def _sweep_once(out_dir: str) -> tuple:
+    """One serve-smoke sweep in this process; returns (seconds, rows)."""
+    from repro.uvm.scenarios import expand_scenario
+    from repro.uvm.sweep import run_sweep
+
+    cells = expand_scenario("serve-smoke", backend="pallas")
     t0 = time.perf_counter()
-    subprocess.run([sys.executable, "-m", "repro.uvm.sweep",
-                    "--scenario", "serve-smoke", "--backend", "pallas",
-                    "--out", out_dir],
-                   check=True, env=env, stdout=subprocess.DEVNULL)
-    return time.perf_counter() - t0
+    rows = run_sweep(cells, out_dir=out_dir)
+    return time.perf_counter() - t0, rows
 
 
 def e2e_row() -> Dict:
-    """Fresh-process serve-smoke wall time, warm kernel-executable cache.
+    """Serve-smoke wall time with every lane program compiled.
 
-    The warmup run both hides one-time costs this bench does not track
-    (filesystem cache, Python import compilation) and populates the
-    kernel-executable cache, so the timed run measures the steady state a
-    resumed/CI sweep actually pays."""
+    The warmup sweep hides one-time costs this bench does not track
+    (compilation, trace generation into a fresh cache), so the timed
+    sweep measures the steady state."""
     with tempfile.TemporaryDirectory(prefix="lane_bench_warm_") as d:
-        warmup_s = _sweep_once(d)
+        warmup_s, _ = _sweep_once(d)
     with tempfile.TemporaryDirectory(prefix="lane_bench_e2e_") as d:
-        seconds = _sweep_once(d)
-        with open(os.path.join(d, "results.json")) as f:
-            rows = json.load(f)["rows"]
+        seconds, rows = _sweep_once(d)
     if len(rows) != 24:
         raise SystemExit(f"lane_bench: serve-smoke produced {len(rows)} "
                          "rows, not 24")
@@ -179,7 +167,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--emit-json", default=None, metavar="PATH",
                     help="write the trajectory point (BENCH_lanes.json)")
     ap.add_argument("--skip-e2e", action="store_true",
-                    help="micro rows only; skip the subprocess sweeps")
+                    help="micro rows only; skip the end-to-end sweeps")
     args = ap.parse_args(argv)
 
     from repro.uvm.sweep import SWEEP_VERSION

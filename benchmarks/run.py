@@ -75,9 +75,8 @@ def main() -> None:
                     choices=["auto", "numpy", "pallas"],
                     help="replay backend for the UVM sweep suites "
                          "(pallas = multi-lane kernel batches; auto "
-                         "picks pallas only where the lanes compile "
-                         "natively — TPU, or REPRO_PALLAS_COMPILE=1 on "
-                         "other accelerators; every result row records "
+                         "picks pallas on a TPU and numpy elsewhere; "
+                         "every result row records "
                          "the backend that actually ran, so per-cell "
                          "fallbacks are visible)")
     ap.add_argument("--emit-json", default=None, metavar="PATH",

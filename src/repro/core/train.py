@@ -31,16 +31,12 @@ def _loss_fn(cfg, params, x, y):
     return nll
 
 
-def train_predictor(cfg: model_lib.PredictorConfig, data: SequenceDataset,
-                    *, steps: int = 400, batch_size: int = 128,
-                    lr: float = 3e-3, seed: int = 0,
-                    params=None, eval_topk: int = 10,
-                    log_every: int = 0) -> TrainResult:
-    key = jax.random.PRNGKey(seed)
-    if params is None:
-        params = model_lib.init_params(cfg, key)
+def make_train_step(cfg: model_lib.PredictorConfig, *, steps: int,
+                    lr: float = 3e-3):
+    """The optimizer and the jitted train step of :func:`train_predictor`:
+    ``step_fn(params, opt_state, x, y, step) -> (params, opt_state,
+    loss)``, with a warmup-cosine learning rate over ``steps``."""
     opt = AdamW(weight_decay=1e-4, clip_norm=1.0)
-    opt_state = opt.init(params)
     sched = linear_warmup_cosine(lr, warmup_steps=min(50, steps // 10 + 1),
                                  total_steps=steps)
 
@@ -50,6 +46,20 @@ def train_predictor(cfg: model_lib.PredictorConfig, data: SequenceDataset,
             lambda p: _loss_fn(cfg, p, x, y))(params)
         params, opt_state = opt.update(grads, params, opt_state, sched(step))
         return params, opt_state, loss
+
+    return opt, step_fn
+
+
+def train_predictor(cfg: model_lib.PredictorConfig, data: SequenceDataset,
+                    *, steps: int = 400, batch_size: int = 128,
+                    lr: float = 3e-3, seed: int = 0,
+                    params=None, eval_topk: int = 10,
+                    log_every: int = 0) -> TrainResult:
+    key = jax.random.PRNGKey(seed)
+    if params is None:
+        params = model_lib.init_params(cfg, key)
+    opt, step_fn = make_train_step(cfg, steps=steps, lr=lr)
+    opt_state = opt.init(params)
 
     t0 = time.time()
     it = batches(data.x_train, data.y_train, batch_size, seed=seed,

@@ -198,57 +198,23 @@ def opt_shardings(param_sharding: Any, params_shape: Any, mesh: Mesh,
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across jax versions.
-
-    jax >= 0.5 wants explicit ``axis_types`` (Auto) for the sharding-in-types
-    machinery; jax 0.4.x does not accept the keyword at all.
-    """
+    """``jax.make_mesh`` with explicit ``Auto`` axis types."""
     kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes),
-                **kwargs)
-        except TypeError:  # pragma: no cover - axis_types not accepted
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def mesh_context(mesh):
-    """``jax.sharding.set_mesh`` where available, else the plain ``with
-    mesh:`` physical-mesh context (jax 0.4.x)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def _context_mesh():
-    """Mesh of the current sharding context, across jax versions.
-
-    jax >= 0.5 exposes ``jax.sharding.get_abstract_mesh()``; on older
-    releases the ``with mesh:`` context lives in the thread-resources env.
-    Returns ``None`` when no mesh context is active (or none is detectable).
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            return get_abstract()
-        except Exception:  # pragma: no cover - defensive
-            pass
-    try:  # jax < 0.5: physical mesh from the `with mesh:` context
-        from jax._src import mesh as mesh_lib
-        return mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - defensive
-        return None
+    """The sharding context of ``mesh`` (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def constrain(x, *axes):
     """Activation sharding constraint by logical axes; no-op without a mesh
     context.  ``axes`` entries are mesh axis names, tuples, or None."""
-    mesh = _context_mesh()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
 

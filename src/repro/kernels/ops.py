@@ -18,10 +18,8 @@ from repro.kernels.int4_matmul import int4_matmul_pallas
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default shared by every Pallas entry point in the
-    repo — the kernels below and the UVM multi-lane replay backend
-    (``repro.uvm.backends.pallas_backend``): interpret everywhere except
-    on a real TPU backend, where kernels compile through Mosaic."""
+    """Interpret-mode default of the kernels below: interpret everywhere
+    except on a real TPU backend, where they compile through Mosaic."""
     return jax.default_backend() != "tpu"
 
 

@@ -51,8 +51,11 @@ the same API: ``PYTHONPATH=src python -m repro.uvm.sweep --help``.
 Learned cells are train-once: ``repro.uvm.predcache`` content-addresses the
 predictor's ``predict_trace`` arrays by (trace content, model config), so a
 (trace × prediction_us × device_frac) grid trains one model per trace and
-every variant — in-process, across ``--workers`` processes (atomic
+every variant — in-process, across concurrent sweeps (atomic
 write-rename + training lock), and across runs — reuses the cached array.
+All device work (lane batches, predictor training and prediction) runs
+in the process that calls ``run_sweep``, before any ``--workers``
+fan-out: one process holds the chip.
 """
 from repro.uvm.config import UVMConfig
 from repro.uvm.engine import VectorizedUVMSimulator, simulate

@@ -3,10 +3,19 @@
 Packs many compatible sweep cells into ONE lane-batched ``pl.pallas_call``:
 one lane per (trace, config) cell, traces padded to the longest lane, and
 per-lane residency/arrival/LRU-stamp state held as device arrays.  The
-kernel grid iterates over lanes, so on an accelerator every cell of a
-sweep batch replays concurrently; on CPU hosts the kernel runs in
-interpret mode (exact same jaxpr, executed through XLA-CPU), which is what
-CI exercises under ``JAX_PLATFORMS=cpu``.
+kernel grid iterates over lanes.
+
+Lowering
+--------
+On every platform the kernel body is lowered by XLA, not by Mosaic:
+``pallas_call(..., interpret=True)`` takes Pallas's discharge path, which
+turns the grid into an XLA loop over lanes and the refs into array
+slices.  On a TPU that is one compiled device program per batch shape;
+on a CPU host it is the same program through XLA:CPU, which is what the
+tests run under ``JAX_PLATFORMS=cpu``.  Mosaic refuses this kernel as
+written (the ``(1, t_max)`` lane blocks and the float64 timing state;
+see ``README.md``), and lowering it there waits on a 32-bit timing
+model.
 
 Packable cells and lane families
 --------------------------------
@@ -56,7 +65,7 @@ Exactness
 ---------
 Every float chain in the kernel replays the legacy loop's IEEE-754
 operation order in float64 (the lane functions are traced under
-``jax.experimental.enable_x64``), including a branch-free emulation of
+``jax.enable_x64``), including a branch-free emulation of
 CPython's float floor-division in the fault-service window computation
 and the sequential ``t += page_tx`` arrival chain of non-batch (oracle
 continuous) prefetches.  Integer counters are therefore exact and
@@ -68,23 +77,18 @@ independent NumPy replays, and ``tests/test_differential.py`` fuzzes all
 registered backend pairs.
 
 The per-lane state (arrival/stamp/pfu spans, tree counts) is carried
-through a ``lax.fori_loop`` over trace positions — the functional-carry
-form keeps the kernel identical between interpret mode and compiled
-execution.  A device-native Mosaic/Triton lowering would move the span
-state into scratch refs; the lane packing, parameter blocks, and stats
-layout here are already shaped for that (see ``README.md``).
+through a ``lax.fori_loop`` over trace positions.  A Mosaic lowering
+would move the span state into scratch refs; the lane packing,
+parameter blocks, and stats layout here are already shaped for that.
 """
 from __future__ import annotations
 
 import functools
-import hashlib
-import os
-import pickle
-import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import compile_cache
 from repro.traces.trace import BASIC_BLOCK_PAGES, ROOT_PAGES
 from repro.uvm.eviction import (EVICTION_POLICIES, SCORE_MULT_1,
                                 SCORE_MULT_2, SCORE_SEED_MULT,
@@ -93,7 +97,8 @@ from repro.uvm.prefetchers import (BlockPrefetcher, LearnedPrefetcher,
                                    NoPrefetcher, OraclePrefetcher,
                                    Prefetcher, TreePrefetcher)
 from repro.uvm.replay_core import (ReplayBackend, ReplayRequest,
-                                   cycles_per_access, dense_bounds)
+                                   cycles_per_access, dense_bounds,
+                                   device_held_by_parent, require_device)
 from repro.uvm.simulator import UVMStats, _tenant_accesses
 
 #: lane-family kind per exact prefetcher type — the single source of
@@ -196,7 +201,7 @@ def _bucket(n: int, floor: int) -> int:
 @functools.lru_cache(maxsize=None)
 def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                     span: int, buf_len: int, ft_len: int, lookahead: int,
-                    steps_len: int, mt: bool, interpret: bool):
+                    steps_len: int, mt: bool):
     """Build (and cache) the jitted multi-lane replay for one batch shape.
 
     ``family`` is the kernel kind (demand/tree/learned/oracle); ``ft_len``
@@ -871,117 +876,17 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((n_lanes, steps_len),
                                           jnp.float64)]
+    # interpret=True is Pallas's discharge lowering: an XLA program on
+    # every platform (see the module docstring, "Lowering")
     call = pl.pallas_call(
         kernel,
         grid=(n_lanes,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=True,
     )
     return jax.jit(call)
-
-
-#: executable-cache format version: bump when the serialized layout or
-#: the kernel calling convention changes incompatibly
-_KERNEL_CACHE_SCHEMA = 1
-
-
-def _kernel_cache_dir() -> Optional[str]:
-    """Directory of the on-disk lane-executable cache, or None when
-    disabled (``REPRO_KERNEL_CACHE=0``/``off``).  Defaults to a per-user
-    cache dir so every sweep process on a host shares warm kernels."""
-    env = os.environ.get("REPRO_KERNEL_CACHE")
-    if env is not None:
-        if env.strip().lower() in ("", "0", "off"):
-            return None
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "repro-lane-kernels")
-
-
-@functools.lru_cache(maxsize=1)
-def _kernel_src_tag() -> str:
-    """Hash of this module's source: kernel code changes must never be
-    served a stale executable, even without a schema bump."""
-    try:
-        with open(__file__, "rb") as fh:
-            return hashlib.sha256(fh.read()).hexdigest()[:16]
-    except OSError:  # pragma: no cover - frozen/zipped installs
-        return "unknown"
-
-
-def _kernel_cache_path(cache_dir: str, key: Tuple) -> str:
-    import jax
-    tag = hashlib.sha256(
-        repr((_KERNEL_CACHE_SCHEMA, jax.__version__, _kernel_src_tag(),
-              key)).encode()
-    ).hexdigest()[:32]
-    return os.path.join(cache_dir, f"lane_{key[0]}_{key[1]}_{tag}.jaxexec")
-
-
-@functools.lru_cache(maxsize=None)
-def _lane_replay_exec(family: str, policy: str, n_lanes: int, t_max: int,
-                      span: int, buf_len: int, ft_len: int, lookahead: int,
-                      steps_len: int, mt: bool, interpret: bool):
-    """Compiled lane executable for one batch shape, loaded from the
-    on-disk kernel cache when possible.
-
-    On CPU hosts the dominant cold-start cost of a sweep process is not
-    running the lane kernels but *building* them — pallas tracing, XLA
-    lowering, and compilation are a sizable fraction of an entire
-    serve-smoke sweep.  The first process to need a batch shape builds
-    it and serializes the compiled executable
-    (``jax.experimental.serialize_executable``) next to the trace cache;
-    every later process deserializes in milliseconds and skips straight
-    to execution.  Entries are keyed by the full kernel shape, the cache
-    schema, and the jax version; any load failure (stale jax, corrupt
-    file, foreign platform) silently falls back to a fresh build, and
-    writes go through the crash-safe tmp + ``os.replace`` idiom so a
-    killed sweep never publishes a torn executable.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    key = (family, policy, n_lanes, t_max, span, buf_len, ft_len,
-           lookahead, steps_len, mt, interpret)
-    cache_dir = _kernel_cache_dir()
-    path = _kernel_cache_path(cache_dir, key) if cache_dir else None
-    if path is not None and os.path.exists(path):
-        try:
-            from jax.experimental.serialize_executable import (
-                deserialize_and_load)
-            with open(path, "rb") as fh:
-                payload, in_tree, out_tree = pickle.load(fh)
-            return deserialize_and_load(payload, in_tree, out_tree)
-        except Exception:
-            pass                   # stale or torn entry: rebuild below
-    fn = _lane_replay_fn(*key)
-    i32 = jnp.dtype("int32")
-    arg_shapes = [jax.ShapeDtypeStruct((n_lanes, t_max), i32)]  # pages
-    if family == "learned":
-        arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, t_max), i32))
-    if family == "oracle":
-        arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, ft_len), i32))
-        arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, t_max), i32))
-    if steps_len:
-        arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, t_max), i32))
-    arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, _N_FPARAMS),
-                                           jnp.dtype("float64")))
-    arg_shapes.append(jax.ShapeDtypeStruct((n_lanes, _N_IPARAMS), i32))
-    compiled = fn.lower(*arg_shapes).compile()
-    if path is not None:
-        try:
-            from jax.experimental.serialize_executable import serialize
-            payload, in_tree, out_tree = serialize(compiled)
-            os.makedirs(cache_dir, exist_ok=True)
-            tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
-            with open(tmp, "wb") as fh:
-                pickle.dump((payload, in_tree, out_tree), fh)
-            os.replace(tmp, path)
-        except Exception:
-            pass                   # caching is best-effort, never fatal
-    return compiled
 
 
 def _lane_shape(request: ReplayRequest) -> Tuple[str, str, int, int]:
@@ -1000,24 +905,18 @@ def _lane_shape(request: ReplayRequest) -> Tuple[str, str, int, int]:
 
 class PallasReplayBackend(ReplayBackend):
     name = "pallas"
-    experimental = True   # runtime failures degrade down the chain
 
     def is_native(self) -> bool:
-        """Native only when jax is already up on an accelerator the lanes
-        actually *compile* for (the same :func:`_interpret_mode` policy:
-        TPU, or ``REPRO_PALLAS_COMPILE=1`` elsewhere): ``auto``
-        resolution must not drag jax into NumPy-only sweep workers, and
-        interpret-mode lanes lose to the NumPy engine on any host."""
-        import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
+        """True on a TPU, where the lanes are a compiled device program;
+        elsewhere the same program runs through XLA:CPU and loses to the
+        NumPy engine.  A sweep worker process is never native: its parent
+        holds the chip (:func:`repro.uvm.replay_core.require_device`), so
+        asking JAX here would initialise the accelerator a second time.
+        Backend initialisation errors propagate."""
+        if device_held_by_parent():
             return False
-        try:
-            if jax.default_backend() == "cpu":
-                return False
-        except Exception:  # pragma: no cover - uninitialized backends
-            return False
-        return not _interpret_mode()
+        import jax
+        return jax.default_backend() == "tpu"
 
     # ------------------------------------------------------------------
     def can_replay(self, request: ReplayRequest) -> bool:
@@ -1108,6 +1007,7 @@ class PallasReplayBackend(ReplayBackend):
 
     # ------------------------------------------------------------------
     def replay(self, requests: Sequence[ReplayRequest]) -> List[UVMStats]:
+        require_device("a pallas lane batch")
         for req in requests:
             if not self.can_replay(req):
                 raise ValueError(
@@ -1132,8 +1032,7 @@ class PallasReplayBackend(ReplayBackend):
     def _replay_batch(self, requests: Sequence[ReplayRequest]
                       ) -> List[UVMStats]:
         """Replay one family-homogeneous lane batch: pad, launch, unpack."""
-        import jax  # noqa: F401  (jax must import before enable_x64)
-        from jax.experimental import enable_x64
+        import jax
 
         families = {lane_family(r.prefetcher) for r in requests}
         assert len(families) == 1, \
@@ -1235,11 +1134,10 @@ class PallasReplayBackend(ReplayBackend):
                 sids_in[l, :n] = np.where(sid >= sb.size, steps_len,
                                           sid).astype(np.int32)
 
-        interpret = _interpret_mode()
-        with enable_x64():
-            fn = _lane_replay_exec(kind, policy, n_lanes, t_max, span,
-                                   buf_len, ft_len, lookahead, steps_len,
-                                   mt, interpret)
+        compile_cache.enable()
+        with jax.enable_x64(True):
+            fn = _lane_replay_fn(kind, policy, n_lanes, t_max, span,
+                                 buf_len, ft_len, lookahead, steps_len, mt)
             raw = fn(pages, *extra_in, fparams, iparams)
         if steps_len:
             raw, raw_steps = (np.asarray(raw[0]), np.asarray(raw[1]))
@@ -1298,12 +1196,3 @@ def _fill_step_clocks(bounds: np.ndarray, lane_steps: np.ndarray
     idx = np.maximum.accumulate(idx)
     return np.where(idx >= 0, vals[np.maximum(idx, 0)], 0.0)
 
-
-def _interpret_mode() -> bool:
-    """Shared repo policy (``repro.kernels.ops.default_interpret``):
-    interpret everywhere except on a real TPU.  ``REPRO_PALLAS_COMPILE=1``
-    forces native compilation for experiments on other accelerators."""
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
-    from repro.kernels.ops import default_interpret
-    return default_interpret()

@@ -2,7 +2,7 @@
 
 Production-scale sweep grids (the 660-cell ``oversub-full`` matrix and
 bigger) must survive killed workers, torn result files, corrupted cached
-artifacts, and flaky experimental backends — and *provably converge to
+artifacts, and transient backend faults — and *provably converge to
 bit-identical results* when they do.  This module is the injection side
 of that proof:
 

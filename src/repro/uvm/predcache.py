@@ -258,6 +258,10 @@ def get_or_train(trace, *, steps: int = 150, seed: int = 0,
                                 **(service_kwargs or {}))
 
     def _train() -> np.ndarray:
+        from repro import compile_cache
+        from repro.uvm.replay_core import require_device
+        require_device("predictor training")
+        compile_cache.enable()
         svc = _fresh_service()
         svc.fit(trace)
         preds = np.ascontiguousarray(svc.predict_trace(), dtype=np.int64)

@@ -81,12 +81,44 @@ class TransientBackendFault(RuntimeError):
     preemption, transient OOM, an injected chaos fault — see
     ``repro.uvm.faults``).
 
-    :func:`dispatch` and the sweep's lane scheduler re-raise these instead
-    of degrading down the fallback chain: degrading would permanently
-    record a different ``backend`` for the cell, so a retried sweep could
-    never converge byte-identically to a fault-free run.  The sweep's
+    Like every backend failure, :func:`dispatch` and the sweep's lane
+    scheduler raise these instead of degrading down the fallback chain:
+    degrading would permanently record a different ``backend`` for the
+    cell, so a retried sweep could never converge byte-identically to a
+    fault-free run.  The sweep's
     lease/retry layer (or a driver restart) retries the whole cell on the
     originally-resolved backend instead."""
+
+
+# ---------------------------------------------------------------------------
+# one process holds the accelerator
+# ---------------------------------------------------------------------------
+
+#: True in a sweep worker process (set by :func:`hand_device_to_parent`)
+_device_held_by_parent = False
+
+
+def hand_device_to_parent() -> None:
+    """Mark this process as a sweep worker whose parent holds the
+    accelerator: a chip belongs to one process at a time, so device work
+    here (:func:`require_device`) raises instead of initialising it."""
+    global _device_held_by_parent
+    _device_held_by_parent = True
+
+
+def device_held_by_parent() -> bool:
+    return _device_held_by_parent
+
+
+def require_device(what: str) -> None:
+    """Raise when ``what`` (device work) is asked of a sweep worker: the
+    sweep runs all device work in its parent before the fan-out, so a
+    worker that reaches here was handed a cell it must not run."""
+    if _device_held_by_parent:
+        raise RuntimeError(
+            f"{what} needs the accelerator, which the sweep's parent "
+            "process holds; this worker process may not initialise it "
+            "(run the cell in the parent, before the fan-out)")
 
 
 # ---------------------------------------------------------------------------
@@ -134,14 +166,6 @@ class ReplayBackend:
 
     name: str = "abstract"
 
-    #: experimental backends may fail at *runtime* on exotic platforms
-    #: (lowering errors, device OOM); :func:`dispatch` degrades their
-    #: runtime failures to the next backend of the chain with a warning.
-    #: Non-experimental backends' errors always propagate — a failure
-    #: there is a bug, and silently serving legacy results would let the
-    #: golden equivalence suite pass vacuously.
-    experimental: bool = False
-
     def can_replay(self, request: ReplayRequest) -> bool:
         raise NotImplementedError
 
@@ -186,10 +210,11 @@ def backend_chain(backend: str = "auto") -> List[str]:
 
     Every chain ends in ``legacy`` (which can replay anything), so
     dispatch always succeeds; the stats record which backend actually ran.
-    ``auto`` prefers the pallas lanes only where they compile natively
-    (TPU, or ``REPRO_PALLAS_COMPILE=1`` on other accelerators) — anywhere
-    the lanes would run in interpret mode, the NumPy engine is both exact
-    and faster.
+    ``auto`` prefers the pallas lanes only where they are a compiled
+    device program (a TPU); on a CPU host the NumPy engine is both exact
+    and faster.  The chain falls back per cell on what a backend declines
+    by contract (:meth:`~ReplayBackend.can_replay`), never on a runtime
+    failure.
     """
     if backend == "legacy":
         return ["legacy"]
@@ -217,34 +242,13 @@ def resolve_backend(request: ReplayRequest,
 
 
 def dispatch(request: ReplayRequest, backend: str = "auto") -> UVMStats:
-    """Replay one cell on the first capable backend of the chain.
+    """Replay one cell on the first backend of the chain that accepts it.
 
-    A *runtime* failure in an :attr:`~ReplayBackend.experimental`
-    non-final backend (e.g. a pallas lowering error on an exotic
-    platform) degrades to the next backend of the chain with a warning
-    instead of aborting the caller's whole grid — the stats still record
-    the backend that actually ran.  Runtime errors of non-experimental
-    backends (numpy, legacy) propagate: they indicate bugs, and silently
-    serving the fallback's results would make the golden equivalence
-    harness pass vacuously.
+    A runtime failure of that backend propagates, a
+    :class:`TransientBackendFault` included: replaying the cell on the
+    next backend instead would hide a device failure behind a host run.
     """
-    chain = [get_backend(name) for name in backend_chain(backend)]
-    capable = [b for b in chain if b.can_replay(request)]
-    for b in capable[:-1]:
-        if not b.experimental:
-            return b.replay([request])[0]
-        try:
-            return b.replay([request])[0]
-        except TransientBackendFault:
-            # retryable by contract: degrading would record a different
-            # backend for the cell, breaking chaos convergence — let the
-            # caller's retry layer re-run the cell on the same chain
-            raise
-        except Exception as e:
-            import warnings
-            warnings.warn(f"replay backend {b.name!r} failed at runtime "
-                          f"({e!r}); falling back", RuntimeWarning)
-    return capable[-1].replay([request])[0]
+    return resolve_backend(request, backend).replay([request])[0]
 
 
 # ---------------------------------------------------------------------------

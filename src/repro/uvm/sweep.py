@@ -40,11 +40,21 @@ lane per cell, padded to the longest trace; see
 ``repro.uvm.backends.pallas_backend``).  Everything unpackable falls back
 *per cell* down the ``pallas → numpy → legacy`` chain, and every result
 row records the backend that actually ran in its ``backend`` column, so
-fallbacks are visible instead of silently reading as covered.  ``auto`` resolves to the pallas lanes only when jax is
-already up on a platform the lanes compile natively for (TPU, or
-``REPRO_PALLAS_COMPILE=1`` on other accelerators); everywhere else —
-including CPU hosts, where the lanes would run in interpret mode — it is
-the NumPy engine.
+fallbacks are visible instead of silently reading as covered.  That
+per-cell fallback covers only what the lanes decline by contract (span
+or length caps, timelines); a lane batch that fails at runtime raises.
+``auto`` resolves to the pallas lanes on a TPU, where they are a
+compiled device program, and to the NumPy engine everywhere else.
+
+One process holds the chip
+--------------------------
+
+All device work — lane batches, and predictor training and prediction
+for ``learned`` cells — runs in the process that called
+:func:`run_sweep`, before any ``--workers`` fan-out.  Worker processes
+replay the remaining cells on the host and never initialise the
+accelerator: device work asked of one raises
+(``repro.uvm.replay_core.require_device``).
 
 Train-once learned cells
 ------------------------
@@ -60,13 +70,13 @@ every other learned cell of the grid reuses the cached array, in-process
 (memo) and across runs (content-addressed ``.npy`` files under
 ``<trace cache>/pred_cache/``, written with atomic rename).
 
-With ``--workers N`` the cache is shared through the filesystem: the first
-worker to miss a key takes a lockfile and trains; workers hitting the same
-key wait for the array instead of training again, and workers on different
-keys train in parallel — a (trace × prediction_us × device_frac) grid costs
-one training run per trace no matter how many variants ride on it or how
-the pool schedules them.  ``REPRO_PREDCACHE=0`` restores the
-retrain-per-cell behavior.
+Learned cells always run in the sweep's own process (see "One process
+holds the chip"); across concurrent sweeps the disk cache is shared
+through the filesystem: the first process to miss a key takes a lockfile
+and trains, others hitting the same key wait for the array instead of
+training again — a (trace × prediction_us × device_frac) grid costs one
+training run per trace no matter how many variants ride on it.
+``REPRO_PREDCACHE=0`` restores the retrain-per-cell behavior.
 
 A prebuilt predictions array can still be supplied per bench via
 :func:`simulate_cell`'s ``prefetcher`` override.
@@ -135,15 +145,13 @@ from repro.core.families import MODEL_FAMILIES  # jax-free config layer
 from repro.distributed import fault_tolerance as ft
 from repro.traces.trace import ACCESS_DTYPE, Trace
 from repro.uvm import adaptive, faults
-from repro.uvm.replay_core import TransientBackendFault
 from repro.uvm.config import UVMConfig
 from repro.uvm.engine import simulate
 from repro.uvm.eviction import EVICTION_POLICIES
 from repro.uvm.prefetchers import (BlockPrefetcher, LearnedPrefetcher,
                                    NoPrefetcher, OraclePrefetcher,
                                    Prefetcher, TreePrefetcher)
-from repro.uvm.replay_core import (ReplayRequest, backend_chain,
-                                   dispatch as replay_dispatch, get_backend)
+from repro.uvm.replay_core import ReplayRequest, backend_chain, get_backend
 from repro.uvm.simulator import UVMStats
 
 #: cell-spec prefetcher names to concrete types — the single source the
@@ -814,8 +822,11 @@ def _worker(args) -> Dict:
 
 
 def _init_worker(path: List[str]) -> None:
-    """spawn-context initializer: children need the parent's sys.path (the
-    repo uses a src layout without installation)."""
+    """Worker-process initializer: children need the parent's sys.path
+    (the repo uses a src layout without installation), and the parent
+    holds the accelerator."""
+    from repro.uvm.replay_core import hand_device_to_parent
+    hand_device_to_parent()
     for p in reversed(path):
         if p not in sys.path:
             sys.path.insert(0, p)
@@ -1258,12 +1269,10 @@ def _run_lane_batches(cells: Sequence[SweepCell],
     Cells the backend declines (span too large, empty trace, ...) are
     left out of the result and flow back to the per-cell pool path,
     which keeps the ``--workers`` fan-out for them.  A runtime failure
-    of a lane batch (experimental-backend lowering faults) degrades its
-    cells to the NumPy path inline, with a warning; their rows record
-    the backend that actually ran.  A ``TransientBackendFault``
-    propagates out of the flush future and aborts the scheduler — the
-    PR 7 contract (crash the driver, retry on the same backend after
-    resume) is preserved across the thread boundary.
+    of a lane batch propagates out of the flush future and aborts the
+    scheduler: its cells never replay on the host in its place.  For a
+    ``TransientBackendFault`` that is the retry contract (crash the
+    driver, retry on the same backend after resume).
     """
     from repro.uvm.backends.pallas_backend import _lane_shape
 
@@ -1281,18 +1290,7 @@ def _run_lane_batches(cells: Sequence[SweepCell],
         """Flush-stage body (runs on the flush thread): replay one packed
         batch and assemble its rows."""
         t0 = time.time()
-        try:
-            stats = backend.replay(list(reqs))
-        except TransientBackendFault:
-            # retryable by contract: degrading would permanently change
-            # the rows' backend column, so let the driver crash and the
-            # resumed run replay these cells on the same backend
-            raise
-        except Exception as e:  # pragma: no cover - backend runtime faults
-            warnings.warn(f"pallas lane batch failed at runtime ({e!r}); "
-                          "replaying the affected cells on the NumPy path",
-                          RuntimeWarning)
-            stats = [replay_dispatch(r, "numpy") for r in reqs]
+        stats = backend.replay(list(reqs))
         per_cell = (time.time() - t0) / len(b)
         out: Dict[int, Dict] = {}
         for i, st, cap, req in zip(b, stats, cps, reqs):
@@ -1463,27 +1461,29 @@ def run_sweep(cells: Sequence[SweepCell], *, out_dir: Optional[str] = None,
         handled = {lane_pending[j] for j in lane_rows}
         pending = [i for i in pending if i not in handled]
 
-    if pending and out_dir:
-        # leased execution: every cell resolves to a persisted result or
-        # a quarantine verdict, whatever crashes along the way
-        if workers > 1:
-            _lease_pool(cells, pending, out_dir, cache_dir, workers, pol,
-                        _record, verbose)
+    # one process holds the chip: learned cells (predictor training and
+    # prediction) run here, before the fan-out; workers get the rest
+    fan_out = ([i for i in pending if cells[i].prefetcher != "learned"]
+               if workers > 1 else [])
+    for i in sorted(set(pending) - set(fan_out)):
+        if out_dir:
+            # leased execution: every cell resolves to a persisted result
+            # or a quarantine verdict, whatever crashes along the way
+            status, row = _run_cell_leased(i, cells[i], out_dir, cache_dir,
+                                           pol)
+            _record(i, row, persist=False)
         else:
-            for i in pending:
-                status, row = _run_cell_leased(i, cells[i], out_dir,
-                                               cache_dir, pol)
-                _record(i, row, persist=False)
-    elif pending and workers > 1:
-        ctx = _mp_context()
-        with ctx.Pool(min(workers, len(pending)), initializer=_init_worker,
-                      initargs=(list(sys.path),)) as pool:
-            args = [(cells[i], cache_dir) for i in pending]
-            for i, row in zip(pending, pool.imap(_worker, args)):
-                _record(i, row)
-    else:
-        for i in pending:
             _record(i, simulate_cell(cells[i], cache_dir=cache_dir))
+    if fan_out and out_dir:
+        _lease_pool(cells, fan_out, out_dir, cache_dir, workers, pol,
+                    _record, verbose)
+    elif fan_out:
+        ctx = _mp_context()
+        with ctx.Pool(min(workers, len(fan_out)), initializer=_init_worker,
+                      initargs=(list(sys.path),)) as pool:
+            args = [(cells[i], cache_dir) for i in fan_out]
+            for i, row in zip(fan_out, pool.imap(_worker, args)):
+                _record(i, row)
 
     out = [rows[i] for i in range(len(cells))]
     if out_dir and write_aggregate:
@@ -1601,9 +1601,7 @@ def main(argv: Optional[List[str]] = None) -> None:
                     choices=["auto", "vectorized", "legacy"])
     ap.add_argument("--backend", default=None, choices=list(BACKENDS),
                     help="replay backend: numpy, pallas (multi-lane "
-                         "kernel batches), or auto (pallas only where "
-                         "the lanes compile natively — TPU, or "
-                         "REPRO_PALLAS_COMPILE=1 on other accelerators; "
+                         "kernel batches), or auto (pallas on a TPU, "
                          "numpy otherwise); defaults to "
                          "$REPRO_SWEEP_BACKEND or auto")
     ap.add_argument("--out", default=None, help="results directory")
@@ -1694,6 +1692,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     for r in rows:
         print(",".join(f"{r[c]:.4f}" if isinstance(r[c], float) else str(r[c])
                        for c in cols))
+    if n_quar:
+        raise SystemExit(f"{n_quar} of {len(rows)} cells quarantined")
 
 
 if __name__ == "__main__":

@@ -145,9 +145,9 @@ def test_pallas_declines_oversized_oracle_lookahead():
 
 
 def test_numpy_runtime_failure_propagates(monkeypatch):
-    """Only *experimental* backends may degrade at runtime: a numpy-engine
-    crash must surface, not silently serve legacy results (which would
-    let the golden equivalence suite pass vacuously)."""
+    """A numpy-engine crash must surface, not silently serve legacy
+    results (which would let the golden equivalence suite pass
+    vacuously)."""
     from repro.uvm import VectorizedUVMSimulator
     from repro.uvm.backends.numpy_backend import NumpyReplayBackend
 
@@ -161,22 +161,60 @@ def test_numpy_runtime_failure_propagates(monkeypatch):
 
 
 def test_pallas_runtime_failure_degrades_with_warning(monkeypatch):
+    """A lane batch that fails at runtime raises: the cell is never
+    replayed on the NumPy engine in its place (a chip run must not
+    silently become a host run), and no fallback warning is issued."""
+    import warnings
+
     from repro.uvm.backends.pallas_backend import PallasReplayBackend
 
     def _boom(self, requests):
         raise RuntimeError("synthetic lowering failure")
 
     monkeypatch.setattr(PallasReplayBackend, "replay", _boom)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        stats = dispatch(_req(np.arange(50)), "pallas")
-    assert stats.backend == "numpy"
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(RuntimeError, match="synthetic lowering failure"):
+            dispatch(_req(np.arange(50)), "pallas")
 
 
-def test_is_native_consistent_with_interpret_policy():
-    """On a CPU host the lanes run in interpret mode, so they are not
-    native and ``auto`` resolution must prefer the NumPy engine."""
+def test_is_native_consistent_with_interpret_policy(monkeypatch):
+    """The lanes are native only on a TPU: on a CPU host ``auto`` resolves
+    to the NumPy engine, and a sweep worker (whose parent holds the chip)
+    is never native and never asks JAX."""
+    import jax
+
+    from repro.uvm import replay_core
+
+    assert jax.default_backend() == "cpu"
     assert get_backend("pallas").is_native() is False
     assert backend_chain("auto") == ["numpy", "legacy"]
+
+    def _no_jax():
+        raise AssertionError("a worker must not query the JAX backend")
+
+    monkeypatch.setattr(replay_core, "_device_held_by_parent", True)
+    monkeypatch.setattr(jax, "default_backend", _no_jax)
+    assert get_backend("pallas").is_native() is False
+    assert backend_chain("auto") == ["numpy", "legacy"]
+
+
+def test_worker_process_refuses_device_work(monkeypatch):
+    """In a sweep worker the parent holds the chip: a lane batch or a
+    predictor training run asked of it raises loudly instead of
+    initialising the accelerator (or quietly using the CPU)."""
+    from repro.uvm import predcache, replay_core
+
+    monkeypatch.setattr(replay_core, "_device_held_by_parent", True)
+    with pytest.raises(RuntimeError, match="parent process holds"):
+        get_backend("pallas").replay([_req(np.arange(50))])
+    with pytest.raises(RuntimeError, match="parent process holds"):
+        dispatch(_req(np.arange(50)), "pallas")
+    monkeypatch.setenv("REPRO_PREDCACHE", "0")
+    with pytest.raises(RuntimeError, match="predictor training"):
+        predcache.get_or_train(_mk_trace(np.arange(300) % 64), steps=1)
+    # host backends are unaffected
+    assert dispatch(_req(np.arange(50)), "numpy").backend == "numpy"
 
 
 def test_fits_batch_budgets():
@@ -463,46 +501,33 @@ if HAVE_HYPOTHESIS:
             _assert_equivalent(g, w, context=f"lane {i}/{cells[i][1:]}")
 
 # ---------------------------------------------------------------------------
-# kernel-executable disk cache (REPRO_KERNEL_CACHE)
+# JAX's persistent compilation cache: placed from outside, else fixed
 # ---------------------------------------------------------------------------
 
-def _exec_cache_files(d):
+def test_compile_cache_location(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and the code then sets no cache
+    of its own; without it the cache is a fixed, git-ignored directory
+    inside the checkout."""
     import os
-    return [f for f in os.listdir(d) if f.endswith(".jaxexec")]
 
+    import jax
 
-def test_kernel_exec_cache_roundtrip(tmp_path, monkeypatch):
-    """The compiled-lane cache: the first build serializes to
-    REPRO_KERNEL_CACHE, a later process (simulated by clearing the
-    in-process memo) deserializes bit-equal, a corrupt entry falls back
-    to a fresh build (and is rewritten), and ``0`` disables the cache."""
-    import os
-    from repro.uvm.backends import pallas_backend as pb
+    from repro import compile_cache
 
-    cache = tmp_path / "kernels"
-    monkeypatch.setenv("REPRO_KERNEL_CACHE", str(cache))
-    pages = np.tile(np.arange(40), 2)
-    backend = get_backend("pallas")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "untouched")
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "untouched"
 
-    pb._lane_replay_exec.cache_clear()
-    want = backend.replay([_req(pages, cap=24)])[0]     # build + serialize
-    files = _exec_cache_files(cache)
-    assert files, "no serialized executable written"
-
-    pb._lane_replay_exec.cache_clear()                  # "new process"
-    got = backend.replay([_req(pages, cap=24)])[0]      # deserialize path
-    _assert_equivalent(got, want, "exec-cache deserialize")
-
-    for f in files:                                     # corrupt the entry
-        with open(os.path.join(str(cache), f), "wb") as fh:
-            fh.write(b"not a serialized executable")
-    pb._lane_replay_exec.cache_clear()
-    got = backend.replay([_req(pages, cap=24)])[0]      # fallback build
-    _assert_equivalent(got, want, "exec-cache corrupt fallback")
-
-    monkeypatch.setenv("REPRO_KERNEL_CACHE", "0")       # disabled
-    assert pb._kernel_cache_dir() is None
-    pb._lane_replay_exec.cache_clear()
-    got = backend.replay([_req(pages, cap=24)])[0]
-    _assert_equivalent(got, want, "exec-cache disabled")
-    pb._lane_replay_exec.cache_clear()
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable()
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable() == path         # fixed, idempotent
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -178,9 +178,10 @@ def test_injected_backend_fault_is_transient(tmp_path, monkeypatch):
 
 def test_dispatch_reraises_transient_instead_of_degrading(monkeypatch):
     """A transient pallas fault must NOT degrade to numpy (that would
-    permanently change the row's backend column); plain runtime faults
-    still degrade with a warning, and numpy/legacy errors always
-    propagate — the golden equivalence can never pass vacuously."""
+    permanently change the row's backend column), and neither does a
+    hard runtime fault: both reach the caller, on every backend — a
+    device failure is never hidden behind a host replay, and the golden
+    equivalence can never pass vacuously."""
     from repro.uvm.backends.numpy_backend import NumpyReplayBackend
     from repro.uvm.backends.pallas_backend import PallasReplayBackend
     from repro.uvm.replay_core import TransientBackendFault, dispatch
@@ -198,12 +199,9 @@ def test_dispatch_reraises_transient_instead_of_degrading(monkeypatch):
         raise RuntimeError("lowering exploded")
 
     monkeypatch.setattr(PallasReplayBackend, "replay", _hard)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        stats = dispatch(req, "pallas")
-    assert stats.hits + stats.late + stats.faults > 0
+    with pytest.raises(RuntimeError, match="lowering exploded"):
+        dispatch(req, "pallas")
 
-    # non-experimental backends are never degraded around — their
-    # failures (transient or not) reach the caller
     monkeypatch.setattr(NumpyReplayBackend, "replay", _hard)
     with pytest.raises(RuntimeError, match="lowering exploded"):
         dispatch(req, "numpy")

@@ -247,9 +247,12 @@ def test_sweep_pallas_fallback_is_recorded(tmp_path, monkeypatch):
 
 def test_sweep_pallas_runtime_failure_degrades_per_cell(tmp_path,
                                                         monkeypatch):
-    """A lane batch that dies at runtime (not structurally) must not abort
-    the grid: affected cells replay per cell on the NumPy path and their
-    rows say so."""
+    """A lane batch that dies at runtime (not structurally) raises out of
+    the sweep: its cells are not replayed on the NumPy path in its place,
+    so a device failure can never read as a host run, and no row of the
+    failed batch is persisted."""
+    import warnings
+
     from repro.uvm.backends.pallas_backend import PallasReplayBackend
 
     def _boom(self, requests):
@@ -257,14 +260,14 @@ def test_sweep_pallas_runtime_failure_degrades_per_cell(tmp_path,
 
     monkeypatch.setattr(PallasReplayBackend, "replay", _boom)
     cells = _backend_grid("pallas")[:4]
-    with pytest.warns(RuntimeWarning, match="lane batch failed"):
-        rows = run_sweep(cells, out_dir=str(tmp_path / "out"), workers=1)
-    assert [r["backend"] for r in rows] == ["numpy"] * len(rows)
-    want = run_sweep(_backend_grid("numpy")[:4],
-                     out_dir=str(tmp_path / "ref"), workers=1)
-    for got, ref in zip(rows, want):
-        for f in INT_ROW_FIELDS:
-            assert got[f] == ref[f], f
+    out = str(tmp_path / "out")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(RuntimeError, match="synthetic kernel failure"):
+            run_sweep(cells, out_dir=out, workers=1)
+    for cell in cells:
+        assert load_cell_row(os.path.join(out, "cells",
+                                          f"{cell.key()}.json"))[0] is None
 
 
 def test_sweep_pallas_resume_skips_lane_batches(tmp_path, monkeypatch):
@@ -372,6 +375,60 @@ def test_learned_resume_needs_no_training(tmp_path, monkeypatch):
     resumed = run_sweep(cells, out_dir=out, workers=1)
     assert _strip_timing(resumed) == _strip_timing(first)
     predcache.clear_memo()
+
+
+def test_learned_cells_train_in_the_parent_before_fan_out(tmp_path,
+                                                         monkeypatch):
+    """One process holds the chip: with ``workers > 1`` the learned cells
+    (predictor training + prediction) run in the sweep's own process
+    before the fan-out, so the workers — which refuse device work —
+    replay only the rest, and the grid matches a serial run."""
+    from repro.core.service import PredictorService
+
+    fits = []
+    orig_fit = PredictorService.fit
+
+    def counting_fit(self, *args, **kwargs):
+        fits.append(1)                  # counts calls in THIS process only
+        return orig_fit(self, *args, **kwargs)
+
+    monkeypatch.setattr(PredictorService, "fit", counting_fit)
+    cells = (_learned_grid()[:2]
+             + expand_grid(["ATAX"], ["none", "tree"], scales=[0.25]))
+    predcache.clear_memo()
+    rows = run_sweep(cells, out_dir=str(tmp_path / "par"), workers=2)
+    assert len(fits) == 1
+    assert not any(r["quarantined"] for r in rows)
+    predcache.clear_memo()
+    serial = run_sweep(cells, out_dir=str(tmp_path / "ser"), workers=1)
+    assert _strip_timing(rows) == _strip_timing(serial)
+    predcache.clear_memo()
+
+
+def test_cli_exits_nonzero_on_quarantine(tmp_path, monkeypatch, capsys):
+    """``python -m repro.uvm.sweep`` fails when any row is quarantined."""
+    from repro.uvm import faults
+    from repro.uvm.sweep import main
+
+    cell = expand_grid(["ATAX"], ["none"], scales=[0.25], backend="numpy")[0]
+    plan = faults.FaultPlan(seed=0, specs=(
+        faults.FaultSpec("cell.start", "raise", prob=1.0, max_count=None,
+                         match=cell.key()),))
+    monkeypatch.setenv(faults.FAULT_PLAN_ENV, plan.to_json())
+    monkeypatch.setenv("REPRO_SWEEP_MAX_ATTEMPTS", "1")
+    monkeypatch.setenv("REPRO_SWEEP_BACKOFF", "0")
+    faults.reset()
+    try:
+        with pytest.warns(RuntimeWarning, match="quarantined"):
+            with pytest.raises(SystemExit) as exc:
+                main(["--benches", "ATAX", "--prefetchers", "none",
+                      "--scales", "0.25", "--backend", "numpy",
+                      "--out", str(tmp_path / "out")])
+    finally:
+        monkeypatch.delenv(faults.FAULT_PLAN_ENV)
+        faults.reset()
+    assert exc.value.code not in (0, None)
+    assert "1 of 1 cells quarantined" in str(exc.value.code)
 
 
 # ---------------------------------------------------------------------------
