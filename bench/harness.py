@@ -1,0 +1,307 @@
+"""The benchmark's run: set-up, the timed window, correctness, the result.
+
+One process on one chip.  Everything that belongs to one cell is data
+found by name: ``BENCHMARK.json`` names the cell, its configuration file
+(``bench/configs/<config>.json``) and its traffic file
+(``bench/workloads/<cell>.json``: the traces, the grid axes and the
+limits of the comparison); each per-layer metric is a reader in
+``bench/metrics/<metric>.py``.
+
+The window drives the sweep through its own entry point,
+``repro.uvm.sweep.run_sweep(cells, workers=1)``, one whole grid after
+another (closed loop, the way a researcher reruns a sweep).  A pass
+replays the grid once on each trace of the cell's traffic file
+(``trace_seeds``), in the order ``--seed`` draws; passes follow each
+other until ``--seconds`` have passed, and the pass that crosses the
+mark is finished and counted.  So every run does the same work, whatever
+its seed, and the window's work and its time end at the same lane batch
+boundary.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import itertools
+import json
+import os
+import resource
+import shutil
+import tempfile
+import time
+from typing import Callable, Dict, List, Optional
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_DIR)
+
+#: JAX's event for one XLA compilation or persistent-cache load
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: JAX's event for one program loaded from the persistent cache
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class Refused(Exception):
+    """The run cannot be measured (no chip, a traffic pin that moved, a
+    missing file): the harness exits non-zero and prints no result."""
+
+
+@dataclasses.dataclass
+class Cell:
+    """One benchmark cell, resolved from its files."""
+
+    name: str
+    chips: int
+    config: Dict
+    workload: Dict
+    per_layer: List[Dict]          # BENCHMARK.json entries that apply here
+    end_to_end: List[Dict]
+    backend: str = "auto"
+
+
+def _read_json(path: str) -> Dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except (OSError, ValueError) as e:
+        raise Refused(f"cannot read {os.path.relpath(path, ROOT)}: {e}")
+
+
+def load_cell(name: str, root: str = ROOT) -> Cell:
+    """Resolve cell ``name`` from ``BENCHMARK.json`` and its files."""
+    bm = _read_json(os.path.join(root, "BENCHMARK.json"))
+    entry = next((w for w in bm["workloads"] if w["name"] == name), None)
+    if entry is None:
+        raise Refused(f"no workload {name!r} in BENCHMARK.json")
+    return cell_from_files(name, entry["traffic"], entry["config"],
+                           int(entry["chips"]), bm, root)
+
+
+def cell_from_files(name: str, traffic: str, config: str, chips: int,
+                    bm: Dict, root: str = ROOT) -> Cell:
+    """A cell from its traffic and configuration files, with the
+    metrics of ``bm`` (a ``BENCHMARK.json``) that apply to it."""
+    conf = next(c for c in bm["configs"] if c["name"] == config)
+    workload = _read_json(os.path.join(root, "bench", "workloads",
+                                       f"{traffic}.json"))
+    if workload["config"] != config:
+        raise Refused(f"{name}: traffic file names config "
+                      f"{workload['config']!r}, BENCHMARK.json {config!r}")
+
+    def applies(m: Dict) -> bool:
+        return name in m.get("workloads", [name])
+
+    return Cell(name=name, chips=chips,
+                config=_read_json(os.path.join(root, conf["file"])),
+                workload=workload,
+                per_layer=[m for m in bm["per_layer"] if applies(m)],
+                end_to_end=[m for m in bm["end_to_end"] if applies(m)])
+
+
+def load_reader(metric: str) -> Callable:
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    path = os.path.join(BENCH_DIR, "metrics", f"{metric}.py")
+    if not os.path.exists(path):
+        raise Refused(f"no reader for metric {metric!r} ({path})")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def grid(cell: Cell, seed: int) -> List[Dict]:
+    """The cell's sweep cells: the product of its grid axes, in file order."""
+    axes = cell.workload["grid"]
+    fixed = dict(bench=cell.config["bench"], scale=cell.config["scale"],
+                 window=cell.config["window"], seed=int(seed),
+                 backend=cell.backend)
+    fixed.update(cell.workload.get("fixed", {}))
+    keys = list(axes)
+    return [dict(fixed, **dict(zip(keys, vals)))
+            for vals in itertools.product(*(axes[k] for k in keys))]
+
+
+def trace_order(cell: Cell, seed: int) -> List[int]:
+    """The seeds of the traces one pass replays: the traffic file's
+    ``trace_seeds``, in an order drawn from ``seed``.  The traces are the
+    same for every seed, since the work a trace asks of the lanes (its
+    faults and evictions) depends on its seed."""
+    import numpy as np
+
+    pool = [int(s) for s in cell.workload["trace_seeds"]]
+    rng = np.random.default_rng(seed % 2 ** 64)
+    return [pool[i] for i in rng.permutation(len(pool))]
+
+
+def check_pins(trace, config: Dict) -> None:
+    """Refuse a trace whose size moved from the configuration's pins."""
+    pins = config["pins"]
+    got = {"n_accesses": len(trace.accesses),
+           "n_instructions": int(trace.n_instructions)}
+    bad = {k: (got[k], v) for k, v in pins.items() if got[k] != v}
+    if bad:
+        raise Refused(f"trace of {config['name']} moved from its pins "
+                      f"(got, pinned): {bad}")
+
+
+def require_chips(chips: int):
+    """The devices, or :class:`Refused` when there is no TPU or too few."""
+    import jax
+
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise Refused(f"no TPU: JAX's first device is {devs[0].platform!r} "
+                      f"({devs[0].device_kind}); the benchmark runs only "
+                      "on the chip")
+    if len(devs) < chips:
+        raise Refused(f"the cell needs {chips} chips, JAX sees {len(devs)}")
+    return devs
+
+
+class CompileCounter:
+    """Counts XLA compilations and persistent-cache loads, from JAX's
+    own monitoring events, since :meth:`reset`."""
+
+    def __init__(self) -> None:
+        import jax.monitoring
+
+        self.n = 0
+        self.cache_hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on(self, name: str, _secs: float, **_kw) -> None:
+        if name == COMPILE_EVENT:
+            self.n += 1
+
+    def _on_event(self, name: str, **_kw) -> None:
+        if name == CACHE_HIT_EVENT:
+            self.cache_hits += 1
+
+    def reset(self) -> None:
+        self.n = 0
+        self.cache_hits = 0
+
+
+@dataclasses.dataclass
+class Window:
+    grids: List[List[Dict]]       # the rows of each finished grid
+    trace_seeds: List[int]        # the trace each grid replayed
+    seconds: float                # window start to the end of the last grid
+    compiles: int                 # compilations inside the window
+
+
+def run_window(one_pass, seconds: float, compiles: CompileCounter,
+               trace_dir: Optional[str] = None) -> Window:
+    """Whole passes back to back until ``seconds`` have passed; a pass is
+    one grid per ``(trace seed, sweep cells)`` of ``one_pass``.  With
+    ``trace_dir`` the window runs under the profiler."""
+    import jax
+    from repro.uvm.sweep import run_sweep
+
+    if trace_dir:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    grids, seeds = [], []
+    compiles.reset()
+    t0 = time.perf_counter()
+    try:
+        while True:
+            for trace_seed, sweep_cells in one_pass:
+                with jax.profiler.TraceAnnotation("bench.grid"):
+                    grids.append(run_sweep(sweep_cells, workers=1))
+                seeds.append(trace_seed)
+            elapsed = time.perf_counter() - t0
+            if elapsed >= seconds:
+                break
+    finally:
+        if trace_dir:
+            jax.profiler.stop_trace()
+    return Window(grids, seeds, elapsed, compiles.n)
+
+
+def failed_rows(rows: List[Dict]) -> int:
+    """Rows quarantined or replayed on any backend but the lanes."""
+    return sum(1 for r in rows
+               if r.get("quarantined") or r.get("backend") != "pallas")
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
+             t_start: float, require_chip: bool = True,
+             log=print) -> Dict:
+    """Set-up, window and checks of one run; returns the result line."""
+    from bench import compare, trace_reduce
+
+    import jax
+    from repro import compile_cache
+    from repro.uvm.sweep import SweepCell, load_trace
+
+    devs = require_chips(cell.chips) if require_chip else jax.devices()
+    compile_cache.enable()
+    compiles = CompileCounter()
+    conf = cell.config
+    t_dev = time.perf_counter()
+    program_traces, one_pass = {}, []
+    for ts in trace_order(cell, seed):
+        program_traces[ts] = load_trace(conf["bench"], conf["scale"], ts,
+                                        conf["window"])
+        check_pins(program_traces[ts], conf)
+        one_pass.append((ts, [SweepCell(**c) for c in grid(cell, ts)]))
+    t_traces = time.perf_counter()
+    tmp = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+    # warm-up: one whole grid compiles and runs every lane shape the
+    # window will use (the traces of a cell share their shapes)
+    run_window(one_pass[:1], 0.0, compiles)
+    setup_s = time.perf_counter() - t_start
+    log(f"bench: set-up {setup_s:.3f}s (start and device "
+        f"{t_dev - t_start:.3f}s, traces {t_traces - t_dev:.3f}s, warm-up "
+        f"grid {time.perf_counter() - t_traces:.3f}s), {compiles.n} "
+        f"compilations, {compiles.cache_hits} from the persistent cache")
+    win = run_window(one_pass, seconds, compiles, trace_dir=tmp)
+    rss_mib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
+    mem = devs[0].memory_stats() or {}
+    rows = [r for g in win.grids for r in g]
+    log(f"bench: window {win.seconds:.3f}s, {len(win.grids)} grids, "
+        f"{len(rows)} rows, {win.compiles} compilations")
+
+    result: Dict = {"correct": False,
+                    "attempted": len(win.grids) * len(one_pass[0][1]),
+                    "failed": failed_rows(rows), "metrics": {}}
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs),
+              "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
+    lane_accesses = sum(int(r["n_accesses"] or 0) for r in rows
+                        if r.get("backend") == "pallas")
+    if trace:
+        red = trace_reduce.reduce_dir(tmp)
+        shutil.rmtree(tmp, ignore_errors=True)
+        device["busy_s"] = red.busy_s
+        device["window_s"] = red.window_s
+        ctx = trace_reduce.MetricContext(
+            reduced=red, cell=cell, rows=rows, window=win,
+            lane_accesses=lane_accesses, device_kind=devs[0].device_kind,
+            program_traces=program_traces)
+        for m in cell.per_layer:
+            value = load_reader(m["name"])(ctx)
+            if value is not None:
+                result["metrics"][m["name"]] = {"value": value,
+                                                "unit": m["unit"]}
+        result["breakdown"] = red.breakdown()
+    else:
+        e2e = {"accesses_per_s": (sum(int(r["n_accesses"] or 0)
+                                      for r in rows) / win.seconds),
+               "host_peak_rss_mib": rss_mib, "setup_s": setup_s}
+        for m in cell.end_to_end:
+            result["metrics"][m["name"]] = {"value": e2e[m["name"]],
+                                            "unit": m["unit"]}
+    result["device"] = device
+
+    # correctness: every row of the window against the plain reference
+    checks = compare.check_window(conf, program_traces,
+                                  list(zip(win.trace_seeds, win.grids)),
+                                  dict(one_pass))
+    limits = cell.workload["limits"]
+    result["correct"] = all(checks[k] <= limits[k] for k in limits)
+    result["checks"] = {k: {"value": checks[k], "limit": limits[k]}
+                        for k in limits}
+    return result
