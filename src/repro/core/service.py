@@ -18,6 +18,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import families
 from repro.core import model as model_lib
 from repro.core.dataset import SEQ_LEN, build_dataset
@@ -76,21 +77,23 @@ class PredictorService:
     def fit(self, trace: Trace, init_params=None,
             cfg: model_lib.PredictorConfig | None = None,
             max_train: int = 16000) -> TrainResult:
-        self.trace = trace
-        self.ct = cluster_trace(trace, self.cluster_key)
-        self.vocab = DeltaVocab.build(self.ct, distance=self.distance)
-        self.convergence = delta_convergence(self.ct)
-        if cfg is None:
-            cfg = model_lib.family_config(
-                self.model_family, self.vocab.n_classes, self.convergence,
-                self.bypass_threshold, quantize=self.quantize)
-        data = build_dataset(self.ct, self.vocab, features=list(cfg.features),
-                             seq_len=self.seq_len, distance=self.distance,
-                             max_train=max_train, seed=self.seed)
-        self.result = train_predictor(cfg, data, steps=self.steps,
-                                      batch_size=self.batch_size,
-                                      seed=self.seed, params=init_params)
-        return self.result
+        with obs.span("predictor.fit", steps=self.steps):
+            self.trace = trace
+            self.ct = cluster_trace(trace, self.cluster_key)
+            self.vocab = DeltaVocab.build(self.ct, distance=self.distance)
+            self.convergence = delta_convergence(self.ct)
+            if cfg is None:
+                cfg = model_lib.family_config(
+                    self.model_family, self.vocab.n_classes, self.convergence,
+                    self.bypass_threshold, quantize=self.quantize)
+            data = build_dataset(self.ct, self.vocab,
+                                 features=list(cfg.features),
+                                 seq_len=self.seq_len, distance=self.distance,
+                                 max_train=max_train, seed=self.seed)
+            self.result = train_predictor(cfg, data, steps=self.steps,
+                                          batch_size=self.batch_size,
+                                          seed=self.seed, params=init_params)
+            return self.result
 
     def predict_trace(self, trace: Trace | None = None,
                       batch_size: int = 4096) -> np.ndarray:
@@ -105,58 +108,60 @@ class PredictorService:
         padded device batch, jit compiles one shape for the whole trace, and
         only the (class, confidence) pair per window crosses back to the
         host instead of full logits rows."""
-        assert self.result is not None and self.vocab is not None
-        if trace is None:
-            ct = self.ct
-        else:
-            ct = cluster_trace(trace, self.cluster_key)
-        cfg, params = self.result.cfg, self.result.params
-        out = np.full(max(g.max() for g in ct.global_index) + 1, -1,
-                      dtype=np.int64)
-        window = np.arange(self.seq_len)[None, :]
-        # windows accumulate across clusters but are inferred in shared
-        # flushes of at most flush_windows rows, so peak memory is bounded
-        # by the flush size, not the trace length
-        flush_windows = max(batch_size, 65536)
-        pend_x: list = []
-        pend_spans: list = []
-        pend_n = 0
+        with obs.span("predictor.predict"):
+            assert self.result is not None and self.vocab is not None
+            if trace is None:
+                ct = self.ct
+            else:
+                ct = cluster_trace(trace, self.cluster_key)
+            cfg, params = self.result.cfg, self.result.params
+            out = np.full(max(g.max() for g in ct.global_index) + 1, -1,
+                          dtype=np.int64)
+            window = np.arange(self.seq_len)[None, :]
+            # windows accumulate across clusters but are inferred in shared
+            # flushes of at most flush_windows rows, so peak memory is bounded
+            # by the flush size, not the trace length
+            flush_windows = max(batch_size, 65536)
+            pend_x: list = []
+            pend_spans: list = []
+            pend_n = 0
 
-        def _flush() -> None:
-            nonlocal pend_x, pend_spans, pend_n
-            if not pend_x:
-                return
-            x = pend_x[0] if len(pend_x) == 1 else np.concatenate(pend_x)
-            cls, conf = predict_cls_conf(cfg, params, x, batch_size)
-            off = 0
-            for pages, gidx, ends in pend_spans:
-                m = len(ends)
-                c, p = cls[off:off + m], conf[off:off + m]
-                off += m
-                deltas = self.vocab.decode(c)
-                # confidence gate: don't prefetch on low-probability
-                # predictions (useless prefetches cost bus bandwidth, §7.6)
-                pred_pages = np.where((c == 0) | (p < self.min_prob),
-                                      -1, pages[ends] + deltas)
-                out[gidx[ends]] = pred_pages
-            pend_x, pend_spans, pend_n = [], [], 0
+            def _flush() -> None:
+                nonlocal pend_x, pend_spans, pend_n
+                if not pend_x:
+                    return
+                x = pend_x[0] if len(pend_x) == 1 else np.concatenate(pend_x)
+                cls, conf = predict_cls_conf(cfg, params, x, batch_size)
+                off = 0
+                for pages, gidx, ends in pend_spans:
+                    m = len(ends)
+                    c, p = cls[off:off + m], conf[off:off + m]
+                    off += m
+                    deltas = self.vocab.decode(c)
+                    # confidence gate: don't prefetch on low-probability
+                    # predictions (useless prefetches cost bus bandwidth,
+                    # §7.6)
+                    pred_pages = np.where((c == 0) | (p < self.min_prob),
+                                          -1, pages[ends] + deltas)
+                    out[gidx[ends]] = pred_pages
+                pend_x, pend_spans, pend_n = [], [], 0
 
-        for cluster, pages, gidx in zip(ct.clusters, ct.pages,
-                                        ct.global_index):
-            n = len(pages)
-            if n < self.seq_len:
-                continue
-            enc = encode_features(cluster, list(cfg.features))
-            all_starts = np.arange(0, n - self.seq_len + 1)
-            for s0 in range(0, len(all_starts), flush_windows):
-                starts = all_starts[s0:s0 + flush_windows]
-                pend_x.append(enc[starts[:, None] + window])
-                pend_spans.append((pages, gidx, starts + self.seq_len - 1))
-                pend_n += len(starts)
-                if pend_n >= flush_windows:
-                    _flush()
-        _flush()
-        return out
+            for cluster, pages, gidx in zip(ct.clusters, ct.pages,
+                                            ct.global_index):
+                n = len(pages)
+                if n < self.seq_len:
+                    continue
+                enc = encode_features(cluster, list(cfg.features))
+                all_starts = np.arange(0, n - self.seq_len + 1)
+                for s0 in range(0, len(all_starts), flush_windows):
+                    starts = all_starts[s0:s0 + flush_windows]
+                    pend_x.append(enc[starts[:, None] + window])
+                    pend_spans.append((pages, gidx, starts + self.seq_len - 1))
+                    pend_n += len(starts)
+                    if pend_n >= flush_windows:
+                        _flush()
+            _flush()
+            return out
 
 
 def pretrain_corpus(traces: List[Trace], cfg: model_lib.PredictorConfig,

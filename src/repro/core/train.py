@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import model as model_lib
 from repro.core.dataset import SequenceDataset, batches
 from repro.core.metrics import topk_accuracy, weighted_f1
@@ -36,6 +37,7 @@ def make_train_step(cfg: model_lib.PredictorConfig, *, steps: int,
     """The optimizer and the jitted train step of :func:`train_predictor`:
     ``step_fn(params, opt_state, x, y, step) -> (params, opt_state,
     loss)``, with a warmup-cosine learning rate over ``steps``."""
+    obs.count("predictor.train_step_builds")
     opt = AdamW(weight_decay=1e-4, clip_norm=1.0)
     sched = linear_warmup_cosine(lr, warmup_steps=min(50, steps // 10 + 1),
                                  total_steps=steps)

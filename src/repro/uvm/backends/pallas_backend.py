@@ -88,7 +88,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro import compile_cache
+from repro import compile_cache, obs
 from repro.traces.trace import BASIC_BLOCK_PAGES, ROOT_PAGES
 from repro.uvm.eviction import (EVICTION_POLICIES, SCORE_MULT_1,
                                 SCORE_MULT_2, SCORE_SEED_MULT,
@@ -236,6 +236,8 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+
+    obs.count("lane.program_builds")       # runs once per cached shape
 
     blk_pages = BASIC_BLOCK_PAGES
     blk_shift = blk_pages.bit_length() - 1
@@ -1046,136 +1048,145 @@ class PallasReplayBackend(ReplayBackend):
         policy = policies.pop()
 
         lanes = len(requests)
-        shapes = [_lane_shape(r) for r in requests]
-        t_max = _bucket(max(t for _, _, t, _ in shapes), 64)
-        span = _bucket(max(s for _, _, _, s in shapes), ROOT_PAGES)
-        buf_len = max(int(r.config.mshr_entries) for r in requests) + 1
-        n_lanes = _bucket(lanes, 1)
-        ft_len = 0
-        if kind == "oracle":
-            ft_len = _bucket(max(len(r.prefetcher.ft_pages)
-                                 for r in requests), 64) + lookahead
-        step_sizes = [0 if r.step_bounds is None
-                      else int(np.asarray(r.step_bounds).size)
-                      for r in requests]
-        steps_len = _bucket(max(step_sizes), 64) if any(step_sizes) else 0
-        # mt is a static kernel flag but tenancy stays per-lane dynamic:
-        # single-tenant lanes of a mixed batch ride with boundary = IMAX
-        # and q0 = -1, which keeps their replay bit-identical (see
-        # _lane_replay_fn), so packing needs no tenancy homogeneity
-        tenancies = [resolve_tenancy(r.trace, r.config) for r in requests]
-        mt = any(t is not None for t in tenancies)
+        obs.count("lane.batches")
+        obs.count("lane.lanes", lanes)
+        obs.count("lane.accesses", sum(len(r.trace.pages) for r in requests))
+        with obs.span("lane.pad", family=family, policy=policy, lanes=lanes):
+            shapes = [_lane_shape(r) for r in requests]
+            t_max = _bucket(max(t for _, _, t, _ in shapes), 64)
+            span = _bucket(max(s for _, _, _, s in shapes), ROOT_PAGES)
+            buf_len = max(int(r.config.mshr_entries) for r in requests) + 1
+            n_lanes = _bucket(lanes, 1)
+            ft_len = 0
+            if kind == "oracle":
+                ft_len = _bucket(max(len(r.prefetcher.ft_pages)
+                                     for r in requests), 64) + lookahead
+            step_sizes = [0 if r.step_bounds is None
+                          else int(np.asarray(r.step_bounds).size)
+                          for r in requests]
+            steps_len = (_bucket(max(step_sizes), 64) if any(step_sizes)
+                         else 0)
+            # mt is a static kernel flag but tenancy stays per-lane dynamic:
+            # single-tenant lanes of a mixed batch ride with boundary = IMAX
+            # and q0 = -1, which keeps their replay bit-identical (see
+            # _lane_replay_fn), so packing needs no tenancy homogeneity
+            tenancies = [resolve_tenancy(r.trace, r.config) for r in requests]
+            mt = any(t is not None for t in tenancies)
 
-        pages = np.zeros((n_lanes, t_max), dtype=np.int32)
-        fparams = np.zeros((n_lanes, _N_FPARAMS), dtype=np.float64)
-        iparams = np.full((n_lanes, _N_IPARAMS), -1, dtype=np.int32)
-        iparams[:, 0] = 0                      # padding lanes replay nothing
-        iparams[:, 6] = np.iinfo(np.int32).max  # single-tenant boundary
-        extra_in: List[np.ndarray] = []
-        if kind == "learned":
-            preds_in = np.full((n_lanes, t_max), -1, dtype=np.int32)
-            extra_in = [preds_in]
-        elif kind == "oracle":
-            # padded first-touch entries point at the trash slot ``span``
-            ft_in = np.full((n_lanes, ft_len), span, dtype=np.int32)
-            pos_in = np.zeros((n_lanes, t_max), dtype=np.int32)
-            extra_in = [ft_in, pos_in]
-        if steps_len:
-            sids_in = np.zeros((n_lanes, t_max), dtype=np.int32)
-            extra_in = extra_in + [sids_in]
-        for l, req in enumerate(requests):
-            trace, cfg, pf = req.trace, req.config, req.prefetcher
-            pf.reset()
-            n = len(trace.pages)
-            lo, _ = dense_bounds(trace, pf)
-            pages[l, :n] = np.asarray(trace.pages, dtype=np.int64) - lo
-            fparams[l] = (
-                cycles_per_access(trace, cfg), cfg.page_transfer_cycles,
-                cfg.far_fault_cycles, cfg.page_table_walk_cycles,
-                cfg.pcie_latency_cycles, cfg.prefetch_overhead_cycles,
-                pf.extra_latency_cycles, cfg.page_size)
-            has_block = (type(pf) is BlockPrefetcher
-                         or (type(pf) is LearnedPrefetcher
-                             and pf.prefetch_block))
-            iparams[l, :4] = (
-                n,
-                -1 if cfg.device_pages is None else int(cfg.device_pages),
-                int(cfg.mshr_entries),
-                1 if has_block else 0)
-            # lane lo mod 2^32 (int32 bit pattern): random-policy draws
-            # hash the absolute page id, identical across backends
-            iparams[l, 5] = np.array(lo & 0xFFFFFFFF,
-                                     dtype=np.uint32).astype(np.int32)
-            tn = tenancies[l]
-            if tn is not None:
-                # dense boundary: may fall outside [0, span) when a trace
-                # slice only touches one tenant's region — the compares
-                # stay correct either way (all-0 / all-1 lanes)
-                iparams[l, 6] = int(tn.boundary) - lo
-                if tn.split:
-                    iparams[l, 7] = int(tn.quotas[0])
-                    iparams[l, 8] = int(tn.quotas[1])
+            pages = np.zeros((n_lanes, t_max), dtype=np.int32)
+            fparams = np.zeros((n_lanes, _N_FPARAMS), dtype=np.float64)
+            iparams = np.full((n_lanes, _N_IPARAMS), -1, dtype=np.int32)
+            iparams[:, 0] = 0                  # padding lanes replay nothing
+            iparams[:, 6] = np.iinfo(np.int32).max  # single-tenant boundary
+            extra_in: List[np.ndarray] = []
             if kind == "learned":
-                pr = np.asarray(pf.predicted_pages, dtype=np.int64)[:n]
-                preds_in[l, :n] = np.where(pr >= 0, pr - lo, -1)
+                preds_in = np.full((n_lanes, t_max), -1, dtype=np.int32)
+                extra_in = [preds_in]
             elif kind == "oracle":
-                ftp = np.asarray(pf.ft_pages, dtype=np.int64) - lo
-                ft_in[l, :len(ftp)] = ftp
-                # the stream position is a pure function of the access
-                # index (it only ever advances): precompute it host-side
-                pos_in[l, :n] = np.searchsorted(
-                    pf.ft_index, np.arange(n), side="right")
-                iparams[l, 4] = len(ftp)
-            if steps_len and req.step_bounds is not None:
-                sb = np.asarray(req.step_bounds, dtype=np.int64)
-                # window id per access; accesses past the last bound go
-                # to the trash slot ``steps_len``
-                sid = np.searchsorted(sb, np.arange(n), side="right")
-                sids_in[l, :n] = np.where(sid >= sb.size, steps_len,
-                                          sid).astype(np.int32)
+                # padded first-touch entries point at the trash slot ``span``
+                ft_in = np.full((n_lanes, ft_len), span, dtype=np.int32)
+                pos_in = np.zeros((n_lanes, t_max), dtype=np.int32)
+                extra_in = [ft_in, pos_in]
+            if steps_len:
+                sids_in = np.zeros((n_lanes, t_max), dtype=np.int32)
+                extra_in = extra_in + [sids_in]
+            for l, req in enumerate(requests):
+                trace, cfg, pf = req.trace, req.config, req.prefetcher
+                pf.reset()
+                n = len(trace.pages)
+                lo, _ = dense_bounds(trace, pf)
+                pages[l, :n] = np.asarray(trace.pages, dtype=np.int64) - lo
+                fparams[l] = (
+                    cycles_per_access(trace, cfg), cfg.page_transfer_cycles,
+                    cfg.far_fault_cycles, cfg.page_table_walk_cycles,
+                    cfg.pcie_latency_cycles, cfg.prefetch_overhead_cycles,
+                    pf.extra_latency_cycles, cfg.page_size)
+                has_block = (type(pf) is BlockPrefetcher
+                             or (type(pf) is LearnedPrefetcher
+                                 and pf.prefetch_block))
+                iparams[l, :4] = (
+                    n,
+                    -1 if cfg.device_pages is None else int(cfg.device_pages),
+                    int(cfg.mshr_entries),
+                    1 if has_block else 0)
+                # lane lo mod 2^32 (int32 bit pattern): random-policy draws
+                # hash the absolute page id, identical across backends
+                iparams[l, 5] = np.array(lo & 0xFFFFFFFF,
+                                         dtype=np.uint32).astype(np.int32)
+                tn = tenancies[l]
+                if tn is not None:
+                    # dense boundary: may fall outside [0, span) when a trace
+                    # slice only touches one tenant's region — the compares
+                    # stay correct either way (all-0 / all-1 lanes)
+                    iparams[l, 6] = int(tn.boundary) - lo
+                    if tn.split:
+                        iparams[l, 7] = int(tn.quotas[0])
+                        iparams[l, 8] = int(tn.quotas[1])
+                if kind == "learned":
+                    pr = np.asarray(pf.predicted_pages, dtype=np.int64)[:n]
+                    preds_in[l, :n] = np.where(pr >= 0, pr - lo, -1)
+                elif kind == "oracle":
+                    ftp = np.asarray(pf.ft_pages, dtype=np.int64) - lo
+                    ft_in[l, :len(ftp)] = ftp
+                    # the stream position is a pure function of the access
+                    # index (it only ever advances): precompute it host-side
+                    pos_in[l, :n] = np.searchsorted(
+                        pf.ft_index, np.arange(n), side="right")
+                    iparams[l, 4] = len(ftp)
+                if steps_len and req.step_bounds is not None:
+                    sb = np.asarray(req.step_bounds, dtype=np.int64)
+                    # window id per access; accesses past the last bound go
+                    # to the trash slot ``steps_len``
+                    sid = np.searchsorted(sb, np.arange(n), side="right")
+                    sids_in[l, :n] = np.where(sid >= sb.size, steps_len,
+                                              sid).astype(np.int32)
 
+        obs.count("lane.padded_lanes", n_lanes - lanes)
         compile_cache.enable()
-        with jax.enable_x64(True):
+        with obs.span("lane.dispatch", family=family, policy=policy), \
+                jax.enable_x64(True):
             fn = _lane_replay_fn(kind, policy, n_lanes, t_max, span,
                                  buf_len, ft_len, lookahead, steps_len, mt)
             raw = fn(pages, *extra_in, fparams, iparams)
-        if steps_len:
-            raw, raw_steps = (np.asarray(raw[0]), np.asarray(raw[1]))
-        else:
-            raw = np.asarray(raw)
+        with obs.span("lane.fetch"):
+            if steps_len:
+                raw, raw_steps = (np.asarray(raw[0]), np.asarray(raw[1]))
+            else:
+                raw = np.asarray(raw)
 
-        out = []
-        for l, req in enumerate(requests):
-            row = raw[l]
-            stats = UVMStats(
-                name=req.trace.name,
-                prefetcher=req.prefetcher.name,
-                n_accesses=len(req.trace.pages),
-                n_instructions=req.trace.n_instructions,
-                cycles=float(row[0]),
-                hits=int(row[1]),
-                late=int(row[2]),
-                faults=int(row[3]),
-                prefetch_issued=int(row[4]),
-                prefetch_used=int(row[5]),
-                pages_migrated=int(row[6]),
-                pages_evicted=int(row[7]),
-                pcie_bytes=float(row[8]),
-                zero_copy_bytes=0.0,
-                timeline=None,
-                eviction=req.config.eviction,
-            )
-            stats.backend = self.name
-            if tenancies[l] is not None:
-                th0 = int(row[len(STAT_FIELDS)])
-                stats.tenant_hits = (th0, stats.hits - th0)
-                stats.tenant_accesses = _tenant_accesses(
-                    req.trace.pages, tenancies[l])
-            if steps_len and req.step_bounds is not None:
-                stats.step_clocks = _fill_step_clocks(
-                    np.asarray(req.step_bounds, dtype=np.int64),
-                    raw_steps[l])
-            out.append(stats)
+        with obs.span("lane.unpack"):
+            out = []
+            for l, req in enumerate(requests):
+                row = raw[l]
+                stats = UVMStats(
+                    name=req.trace.name,
+                    prefetcher=req.prefetcher.name,
+                    n_accesses=len(req.trace.pages),
+                    n_instructions=req.trace.n_instructions,
+                    cycles=float(row[0]),
+                    hits=int(row[1]),
+                    late=int(row[2]),
+                    faults=int(row[3]),
+                    prefetch_issued=int(row[4]),
+                    prefetch_used=int(row[5]),
+                    pages_migrated=int(row[6]),
+                    pages_evicted=int(row[7]),
+                    pcie_bytes=float(row[8]),
+                    zero_copy_bytes=0.0,
+                    timeline=None,
+                    eviction=req.config.eviction,
+                )
+                stats.backend = self.name
+                if tenancies[l] is not None:
+                    th0 = int(row[len(STAT_FIELDS)])
+                    stats.tenant_hits = (th0, stats.hits - th0)
+                    stats.tenant_accesses = _tenant_accesses(
+                        req.trace.pages, tenancies[l])
+                if steps_len and req.step_bounds is not None:
+                    stats.step_clocks = _fill_step_clocks(
+                        np.asarray(req.step_bounds, dtype=np.int64),
+                        raw_steps[l])
+                out.append(stats)
         return out
 
 

@@ -192,6 +192,13 @@ def load(cache_dir: str, key: str) -> Optional[np.ndarray]:
     return load_checked(cache_dir, key)[0]
 
 
+def _count(name: str) -> None:
+    """:func:`repro.obs.count`, imported here: keys and storage must work
+    without pulling in jax."""
+    from repro import obs
+    obs.count(name)
+
+
 def store(cache_dir: str, key: str, preds: np.ndarray) -> str:
     """Atomically persist a predictions array with its checksum: write a
     single ``.npz`` (array + sha256) to a same-directory tempfile, then
@@ -215,6 +222,7 @@ def store(cache_dir: str, key: str, preds: np.ndarray) -> str:
             pass
         raise
     faults.corrupt("pred.artifact", path, key)
+    _count("predcache.stores")
     return path
 
 
@@ -257,10 +265,14 @@ def get_or_train(trace, *, steps: int = 150, seed: int = 0,
         return PredictorService(steps=steps, seed=seed,
                                 **(service_kwargs or {}))
 
+    trained: list = []                   # _train ran in this call
+
     def _train() -> np.ndarray:
         from repro import compile_cache
         from repro.uvm.replay_core import require_device
         require_device("predictor training")
+        _count("predcache.misses")
+        trained.append(True)
         compile_cache.enable()
         svc = _fresh_service()
         svc.fit(trace)
@@ -276,6 +288,7 @@ def get_or_train(trace, *, steps: int = 150, seed: int = 0,
     key = predictions_key(trace, **fields)
     preds = _MEMO.get(key)
     if preds is not None:
+        _count("predcache.hits")
         return preds
 
     if cache_dir is None:
@@ -338,5 +351,7 @@ def get_or_train(trace, *, steps: int = 150, seed: int = 0,
             finally:
                 if got:
                     _unlock(lock)
+    if not trained:
+        _count("predcache.hits")
     _MEMO[key] = preds
     return preds

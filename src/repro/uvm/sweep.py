@@ -141,6 +141,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.families import MODEL_FAMILIES  # jax-free config layer
 from repro.distributed import fault_tolerance as ft
 from repro.traces.trace import ACCESS_DTYPE, Trace
@@ -436,12 +437,17 @@ def load_trace(bench: str, scale: float = 1.0, seed: int = 0,
     memo_key = (bench, scale, seed, window, cache_dir)
     memoized = _trace_memo.get(memo_key)
     if memoized is not None:
+        obs.count("trace.memo_hits")
         return memoized
     with _trace_flight(memo_key):
         memoized = _trace_memo.get(memo_key)    # the winner filled it
         if memoized is not None:
+            obs.count("trace.memo_hits")
             return memoized
-        trace = _load_trace_uncached(bench, scale, seed, window, cache_dir)
+        obs.count("trace.memo_misses")
+        with obs.span("trace.build", bench=bench, seed=seed):
+            trace = _load_trace_uncached(bench, scale, seed, window,
+                                         cache_dir)
         _trace_memo.put(memo_key, trace)
         return trace
 
@@ -1285,36 +1291,47 @@ def _run_lane_batches(cells: Sequence[SweepCell],
     # elements make fits_batch refuse to co-bucket families or policies
     shapes: List[Tuple[str, str, int, int]] = []
 
-    def _replay_batch_rows(b: List[int], reqs: List[ReplayRequest],
-                           cps: List[Optional[int]]) -> Dict[int, Dict]:
-        """Flush-stage body (runs on the flush thread): replay one packed
-        batch and assemble its rows."""
-        t0 = time.time()
-        stats = backend.replay(list(reqs))
-        per_cell = (time.time() - t0) / len(b)
-        out: Dict[int, Dict] = {}
-        for i, st, cap, req in zip(b, stats, cps, reqs):
-            row = _finish_row(cells[i], st, cap, per_cell)
-            if req.trace.meta and "serve" in req.trace.meta:
-                row.update(_serve_latency_row(cells[i], req.trace,
-                                              req.config, st, cache_dir))
-            elif _is_mt_trace(req.trace):
-                row.update(_mt_row(cells[i], req.trace, req.config, st,
-                                   cap, cache_dir))
-            out[i] = row
-        return out
+    def _replay_batch_rows(n: int, b: List[int], reqs: List[ReplayRequest],
+                           cps: List[Optional[int]],
+                           shps: List[Tuple[str, str, int, int]]
+                           ) -> Dict[int, Dict]:
+        """Flush-stage body (runs on the flush thread): replay packed
+        batch ``n`` and assemble its rows."""
+        with obs.span("lane.batch", batch=n, family=shps[0][0],
+                      policy=shps[0][1], lanes=len(b),
+                      accesses=sum(sh[2] for sh in shps)):
+            t0 = time.time()
+            stats = backend.replay(list(reqs))
+            per_cell = (time.time() - t0) / len(b)
+            out: Dict[int, Dict] = {}
+            with obs.span("sweep.finish_rows", batch=n):
+                for i, st, cap, req in zip(b, stats, cps, reqs):
+                    row = _finish_row(cells[i], st, cap, per_cell)
+                    if req.trace.meta and "serve" in req.trace.meta:
+                        row.update(_serve_latency_row(
+                            cells[i], req.trace, req.config, st, cache_dir))
+                    elif _is_mt_trace(req.trace):
+                        row.update(_mt_row(cells[i], req.trace, req.config,
+                                           st, cap, cache_dir))
+                    out[i] = row
+            return out
 
     n_flush = max(1, int(_env_num("REPRO_SWEEP_FLUSH_THREADS", 2)))
     flush_pool = ThreadPoolExecutor(max_workers=n_flush)
     inflight: collections.deque = collections.deque()   # FIFO of futures
+    n_flushed = 0                            # index of the batch being packed
 
     def _await_inflight(room: int = 0) -> None:
         """Drain flush futures (oldest first) until at most ``room`` are
         still in flight; re-raises their failures in the main thread."""
-        while len(inflight) > room:
-            rows.update(inflight.popleft().result())
+        if len(inflight) <= room:
+            return
+        with obs.span("sweep.await"):
+            while len(inflight) > room:
+                rows.update(inflight.popleft().result())
 
     def _flush() -> None:
+        nonlocal n_flushed
         if not batch:
             return
         if verbose:
@@ -1323,7 +1340,9 @@ def _run_lane_batches(cells: Sequence[SweepCell],
         faults.fire("lane.flush", f"{len(batch)}:{cells[batch[0]].key()}")
         _await_inflight(room=n_flush - 1)    # bounded batches in flight
         inflight.append(flush_pool.submit(
-            _replay_batch_rows, list(batch), list(requests), list(caps)))
+            _replay_batch_rows, n_flushed, list(batch), list(requests),
+            list(caps), list(shapes)))
+        n_flushed += 1
         batch.clear()
         requests.clear()
         caps.clear()
@@ -1342,32 +1361,37 @@ def _run_lane_batches(cells: Sequence[SweepCell],
     pending = collections.deque()            # (i, future) in scheduler order
     feed = iter(order)
 
+    def _prepare(i: int):
+        """Prepare-stage body (runs on the prep pool)."""
+        with obs.span("sweep.prepare", cell=i):
+            return prepare_cell(cells[i], cache_dir=cache_dir)
+
     def _top_up() -> None:
         while len(pending) < prep_window:
             try:
                 i = next(feed)
             except StopIteration:
                 return
-            pending.append((i, prep_pool.submit(
-                prepare_cell, cells[i], cache_dir=cache_dir)))
+            pending.append((i, prep_pool.submit(_prepare, i)))
 
     try:
         _top_up()
         while pending:
             i, fut = pending.popleft()
             trace, config, prefetcher, pages = fut.result()
-            _top_up()                        # keep the lookahead full
-            req = ReplayRequest(trace, prefetcher, config,
-                                step_bounds=_step_bounds(trace))
-            if not backend.can_replay(req):
-                continue                     # back to the per-cell pool path
-            shape = _lane_shape(req)
-            if not backend.fits_batch(shapes, shape):
-                _flush()
-            batch.append(i)
-            requests.append(req)
-            caps.append(pages)
-            shapes.append(shape)
+            with obs.span("sweep.pack", batch=n_flushed):
+                _top_up()                    # keep the lookahead full
+                req = ReplayRequest(trace, prefetcher, config,
+                                    step_bounds=_step_bounds(trace))
+                if not backend.can_replay(req):
+                    continue                 # back to the per-cell pool path
+                shape = _lane_shape(req)
+                if not backend.fits_batch(shapes, shape):
+                    _flush()
+                batch.append(i)
+                requests.append(req)
+                caps.append(pages)
+                shapes.append(shape)
         _flush()
         _await_inflight(room=0)
     finally:
@@ -1399,100 +1423,102 @@ def run_sweep(cells: Sequence[SweepCell], *, out_dir: Optional[str] = None,
     ``out_dir`` across several grids should pass ``write_aggregate=False``
     so the aggregate files never reflect a partial grid.
     """
-    if cache_dir is None and out_dir is not None:
-        cache_dir = os.path.join(out_dir, "trace_cache")
-    pol = _exec_policy(max_attempts, lease_ttl_s)
-    rows: Dict[int, Dict] = {}
-    pending: List[int] = []
-    for i, cell in enumerate(cells):
-        if out_dir:
-            path = _cell_path(out_dir, cell)
-            if resume:
-                row, reason = load_cell_row(path)
-                if row is not None:
-                    rows[i] = row
-                    continue
-                if reason in ("corrupt", "version"):
-                    quarantine_artifact(
-                        path, f"resume: invalid cell file for "
-                        f"{cell.bench}/{cell.prefetcher} ({reason}); "
-                        "requeueing")
-                qdoc = _read_json(path + ".quarantine")
-                if qdoc is not None:
-                    rows[i] = _quarantine_stub(cell, qdoc)
-                    continue
+    with obs.span("sweep.run", cells=len(cells)):
+        if cache_dir is None and out_dir is not None:
+            cache_dir = os.path.join(out_dir, "trace_cache")
+        pol = _exec_policy(max_attempts, lease_ttl_s)
+        rows: Dict[int, Dict] = {}
+        pending: List[int] = []
+        for i, cell in enumerate(cells):
+            if out_dir:
+                path = _cell_path(out_dir, cell)
+                if resume:
+                    row, reason = load_cell_row(path)
+                    if row is not None:
+                        rows[i] = row
+                        continue
+                    if reason in ("corrupt", "version"):
+                        quarantine_artifact(
+                            path, f"resume: invalid cell file for "
+                            f"{cell.bench}/{cell.prefetcher} ({reason}); "
+                            "requeueing")
+                    qdoc = _read_json(path + ".quarantine")
+                    if qdoc is not None:
+                        rows[i] = _quarantine_stub(cell, qdoc)
+                        continue
+                else:
+                    # a fresh (non-resumed) run must not inherit results,
+                    # attempt counts, or quarantine verdicts from earlier
+                    # runs — the leased executor would short-circuit on them
+                    for suffix in ("", ".quarantine", ".attempts"):
+                        try:
+                            os.unlink(path + suffix)
+                        except OSError:
+                            pass
+            pending.append(i)
+
+        def _record(i: int, row: Dict, persist: bool = True) -> None:
+            rows[i] = row
+            if out_dir and persist:
+                write_cell_row(_cell_path(out_dir, cells[i]), row)
+            if verbose:
+                if row.get("quarantined"):
+                    print(f"[sweep] {row['bench']}/{row['prefetcher']}"
+                          f" frac={row.get('device_frac')} QUARANTINED"
+                          f" after {row.get('retries')} retries", flush=True)
+                else:
+                    print(f"[sweep] {row['bench']}/{row['prefetcher']}"
+                          f" frac={row.get('device_frac')}"
+                          f" backend={row.get('backend')}"
+                          f" hit={row['hit_rate']:.3f} ipc={row['ipc']:.2f}"
+                          f" ({row['seconds']:.2f}s)", flush=True)
+
+        # lane-batch scheduler: pack pallas-bound cells into multi-lane kernel
+        # launches in the parent process (they are already batched — worker
+        # fan-out would only serialize them again); whatever the backend
+        # declines falls back to the per-cell path below
+        lane_pending = [i for i in pending if _wants_lanes(cells[i])]
+        if lane_pending:
+            lane_rows = _run_lane_batches([cells[i] for i in lane_pending],
+                                          cache_dir, verbose=verbose)
+            for j, row in lane_rows.items():
+                _record(lane_pending[j], row)
+            handled = {lane_pending[j] for j in lane_rows}
+            pending = [i for i in pending if i not in handled]
+
+        # one process holds the chip: learned cells (predictor training and
+        # prediction) run here, before the fan-out; workers get the rest
+        fan_out = ([i for i in pending if cells[i].prefetcher != "learned"]
+                   if workers > 1 else [])
+        for i in sorted(set(pending) - set(fan_out)):
+            if out_dir:
+                # leased execution: every cell resolves to a persisted result
+                # or a quarantine verdict, whatever crashes along the way
+                status, row = _run_cell_leased(i, cells[i], out_dir, cache_dir,
+                                               pol)
+                _record(i, row, persist=False)
             else:
-                # a fresh (non-resumed) run must not inherit results,
-                # attempt counts, or quarantine verdicts from earlier
-                # runs — the leased executor would short-circuit on them
-                for suffix in ("", ".quarantine", ".attempts"):
-                    try:
-                        os.unlink(path + suffix)
-                    except OSError:
-                        pass
-        pending.append(i)
+                _record(i, simulate_cell(cells[i], cache_dir=cache_dir))
+        if fan_out and out_dir:
+            _lease_pool(cells, fan_out, out_dir, cache_dir, workers, pol,
+                        _record, verbose)
+        elif fan_out:
+            ctx = _mp_context()
+            with ctx.Pool(min(workers, len(fan_out)), initializer=_init_worker,
+                          initargs=(list(sys.path),)) as pool:
+                args = [(cells[i], cache_dir) for i in fan_out]
+                for i, row in zip(fan_out, pool.imap(_worker, args)):
+                    _record(i, row)
 
-    def _record(i: int, row: Dict, persist: bool = True) -> None:
-        rows[i] = row
-        if out_dir and persist:
-            write_cell_row(_cell_path(out_dir, cells[i]), row)
-        if verbose:
-            if row.get("quarantined"):
-                print(f"[sweep] {row['bench']}/{row['prefetcher']}"
-                      f" frac={row.get('device_frac')} QUARANTINED"
-                      f" after {row.get('retries')} retries", flush=True)
-            else:
-                print(f"[sweep] {row['bench']}/{row['prefetcher']}"
-                      f" frac={row.get('device_frac')}"
-                      f" backend={row.get('backend')}"
-                      f" hit={row['hit_rate']:.3f} ipc={row['ipc']:.2f}"
-                      f" ({row['seconds']:.2f}s)", flush=True)
-
-    # lane-batch scheduler: pack pallas-bound cells into multi-lane kernel
-    # launches in the parent process (they are already batched — worker
-    # fan-out would only serialize them again); whatever the backend
-    # declines falls back to the per-cell path below
-    lane_pending = [i for i in pending if _wants_lanes(cells[i])]
-    if lane_pending:
-        lane_rows = _run_lane_batches([cells[i] for i in lane_pending],
-                                      cache_dir, verbose=verbose)
-        for j, row in lane_rows.items():
-            _record(lane_pending[j], row)
-        handled = {lane_pending[j] for j in lane_rows}
-        pending = [i for i in pending if i not in handled]
-
-    # one process holds the chip: learned cells (predictor training and
-    # prediction) run here, before the fan-out; workers get the rest
-    fan_out = ([i for i in pending if cells[i].prefetcher != "learned"]
-               if workers > 1 else [])
-    for i in sorted(set(pending) - set(fan_out)):
-        if out_dir:
-            # leased execution: every cell resolves to a persisted result
-            # or a quarantine verdict, whatever crashes along the way
-            status, row = _run_cell_leased(i, cells[i], out_dir, cache_dir,
-                                           pol)
-            _record(i, row, persist=False)
-        else:
-            _record(i, simulate_cell(cells[i], cache_dir=cache_dir))
-    if fan_out and out_dir:
-        _lease_pool(cells, fan_out, out_dir, cache_dir, workers, pol,
-                    _record, verbose)
-    elif fan_out:
-        ctx = _mp_context()
-        with ctx.Pool(min(workers, len(fan_out)), initializer=_init_worker,
-                      initargs=(list(sys.path),)) as pool:
-            args = [(cells[i], cache_dir) for i in fan_out]
-            for i, row in zip(fan_out, pool.imap(_worker, args)):
-                _record(i, row)
-
-    out = [rows[i] for i in range(len(cells))]
-    if out_dir and write_aggregate:
-        write_results(out, out_dir)
-        _write_json_atomic(
-            os.path.join(out_dir, "quarantine.json"),
-            {"cells": [q for q in
-                       (_read_json(_cell_path(out_dir, c) + ".quarantine")
-                        for c in cells) if q is not None]})
+        out = [rows[i] for i in range(len(cells))]
+        if out_dir and write_aggregate:
+            write_results(out, out_dir)
+            _write_json_atomic(
+                os.path.join(out_dir, "quarantine.json"),
+                {"cells": [q for q in
+                           (_read_json(_cell_path(out_dir, c) + ".quarantine")
+                            for c in cells) if q is not None]})
+        obs.sample_rss()
     return out
 
 
