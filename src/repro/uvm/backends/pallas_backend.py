@@ -3,19 +3,33 @@
 Packs many compatible sweep cells into ONE lane-batched ``pl.pallas_call``:
 one lane per (trace, config) cell, traces padded to the longest lane, and
 per-lane residency/arrival/LRU-stamp state held as device arrays.  The
-kernel grid iterates over lanes.
+lanes of a batch advance in lockstep, one trace position per loop step.
 
 Lowering
 --------
 On every platform the kernel body is lowered by XLA, not by Mosaic:
-``pallas_call(..., interpret=True)`` takes Pallas's discharge path, which
-turns the grid into an XLA loop over lanes and the refs into array
-slices.  On a TPU that is one compiled device program per batch shape;
-on a CPU host it is the same program through XLA:CPU, which is what the
-tests run under ``JAX_PLATFORMS=cpu``.  Mosaic refuses this kernel as
-written (the ``(1, t_max)`` lane blocks and the float64 timing state;
-see ``README.md``), and lowering it there waits on a 32-bit timing
-model.
+``pallas_call(..., interpret=True)`` takes Pallas's discharge path,
+which turns the refs into arrays.  The grid has one step, whose blocks
+are the whole ``(n_lanes, ...)`` batch.  Inside it, one ``fori_loop``
+over trace positions runs to the batch's longest lane, and each step
+advances every lane by one access: the per-lane replay is
+``jax.vmap``-ed over the lane axis, and the eviction loop runs until no
+lane has a victim left.  A window of a lane's state (a basic block, a
+root window) is sliced from the lane's own row of the batch, one dynamic
+slice per lane: vmapped with per-lane starts, XLA:TPU would run it as a
+loop over the lanes or as an element-wise scatter.  A lane past its last
+access keeps its counters, clock and stall buffer (its page-sized state
+is never read again), and a lane that has no victim left evicts nothing
+while others do.  Each op of a step is bound by its fixed device
+latency, not its bytes, so L lanes in lockstep cost far less than L
+lanes one after another.  A 1-lane batch calls the per-lane replay
+directly, without ``vmap``: its loop runs to its own length with
+unbatched gathers and scatters and no per-lane selects, so a lane alone
+pays nothing for lockstep.  On a TPU that is one compiled device program
+per batch shape; on a CPU host it is the same program through XLA:CPU,
+which is what the tests run under ``JAX_PLATFORMS=cpu``.  Mosaic refuses
+this kernel as written (its float64 timing state; see ``README.md``),
+and lowering it there waits on a 32-bit timing model.
 
 Packable cells and lane families
 --------------------------------
@@ -77,13 +91,15 @@ independent NumPy replays, and ``tests/test_differential.py`` fuzzes all
 registered backend pairs.
 
 The per-lane state (arrival/stamp/pfu spans, tree counts) is carried
-through a ``lax.fori_loop`` over trace positions.  A Mosaic lowering
-would move the span state into scratch refs; the lane packing,
-parameter blocks, and stats layout here are already shaped for that.
+as ``(n_lanes, ...)`` arrays through the ``lax.fori_loop`` over trace
+positions.  A Mosaic lowering would move the span state into scratch
+refs; the lane packing, parameter blocks, and stats layout here are
+already shaped for that.
 """
 from __future__ import annotations
 
 import functools
+import types
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -209,7 +225,9 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
     ``policy`` is the eviction policy every lane of the batch runs under
     (a batch is policy-homogeneous: the victim-selection code and the
     extra per-lane carry — ``random`` priority draws, ``hotcold``
-    frequency counts — are static kernel structure).
+    frequency counts — are static kernel structure).  ``n_lanes == 1``
+    builds one lane's own loop over its accesses; a wider batch runs its
+    lanes in lockstep (module docstring, "Lowering").
 
     ``mt`` enables multi-tenant lane support (``repro.traces.interleave``):
     per-lane tenancy parameters (dense region boundary + per-tenant
@@ -228,13 +246,15 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
     records the post-access clock per window (the last write of a window
     is the clock after its last access — exactly the legacy recording
     point).  Slot ``steps_len`` is a trash slot for accesses past the
-    last bound and for no-bounds lanes of a mixed batch.  The clock
+    last bound, for no-bounds lanes of a mixed batch, and for a lane
+    past its last access while longer lanes run on.  The clock
     chain itself is untouched, so stats stay bit-identical with capture
     on; ``steps_len == 0`` builds the exact pre-capture kernel (no extra
     input, single output).
     """
     import jax
     import jax.numpy as jnp
+    from jax import custom_batching
     from jax.experimental import pallas as pl
 
     obs.count("lane.program_builds")       # runs once per cached shape
@@ -272,48 +292,111 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
     # indices never land on a real page.  The slot reads as resident
     # (arrival 0.0) and is never the LRU victim (stamp pinned at IMAX).
     state_len = span + 1 if family == "oracle" else span
-    n_inputs = ({"demand": 3, "tree": 3, "learned": 4, "oracle": 5}[family]
-                + (1 if steps_len else 0))
+    # the batch's input blocks, one row per lane, in the order
+    # ``_replay_batch`` passes them
+    in_names = (["pages"]
+                + {"learned": ["preds"], "oracle": ["ft", "pos"]}.get(
+                    family, [])
+                + (["sids"] if steps_len else []) + ["fp", "ip"])
+    n_inputs = len(in_names)
+    # the carry entries the eviction loop reads and writes
+    ev_keys = ["arrival", "stamp", "pfu", "counter", "resident", "evicted",
+               "wbacks", "pcie_free"]
+    if mt:
+        ev_keys.append("rc0")
+    if hotcold:
+        ev_keys.append("freq")
+    if family == "tree":
+        ev_keys.append("counts")
+    # page-sized state a lane past its last access may let go stale:
+    # nothing reads it again, and its step-clock writes go to the trash
+    # slot.  Every other carry entry of such a lane is held as it was.
+    page_state = ("arrival", "stamp", "pfu", "freq", "prio", "counts",
+                  "steps")
 
-    def kernel(*refs):
-        pages_ref = refs[0]
-        fparams_ref = refs[n_inputs - 2]
-        iparams_ref = refs[n_inputs - 1]
-        out_ref = refs[n_inputs]
-        if steps_len:
-            # the per-access window-id stream rides just before the
-            # parameter blocks; the per-window clock carry drains into a
-            # second output block
-            sids = refs[n_inputs - 3][0]
-            steps_out_ref = refs[n_inputs + 1]
+    # Windows of a lane's state: a dynamic slice or update.  Under vmap,
+    # JAX makes one with a per-lane start a gather or scatter, which
+    # XLA:TPU runs as a loop over the lanes or, element by element, as a
+    # serial scatter; so a batch slices or updates each lane's row of the
+    # (n_lanes, pages) array on its own, one dynamic slice per lane.
+    def _per_row(axis_size, in_batched, *args):
+        return [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+
+    def window(x, start, size):
+        @custom_batching.custom_vmap
+        def read(x, start):
+            return jax.lax.dynamic_slice(x, (start,), (size,))
+
+        @read.def_vmap
+        def _(axis_size, in_batched, x, start):
+            x, start = _per_row(axis_size, in_batched, x, start)
+            # rows go into a fresh block one by one: concatenated, they
+            # run slower on XLA:TPU and crash XLA:CPU's fusion emitters
+            out = jnp.zeros((axis_size, size), x.dtype)
+            for l in range(axis_size):
+                out = jax.lax.dynamic_update_slice(
+                    out, jax.lax.dynamic_slice(x, (i32(l), start[l]),
+                                               (1, size)), (i32(l), i32(0)))
+            return out, True
+
+        return read(x, start)
+
+    def put_window(x, vals, start):
+        @custom_batching.custom_vmap
+        def write(x, vals, start):
+            return jax.lax.dynamic_update_slice(x, vals, (start,))
+
+        @write.def_vmap
+        def _(axis_size, in_batched, x, vals, start):
+            x, vals, start = _per_row(axis_size, in_batched, x, vals, start)
+            for l in range(axis_size):
+                x = jax.lax.dynamic_update_slice(x, vals[l][None],
+                                                 (i32(l), start[l]))
+            return x, True
+
+        return write(x, vals, start)
+
+    def lane(L):
+        """One lane's replay, as functions of its input rows ``L``
+        (``in_names`` -> 1-D arrays): ``advance`` replays access ``t`` up
+        to the eviction loop, whose ``econd``/``ebody`` follow, and
+        ``finish`` drains the stall buffer into the stats row.  A 1-lane
+        batch calls them directly; a wider batch calls them under
+        ``jax.vmap``."""
         INF = jnp.float64(jnp.inf)
         IMAX = jnp.int32(IMAX_NP)
-        pages = pages_ref[0]
-        fp = fparams_ref[0]
+        IMAX64 = jnp.int64(IMAX64_NP)
+        zero = jnp.int32(0)
+        pages, fp, ip = L["pages"], L["fp"], L["ip"]
         cpa, page_tx, ff, ptw, pcie_lat = fp[0], fp[1], fp[2], fp[3], fp[4]
         pfo, extra_lat, page_size = fp[5], fp[6], fp[7]
-        n = iparams_ref[0, 0]
-        cap = iparams_ref[0, 1]
-        mshr = iparams_ref[0, 2]
-        has_block = iparams_ref[0, 3] > 0
+        n = ip[0]
+        cap = ip[1]
+        mshr = ip[2]
+        has_block = ip[3] > 0
         track_lru = cap >= 0
-        IMAX64 = jnp.int64(IMAX64_NP)
         if mt:
             # per-lane tenancy: dense boundary page (IMAX = single-tenant
             # lane: every page compares tenant 0 and the branches no-op),
             # per-tenant quotas (q0 < 0 = shared capacity)
-            bnd = iparams_ref[0, 6]
-            q0 = iparams_ref[0, 7]
-            q1 = iparams_ref[0, 8]
+            bnd = ip[6]
+            q0 = ip[7]
+            q1 = ip[8]
             tsplit = q0 >= 0
             slot_iota = jnp.arange(state_len, dtype=i32)
         if randomp:
-            # absolute page ids mod 2^32 per state slot: the random
-            # policy's priority draws hash the absolute page, so all
-            # backends agree whatever the lane's dense-span offset is
-            lane_lo = iparams_ref[0, 5].astype(u32)
-            abs_u32 = lane_lo + jnp.arange(state_len, dtype=i32).astype(u32)
+            # absolute page ids mod 2^32: the random policy's priority
+            # draws hash the absolute page, so all backends agree
+            # whatever the lane's dense-span offset is
+            lane_lo = ip[5].astype(u32)
             iota64 = jnp.arange(state_len, dtype=jnp.int64)
+
+            def abs_page(slots):
+                return lane_lo + slots.astype(u32)
+        if steps_len:
+            sids = L["sids"]
+
         # The legacy loop rounds every multiply before the dependent add,
         # but LLVM contracts ``a + b * c`` into a fused multiply-add
         # (single rounding, 1-ULP drift vs CPython) and neither
@@ -325,14 +408,14 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
             return jnp.abs(x)
 
         if family == "learned":
-            preds = refs[1][0]
+            preds = L["preds"]
         if family == "oracle":
-            ft = refs[1][0]
-            posarr = refs[2][0]
-            n_ft = iparams_ref[0, 4]
+            ft = L["ft"]
+            posarr = L["pos"]
+            n_ft = ip[4]
             look_iota = jnp.arange(lookahead, dtype=i32)
 
-        def step(t, s):
+        def advance(t, s, active):
             arrival, stamp, pfu = s["arrival"], s["stamp"], s["pfu"]
             buf = s["buf"]
             counter = s["counter"]
@@ -385,7 +468,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
             if randomp:
                 # insert-time priority draw, seeded by the touch counter
                 prio = prio.at[p].set(jnp.where(
-                    is_fault, _rand_score(abs_u32[p], counter), prio[p]))
+                    is_fault, _rand_score(abs_page(p), counter), prio[p]))
             counter = counter + 1
             resident = s["resident"] + is_fault.astype(i32)
             if mt:
@@ -418,7 +501,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 # basic block's non-resident pages (the demand page is
                 # already in flight, so the window compare excludes it)
                 blk = (p // blk_pages) * blk_pages
-                win = jax.lax.dynamic_slice(arrival, (blk,), (blk_pages,))
+                win = window(arrival, blk, blk_pages)
                 mask = (win == INF) & is_fault & has_block
                 k = jnp.sum(mask, dtype=i32)
                 kf = k.astype(jnp.float64)
@@ -426,26 +509,22 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 ex_start = jnp.maximum(pcie_free, ex_ready)
                 end = ex_start + _nofma(kf * page_tx)
                 ex_arr = end + pcie_lat          # batch completes as one DMA
-                arrival = jax.lax.dynamic_update_slice(
-                    arrival, jnp.where(mask, ex_arr, win), (blk,))
-                pwin = jax.lax.dynamic_slice(pfu, (blk,), (blk_pages,))
-                pfu = jax.lax.dynamic_update_slice(pfu, pwin | mask, (blk,))
-                swin = jax.lax.dynamic_slice(stamp, (blk,), (blk_pages,))
+                arrival = put_window(arrival, jnp.where(mask, ex_arr, win),
+                                     blk)
+                pwin = window(pfu, blk, blk_pages)
+                pfu = put_window(pfu, pwin | mask, blk)
+                swin = window(stamp, blk, blk_pages)
                 rank = counter + jnp.cumsum(mask, dtype=i32) - 1
-                stamp = jax.lax.dynamic_update_slice(
-                    stamp, jnp.where(mask, rank, swin), (blk,))
+                stamp = put_window(stamp, jnp.where(mask, rank, swin), blk)
                 if hotcold:
-                    fwin = jax.lax.dynamic_slice(freq, (blk,), (blk_pages,))
-                    freq = jax.lax.dynamic_update_slice(
-                        freq, jnp.where(mask, 0, fwin), (blk,))
+                    fwin = window(freq, blk, blk_pages)
+                    freq = put_window(freq, jnp.where(mask, 0, fwin), blk)
                 if randomp:
-                    uwin = jax.lax.dynamic_slice(abs_u32, (blk,),
-                                                 (blk_pages,))
-                    prwin = jax.lax.dynamic_slice(prio, (blk,), (blk_pages,))
-                    prio = jax.lax.dynamic_update_slice(
-                        prio,
-                        jnp.where(mask, _rand_score(uwin, rank), prwin),
-                        (blk,))
+                    uwin = abs_page(blk + jnp.arange(blk_pages, dtype=i32))
+                    prwin = window(prio, blk, blk_pages)
+                    prio = put_window(
+                        prio, jnp.where(mask, _rand_score(uwin, rank), prwin),
+                        blk)
                 counter = counter + k
                 resident = resident + k
                 if mt:
@@ -462,7 +541,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 # ascending page order (the legacy list order), which the
                 # per-level cumsum ranks reproduce so LRU stamps match.
                 root = (p // ROOT_PAGES) * ROOT_PAGES
-                rwin = jax.lax.dynamic_slice(arrival, (root,), (ROOT_PAGES,))
+                rwin = window(arrival, root, ROOT_PAGES)
                 nonres = rwin == INF
                 offs = jnp.arange(ROOT_PAGES, dtype=i32)
                 rel = p - root
@@ -493,28 +572,24 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 ex_start = jnp.maximum(pcie_free, ex_ready)
                 end = ex_start + _nofma(kf * page_tx)
                 ex_arr = end + pcie_lat
-                arrival = jax.lax.dynamic_update_slice(
-                    arrival, jnp.where(out_mask, ex_arr, rwin), (root,))
-                pwin = jax.lax.dynamic_slice(pfu, (root,), (ROOT_PAGES,))
-                pfu = jax.lax.dynamic_update_slice(
-                    pfu, pwin | out_mask, (root,))
-                swin = jax.lax.dynamic_slice(stamp, (root,), (ROOT_PAGES,))
-                stamp = jax.lax.dynamic_update_slice(
-                    stamp, jnp.where(out_mask, counter + rank, swin), (root,))
+                arrival = put_window(
+                    arrival, jnp.where(out_mask, ex_arr, rwin), root)
+                pwin = window(pfu, root, ROOT_PAGES)
+                pfu = put_window(pfu, pwin | out_mask, root)
+                swin = window(stamp, root, ROOT_PAGES)
+                stamp = put_window(
+                    stamp, jnp.where(out_mask, counter + rank, swin), root)
                 if hotcold:
-                    fwin = jax.lax.dynamic_slice(freq, (root,), (ROOT_PAGES,))
-                    freq = jax.lax.dynamic_update_slice(
-                        freq, jnp.where(out_mask, 0, fwin), (root,))
+                    fwin = window(freq, root, ROOT_PAGES)
+                    freq = put_window(freq, jnp.where(out_mask, 0, fwin), root)
                 if randomp:
-                    uwin = jax.lax.dynamic_slice(abs_u32, (root,),
-                                                 (ROOT_PAGES,))
-                    prwin = jax.lax.dynamic_slice(prio, (root,),
-                                                  (ROOT_PAGES,))
-                    prio = jax.lax.dynamic_update_slice(
+                    uwin = abs_page(root + offs)
+                    prwin = window(prio, root, ROOT_PAGES)
+                    prio = put_window(
                         prio,
                         jnp.where(out_mask,
                                   _rand_score(uwin, counter + rank), prwin),
-                        (root,))
+                        root)
                 counter = counter + k
                 resident = resident + k
                 if mt:
@@ -533,10 +608,8 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                         out_mask.reshape(n_nodes, node_span).astype(i32),
                         axis=1, dtype=i32)
                     node0 = root >> (blk_shift + lv)
-                    cwin = jax.lax.dynamic_slice(
-                        counts[lv], (node0,), (n_nodes,))
-                    counts[lv] = jax.lax.dynamic_update_slice(
-                        counts[lv], cwin + inc, (node0,))
+                    cwin = window(counts[lv], node0, n_nodes)
+                    counts[lv] = put_window(counts[lv], cwin + inc, node0)
 
             if family == "learned":
                 # LearnedPrefetcher.on_access: serialized inference server
@@ -566,7 +639,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                         jnp.where(do_pf, 0, freq[safe]))
                 if randomp:
                     prio = prio.at[safe].set(jnp.where(
-                        do_pf, _rand_score(abs_u32[safe], counter),
+                        do_pf, _rand_score(abs_page(safe), counter),
                         prio[safe]))
                 pfu = pfu.at[safe].set(do_pf | pfu[safe])
                 counter = counter + do_pf.astype(i32)
@@ -586,7 +659,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 # second scan seeing the first's insertions.
                 pos_t = posarr[t]
                 base_valid = (pos_t + look_iota) < n_ft
-                win_idx = jax.lax.dynamic_slice(ft, (pos_t,), (lookahead,))
+                win_idx = window(ft, pos_t, lookahead)
 
                 def scan(arrival, stamp, pfu, counter, resident, migrated,
                          issued, pcie_free, pol, rc0, active, batch):
@@ -633,7 +706,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                         pol = (freq,)
                     if randomp:
                         (prio,) = pol
-                        prw = _rand_score(abs_u32[win_idx], counter + rank)
+                        prw = _rand_score(abs_page(win_idx), counter + rank)
                         prio = prio.at[tgt].set(
                             jnp.where(take, prw, prio[tgt]))
                         pol = (prio,)
@@ -678,130 +751,153 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
             nbuf = nbuf - pop.astype(i32)
 
             if steps_len:
-                # the clock is final for this access here (eviction below
-                # never moves it), so the window slot ends up holding the
-                # clock after its last access — the legacy recording point
-                steps = s["steps"].at[sids[t]].set(clock)
-
-            # eviction under oversubscription: the policy picks the victim
-            # (lru = min touch stamp, exact OrderedDict order; random =
-            # min insert-time priority draw; hotcold = min (freq, stamp));
-            # an in-flight victim is retouched at MRU and stops the loop
-            def _allowed(c):
-                """Per-tenant residency ceilings (Tenancy.allowed in
-                int32) + the over-allowance flags of a quota-split lane."""
-                rc0c = c["rc0"]
-                rc1c = c["resident"] - rc0c
-                spill = cap - q0 - q1
-                a0 = q0 + jnp.maximum(0, spill - jnp.maximum(0, rc1c - q1))
-                a1 = q1 + jnp.maximum(0, spill - jnp.maximum(0, rc0c - q0))
-                return rc0c > a0, rc1c > a1
-
-            def econd(c):
-                if mt:
-                    over0, over1 = _allowed(c)
-                    return c["cont"] & jnp.where(
-                        tsplit, over0 | over1, c["resident"] > cap)
-                return c["cont"] & (c["resident"] > cap)
-
-            def ebody(c):
-                arrival, stamp, pfu = c["arrival"], c["stamp"], c["pfu"]
-                counter = c["counter"]
-                res_mask = arrival < INF
-                if mt:
-                    # quota split: trim whichever tenant is over its
-                    # allowance (tenant 0 first, like the legacy loop),
-                    # victim masked to that tenant's state slots; shared
-                    # mode keeps the unmasked single-tenant selection
-                    over0, _ = _allowed(c)
-                    u = jnp.where(over0, 0, 1)
-                    res_mask = res_mask & (
-                        ~tsplit | ((slot_iota >= bnd).astype(i32) == u))
-                if hotcold:
-                    fq = c["freq"]
-                    key = jnp.where(
-                        res_mask & (stamp < IMAX),
-                        (fq.astype(jnp.int64) << 32)
-                        | stamp.astype(jnp.int64), IMAX64)
-                    vi = jnp.argmin(key)
-                elif randomp:
-                    # prio is static while resident: safe to close over
-                    key = jnp.where(
-                        res_mask & (stamp < IMAX),
-                        (prio.astype(jnp.int64) << 21) | iota64, IMAX64)
-                    vi = jnp.argmin(key)
-                else:
-                    vi = jnp.argmin(jnp.where(res_mask, stamp, IMAX))
-                v_arr = arrival[vi]
-                in_flight = v_arr > clock
-                stamp = stamp.at[vi].set(
-                    jnp.where(in_flight, counter, stamp[vi]))
-                if hotcold:
-                    fq = fq.at[vi].add(in_flight.astype(i32))
-                counter = counter + in_flight.astype(i32)
-                arrival = arrival.at[vi].set(
-                    jnp.where(in_flight, v_arr, INF))
-                pfu = pfu.at[vi].set(jnp.where(in_flight, pfu[vi], False))
-                ev = (~in_flight).astype(i32)
-                resident = c["resident"] - ev
-                evicted = c["evicted"] + ev
-                # writeback traffic (half the evictions dirty)
-                wb = (~in_flight) & (evicted % 2 == 0)
-                wbacks = c["wbacks"] + wb.astype(i32)
-                pcie_free = c["pcie_free"] + jnp.where(wb, page_tx, 0.0)
-                out = dict(c, cont=~in_flight, arrival=arrival, stamp=stamp,
-                           pfu=pfu, counter=counter, resident=resident,
-                           evicted=evicted, wbacks=wbacks,
-                           pcie_free=pcie_free)
-                if mt:
-                    out["rc0"] = c["rc0"] - ((~in_flight)
-                                             & (vi < bnd)).astype(i32)
-                if hotcold:
-                    out["freq"] = fq
-                if family == "tree":
-                    cts = list(c["counts"])
-                    for lv in range(levels + 1):
-                        cts[lv] = cts[lv].at[vi >> (blk_shift + lv)].add(-ev)
-                    out["counts"] = tuple(cts)
-                return out
-
-            ecarry = {"cont": track_lru, "arrival": arrival, "stamp": stamp,
-                      "pfu": pfu, "counter": counter, "resident": resident,
-                      "evicted": s["evicted"], "wbacks": s["wbacks"],
-                      "pcie_free": pcie_free}
-            if mt:
-                ecarry["rc0"] = rc0
-            if hotcold:
-                ecarry["freq"] = freq
-            if family == "tree":
-                ecarry["counts"] = tuple(counts)
-            ecarry = jax.lax.while_loop(econd, ebody, ecarry)
+                # the clock is final for this access here (eviction never
+                # moves it), so the window slot ends up holding the clock
+                # after its last access — the legacy recording point.  A
+                # lane past its last access writes the trash slot.
+                sid = sids[t]
+                if active is not None:
+                    sid = jnp.where(active, sid, steps_len)
+                steps = s["steps"].at[sid].set(clock)
 
             out = {
-                "arrival": ecarry["arrival"], "stamp": ecarry["stamp"],
-                "pfu": ecarry["pfu"], "buf": buf,
-                "clock": clock, "pcie_free": ecarry["pcie_free"],
-                "counter": ecarry["counter"],
-                "resident": ecarry["resident"], "nbuf": nbuf,
+                "arrival": arrival, "stamp": stamp, "pfu": pfu, "buf": buf,
+                "clock": clock, "pcie_free": pcie_free, "counter": counter,
+                "resident": resident, "nbuf": nbuf,
                 "hits": hits, "late": late, "faults": faults,
                 "issued": issued, "used": used, "migrated": migrated,
-                "evicted": ecarry["evicted"], "wbacks": ecarry["wbacks"],
+                "evicted": s["evicted"], "wbacks": s["wbacks"],
             }
             if mt:
-                out["rc0"] = ecarry["rc0"]
+                out["rc0"] = rc0
                 out["th0"] = th0
             if family == "learned":
                 out["next_free"] = next_free
             if family == "tree":
-                out["counts"] = ecarry["counts"]
+                out["counts"] = tuple(counts)
             if hotcold:
-                out["freq"] = ecarry["freq"]
+                out["freq"] = freq
             if randomp:
                 out["prio"] = prio
             if steps_len:
                 out["steps"] = steps
+            # the eviction loop runs while ``cont`` holds and the lane is
+            # over its capacity; a lane past its last access takes no part
+            cont = track_lru if active is None else track_lru & active
+            return out, cont
+
+        # eviction under oversubscription: the policy picks the victim
+        # (lru = min touch stamp, exact OrderedDict order; random =
+        # min insert-time priority draw; hotcold = min (freq, stamp));
+        # an in-flight victim is retouched at MRU and stops the loop
+        def _allowed(c):
+            """Per-tenant residency ceilings (Tenancy.allowed in
+            int32) + the over-allowance flags of a quota-split lane."""
+            rc0c = c["rc0"]
+            rc1c = c["resident"] - rc0c
+            spill = cap - q0 - q1
+            a0 = q0 + jnp.maximum(0, spill - jnp.maximum(0, rc1c - q1))
+            a1 = q1 + jnp.maximum(0, spill - jnp.maximum(0, rc0c - q0))
+            return rc0c > a0, rc1c > a1
+
+        def econd(c):
+            if mt:
+                over0, over1 = _allowed(c)
+                return c["cont"] & jnp.where(
+                    tsplit, over0 | over1, c["resident"] > cap)
+            return c["cont"] & (c["resident"] > cap)
+
+        def ebody(c, s, go):
+            """One eviction of the access that left ``s``.  ``go`` (a
+            lockstep batch only) is this lane's ``econd``: where it is
+            false every write is a no-op, so the lane's state stays as it
+            is while the batch's other lanes evict."""
+            clock = s["clock"]
+            arrival, stamp, pfu = c["arrival"], c["stamp"], c["pfu"]
+            counter = c["counter"]
+            res_mask = arrival < INF
+            if mt:
+                # quota split: trim whichever tenant is over its
+                # allowance (tenant 0 first, like the legacy loop),
+                # victim masked to that tenant's state slots; shared
+                # mode keeps the unmasked single-tenant selection
+                over0, _ = _allowed(c)
+                u = jnp.where(over0, 0, 1)
+                res_mask = res_mask & (
+                    ~tsplit | ((slot_iota >= bnd).astype(i32) == u))
+            if hotcold:
+                fq = c["freq"]
+                key = jnp.where(
+                    res_mask & (stamp < IMAX),
+                    (fq.astype(jnp.int64) << 32)
+                    | stamp.astype(jnp.int64), IMAX64)
+                vi = jnp.argmin(key)
+            elif randomp:
+                # prio is static while resident: read it from ``s``
+                key = jnp.where(
+                    res_mask & (stamp < IMAX),
+                    (s["prio"].astype(jnp.int64) << 21) | iota64, IMAX64)
+                vi = jnp.argmin(key)
+            else:
+                vi = jnp.argmin(jnp.where(res_mask, stamp, IMAX))
+            v_arr = arrival[vi]
+            in_flight = v_arr > clock
+            # retouched (in flight) or evicted, on a lane that evicts
+            retouch = in_flight if go is None else go & in_flight
+            evict = ~in_flight if go is None else go & ~in_flight
+            stamp = stamp.at[vi].set(
+                jnp.where(retouch, counter, stamp[vi]))
+            if hotcold:
+                fq = fq.at[vi].add(retouch.astype(i32))
+            counter = counter + retouch.astype(i32)
+            arrival = arrival.at[vi].set(jnp.where(evict, INF, v_arr))
+            pfu = pfu.at[vi].set(jnp.where(evict, False, pfu[vi]))
+            ev = evict.astype(i32)
+            resident = c["resident"] - ev
+            evicted = c["evicted"] + ev
+            # writeback traffic (half the evictions dirty)
+            wb = evict & (evicted % 2 == 0)
+            wbacks = c["wbacks"] + wb.astype(i32)
+            pcie_free = c["pcie_free"] + jnp.where(wb, page_tx, 0.0)
+            cont = ~in_flight if go is None else c["cont"] & ~retouch
+            out = dict(c, cont=cont, arrival=arrival, stamp=stamp, pfu=pfu,
+                       counter=counter, resident=resident, evicted=evicted,
+                       wbacks=wbacks, pcie_free=pcie_free)
+            if mt:
+                out["rc0"] = c["rc0"] - (evict & (vi < bnd)).astype(i32)
+            if hotcold:
+                out["freq"] = fq
+            if family == "tree":
+                cts = list(c["counts"])
+                for lv in range(levels + 1):
+                    cts[lv] = cts[lv].at[vi >> (blk_shift + lv)].add(-ev)
+                out["counts"] = tuple(cts)
             return out
 
+        def finish(f):
+            """Stats row (and step clocks) of the final state: every
+            outstanding stall resolves (max over the buffer is the max
+            over any heap-pop order)."""
+            buf = f["buf"]
+            tail = jnp.max(jnp.where(buf < jnp.inf, buf, -jnp.inf))
+            clock = jnp.where(f["nbuf"] > 0,
+                              jnp.maximum(f["clock"], tail), f["clock"])
+            cols = [clock] + [f[k].astype(jnp.float64) for k in (
+                "hits", "late", "faults", "issued", "used", "migrated",
+                "evicted")]
+            cols.append((f["migrated"] + f["wbacks"]).astype(jnp.float64)
+                        * page_size)
+            if mt:
+                cols.append(f["th0"].astype(jnp.float64))
+            row = jnp.stack(cols)
+            if steps_len:
+                return row, f["steps"][:steps_len]
+            return row
+
+        return types.SimpleNamespace(n=n, advance=advance, econd=econd,
+                                     ebody=ebody, finish=finish)
+
+    def init_state():
         zero = jnp.int32(0)
         init = {
             "arrival": jnp.full((state_len,), jnp.inf, dtype=jnp.float64),
@@ -820,7 +916,7 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
         if family == "oracle":
             # trash slot: reads resident, never the LRU victim
             init["arrival"] = init["arrival"].at[span].set(0.0)
-            init["stamp"] = init["stamp"].at[span].set(IMAX)
+            init["stamp"] = init["stamp"].at[span].set(IMAX_NP)
         if family == "learned":
             init["next_free"] = jnp.float64(0.0)
         if family == "tree":
@@ -835,56 +931,78 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
             # +1 trash slot: accesses past the last bound (and no-bounds
             # lanes of a mixed batch) scatter there instead of a window
             init["steps"] = jnp.zeros((steps_len + 1,), dtype=jnp.float64)
-        final = jax.lax.fori_loop(0, n, step, init)
+        return init
 
-        # drain: every outstanding stall resolves (max over the buffer is
-        # the max over any heap-pop order)
-        buf = final["buf"]
-        tail = jnp.max(jnp.where(buf < jnp.inf, buf, -jnp.inf))
-        clock = jnp.where(final["nbuf"] > 0,
-                          jnp.maximum(final["clock"], tail), final["clock"])
+    def evict(ln, s, cont):
+        """The eviction loop of one lane after ``ln.advance``."""
+        c = jax.lax.while_loop(
+            ln.econd, lambda c: ln.ebody(c, s, None),
+            dict({k: s[k] for k in ev_keys}, cont=cont))
+        return dict(s, **{k: c[k] for k in ev_keys})
 
+    def hold(active, new, old):
+        """``new`` where the lane is active, else ``old`` (page state
+        excepted, see ``page_state``)."""
+        return {k: v if k in page_state else jnp.where(active, v, old[k])
+                for k, v in new.items()}
+
+    def kernel(*refs):
+        rows = dict(zip(in_names, (r[...] for r in refs[:n_inputs])))
+        if n_lanes == 1:
+            # one lane: its own loop over its accesses, unbatched
+            ln = lane({k: v[0] for k, v in rows.items()})
+            final = jax.lax.fori_loop(
+                0, ln.n, lambda t, s: evict(ln, *ln.advance(t, s, None)),
+                init_state())
+            out = jax.tree.map(lambda x: x[None], ln.finish(final))
+        else:
+            # lockstep: one loop over trace positions up to the batch's
+            # longest lane; each step advances every lane by one access
+            def per_lane(fn):
+                return jax.vmap(lambda L, *a: fn(lane(L), *a))
+
+            lane_go = per_lane(lambda ln, c: ln.econd(c))
+            lane_evict = per_lane(lambda ln, c, s, go: ln.ebody(c, s, go))
+
+            def lockstep(t, s):
+                def advance(ln, s):
+                    active = t < ln.n
+                    return ln.advance(t, s, active) + (active,)
+
+                new, cont, active = per_lane(advance)(rows, s)
+                # evictions in lockstep too, until no lane has a victim
+                # left; a lane that is done evicts nothing meanwhile
+                c = jax.lax.while_loop(
+                    lambda c: jnp.any(lane_go(rows, c)),
+                    lambda c: lane_evict(rows, c, new, lane_go(rows, c)),
+                    dict({k: new[k] for k in ev_keys}, cont=cont))
+                new = dict(new, **{k: c[k] for k in ev_keys})
+                return jax.vmap(hold)(active, new, s)
+
+            init = jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (n_lanes,) + x.shape),
+                init_state())
+            final = jax.lax.fori_loop(0, jnp.max(rows["ip"][:, 0]),
+                                      lockstep, init)
+            out = per_lane(lambda ln, f: ln.finish(f))(rows, final)
         if steps_len:
-            steps_out_ref[0, :] = final["steps"][:steps_len]
-        out_ref[0, 0] = clock
-        out_ref[0, 1] = final["hits"].astype(jnp.float64)
-        out_ref[0, 2] = final["late"].astype(jnp.float64)
-        out_ref[0, 3] = final["faults"].astype(jnp.float64)
-        out_ref[0, 4] = final["issued"].astype(jnp.float64)
-        out_ref[0, 5] = final["used"].astype(jnp.float64)
-        out_ref[0, 6] = final["migrated"].astype(jnp.float64)
-        out_ref[0, 7] = final["evicted"].astype(jnp.float64)
-        out_ref[0, 8] = ((final["migrated"] + final["wbacks"])
-                         .astype(jnp.float64) * page_size)
-        if mt:
-            out_ref[0, 9] = final["th0"].astype(jnp.float64)
+            refs[n_inputs][...], refs[n_inputs + 1][...] = out
+        else:
+            refs[n_inputs][...] = out
 
-    in_specs = [pl.BlockSpec((1, t_max), lambda l: (l, 0))]
-    if family == "learned":
-        in_specs.append(pl.BlockSpec((1, t_max), lambda l: (l, 0)))
-    if family == "oracle":
-        in_specs.append(pl.BlockSpec((1, ft_len), lambda l: (l, 0)))
-        in_specs.append(pl.BlockSpec((1, t_max), lambda l: (l, 0)))
-    if steps_len:
-        in_specs.append(pl.BlockSpec((1, t_max), lambda l: (l, 0)))
-    in_specs += [pl.BlockSpec((1, _N_FPARAMS), lambda l: (l, 0)),
-                 pl.BlockSpec((1, _N_IPARAMS), lambda l: (l, 0))]
     n_stats = len(STAT_FIELDS) + (len(MT_STAT_FIELDS) if mt else 0)
-    out_specs = pl.BlockSpec((1, n_stats), lambda l: (l, 0))
     out_shape = jax.ShapeDtypeStruct((n_lanes, n_stats), jnp.float64)
     if steps_len:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, steps_len), lambda l: (l, 0))]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((n_lanes, steps_len),
                                           jnp.float64)]
     # interpret=True is Pallas's discharge lowering: an XLA program on
-    # every platform (see the module docstring, "Lowering")
+    # every platform.  One grid step holds the whole batch: every block
+    # is the full (n_lanes, ...) array (see the module docstring,
+    # "Lowering")
     call = pl.pallas_call(
         kernel,
-        grid=(n_lanes,),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid=(1,),
         out_shape=out_shape,
         interpret=True,
     )
@@ -1051,6 +1169,9 @@ class PallasReplayBackend(ReplayBackend):
         obs.count("lane.batches")
         obs.count("lane.lanes", lanes)
         obs.count("lane.accesses", sum(len(r.trace.pages) for r in requests))
+        # device loop steps: the lanes advance in lockstep, so a batch
+        # runs as many steps as its longest lane has accesses
+        obs.count("lane.steps", max(len(r.trace.pages) for r in requests))
         with obs.span("lane.pad", family=family, policy=policy, lanes=lanes):
             shapes = [_lane_shape(r) for r in requests]
             t_max = _bucket(max(t for _, _, t, _ in shapes), 64)

@@ -9,7 +9,7 @@ including ragged trace lengths and oversubscribed (LRU-evicting) cells.
 import numpy as np
 import pytest
 
-from repro.traces.trace import Trace, make_records
+from repro.traces.trace import ROOT_PAGES, Trace, make_records
 from repro.uvm import UVMConfig
 from repro.uvm.backends.pallas_backend import (MAX_LANE_SPAN_PAGES,
                                                MAX_LANES_PER_BATCH,
@@ -449,6 +449,118 @@ def test_all_family_lane_replay_matches_numpy():
     for (name, _, cap, _), g, w in zip(cases, got, want):
         assert g.backend == "pallas"
         _assert_equivalent(g, w, context=f"{name} cap={cap}")
+
+
+# ---------------------------------------------------------------------------
+# lockstep: a lane batch equals each of its lanes replayed alone
+# ---------------------------------------------------------------------------
+
+#: the prefetcher a lockstep case runs for each lane family
+LOCKSTEP_PREFETCHER = {"demand": "block", "tree": "tree",
+                       "learned": "learned", "oracle": "oracle"}
+MT_TEST_BOUNDARY = 2 * ROOT_PAGES
+
+
+def _ragged_lanes():
+    """Three lanes of unequal length (a 4-lane batch with one padding
+    lane): two evict, at different steps, and one never does."""
+    perm = (np.arange(320) * 7) % 320
+    return [(np.tile(perm, 2), 140),
+            (np.arange(0, 1500, 4) % 480, 90),
+            (np.random.default_rng(3).integers(0, 400, size=230), None)]
+
+
+def _mk_mt_trace(pages0, pages1):
+    """Two page streams as one interleaved two-tenant trace, tenant 1
+    rebased above a root-aligned boundary (the interleaver's layout)."""
+    pages0 = np.asarray(pages0, dtype=np.int64)
+    pages1 = np.asarray(pages1, dtype=np.int64) + MT_TEST_BOUNDARY
+    na, nb = len(pages0), len(pages1)
+    keys = np.concatenate([np.arange(1, na + 1) * nb,
+                           np.arange(1, nb + 1) * na])
+    pages = np.concatenate([pages0, pages1])[np.argsort(keys,
+                                                        kind="stable")]
+    recs = make_records(len(pages))
+    recs["page"] = pages
+    return Trace("synth-mt", recs, {}, {}, len(pages) * 100,
+                 meta={"mt": {"benches": ["A", "B"], "tenants": 2,
+                              "boundary": MT_TEST_BOUNDARY}})
+
+
+def _observed(st):
+    """Everything a row reads from a lane's replay."""
+    fields = {f: getattr(st, f) for f in INT_FIELDS + (
+        "cycles", "pcie_bytes", "backend", "tenant_hits")}
+    fields["step_clocks"] = (None if st.step_clocks is None
+                             else st.step_clocks.tolist())
+    return fields
+
+
+def _lockstep_cases():
+    cases = {}
+    for family, pf_name in LOCKSTEP_PREFETCHER.items():
+        for policy in ("lru", "random", "hotcold"):
+            cases[f"{family}-{policy}-ragged"] = [
+                (_mk_trace(pages), pf_name, cap, policy, None, None)
+                for pages, cap in _ragged_lanes()]
+    # equal lengths and no padding lane: what a sweep grid over one
+    # trace packs
+    sweep = np.tile((np.arange(400) * 3) % 400, 2)
+    cases["demand-lru-equal"] = [
+        (_mk_trace(sweep), pf_name, cap, "lru", None, None)
+        for pf_name in ("none", "block") for cap in (150, 250)]
+    rng = np.random.default_rng(5)
+    a, b = rng.integers(0, 700, 300), np.tile(np.arange(350), 2)
+    cases["tree-hotcold-mt"] = [
+        (_mk_mt_trace(a, b), "tree", 240, "hotcold", None, (100, 100)),
+        (_mk_mt_trace(b[:500], a), "tree", 180, "hotcold", None, None),
+        (_mk_trace(a), "tree", 120, "hotcold", None, None)]
+    cases["block-random-steps"] = [
+        (_mk_trace(pages), "block", cap, "random", bounds, None)
+        for (pages, cap), bounds in zip(_ragged_lanes(), (
+            np.array([0, 40, 40, 300, 600]), None,
+            np.arange(10, 231, 20)))]
+    return cases
+
+
+LOCKSTEP_CASES = _lockstep_cases()
+
+
+@pytest.mark.parametrize("case", list(LOCKSTEP_CASES))
+def test_lockstep_batch_equals_lanes_alone(case):
+    """A lane batch replays every lane in lockstep: each of its rows is
+    bit-equal to that lane replayed alone (a 1-lane batch), and agrees
+    with the NumPy backend.  Every family under every policy, on lanes of
+    unequal length with a padding lane, whose evictions fall at different
+    steps; plus equal lengths, a multi-tenant and a step-clock batch."""
+    lanes = LOCKSTEP_CASES[case]
+
+    def build(i):
+        trace, pf_name, cap, policy, bounds, quotas = lanes[i]
+        config = UVMConfig(device_pages=cap, mshr_entries=16,
+                           eviction=policy, tenant_pages=quotas)
+        return ReplayRequest(trace, golden_prefetcher(pf_name, trace,
+                                                      config), config,
+                             step_bounds=bounds)
+
+    backend = get_backend("pallas")
+    requests = [build(i) for i in range(len(lanes))]
+    assert all(backend.can_replay(r) for r in requests)
+    assert len(backend.pack_lanes(requests)) == 1, "not one batch"
+    together = backend.replay(requests)
+    for i, got in enumerate(together):
+        alone, = backend.replay([build(i)])
+        assert _observed(got) == _observed(alone), f"{case} lane {i}"
+        want = dispatch(build(i), "numpy")
+        _assert_equivalent(got, want, context=f"{case} lane {i}")
+        if want.tenant_hits is not None:
+            assert got.tenant_hits == want.tenant_hits, f"{case} lane {i}"
+        if want.step_clocks is not None:
+            np.testing.assert_allclose(got.step_clocks, want.step_clocks,
+                                       rtol=1e-6)
+    evicted = [st.pages_evicted for st in together]
+    assert len({e for e in evicted if e > 0}) >= 2 or case.endswith(
+        "-equal"), f"vacuous: lanes evict alike {evicted}"
 
 
 # ---------------------------------------------------------------------------

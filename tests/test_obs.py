@@ -218,3 +218,25 @@ def test_predictor_spans_and_cache_counters(tmp_path):
     assert rec.counters["predcache.misses"] == 1
     assert rec.counters["predcache.stores"] == 1
     assert rec.counters["predcache.hits"] == 2
+
+
+@pytest.mark.parametrize("lanes", [4, 1])
+def test_lane_steps_count_the_lockstep_loop(lanes):
+    """``lane.steps`` counts the device loop's steps: a batch of
+    equal-length lanes runs one step per trace position for all of them
+    (``lane.accesses / lane.steps`` lanes a step), a 1-lane batch one
+    step per access."""
+    from repro.uvm import UVMConfig
+    from repro.uvm.prefetchers import NoPrefetcher
+    from repro.uvm.replay_core import ReplayRequest, get_backend
+
+    pages = np.tile(np.arange(90), 3)
+    requests = [ReplayRequest(_mk_trace(pages), NoPrefetcher(),
+                              UVMConfig(device_pages=40 + 10 * i))
+                for i in range(lanes)]
+    with obs.record():
+        get_backend("pallas").replay(requests)
+    rec = obs.take()
+    assert rec.counters["lane.batches"] == 1
+    assert rec.counters["lane.steps"] == len(pages)
+    assert rec.counters["lane.accesses"] == lanes * len(pages)
