@@ -20,6 +20,7 @@ limits.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List
 
 import numpy as np
@@ -28,22 +29,10 @@ from bench.reference import replay as ref_replay
 from bench.reference import tracegen
 
 
-def reference_trace(config: Dict, seed: int):
-    return tracegen.build_trace(config["bench"], config["scale"], seed,
-                                config["window"])
-
-
 def reference_rows(tr, sweep_cells, precise: bool = True) -> List[Dict]:
     """One reference row per sweep cell on the reference trace ``tr``."""
-    ws = tr.working_set_pages
-    out = []
-    for c in sweep_cells:
-        cap = (int(ws * c.device_frac) if c.device_frac is not None
-               else c.device_pages)
-        out.append(ref_replay.replay(tr.pages, tr.n_instructions,
-                                     c.prefetcher, cap, c.eviction,
-                                     precise=precise))
-    return out
+    return [ref_replay.replay(tr, dataclasses.asdict(c), precise=precise)
+            for c in sweep_cells]
 
 
 def row_checks(grids: List[List[Dict]], ref: List[Dict]) -> Dict:
@@ -83,7 +72,7 @@ def check_window(config: Dict, program_traces: Dict, grids: List,
     checks = {"trace_records_differ": 0, "int_mismatches": 0,
               "float_rel_gap": 0.0}
     for ts, program_trace in program_traces.items():
-        tr = reference_trace(config, ts)
+        tr = tracegen.build_trace(config, ts)
         got = trace_checks(program_trace, tr)
         got.update(row_checks([rows for s, rows in grids if s == ts],
                               reference_rows(tr, sweeps[ts])))
@@ -97,7 +86,7 @@ def check_window(config: Dict, program_traces: Dict, grids: List,
 def control_checks(config: Dict, seed: int, sweep_cells) -> Dict:
     """The same numbers with the control in the program's place: the
     reference's rows replayed with a float32 timing state."""
-    tr = reference_trace(config, seed)
+    tr = tracegen.build_trace(config, seed)
     checks = trace_checks(tr, tr)
     checks.update(row_checks(
         [reference_rows(tr, sweep_cells, precise=False)],

@@ -11,19 +11,15 @@ import json
 import os
 from typing import Dict
 
+from bench.reference import family
+
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "peaks.json")
 
-#: bytes of one access's inputs, per lane family: the page id (int32),
-#: plus the oracle's stream position (int32)
-ACCESS_INPUT_BYTES = {"none": 4, "block": 4, "tree": 4, "oracle": 8}
 #: per-page replay state: arrival (float64), LRU stamp (int32), the
 #: prefetched-unused flag (1 byte), plus the policy's own word
 PAGE_STATE_BYTES = 8 + 4 + 1
 POLICY_STATE_BYTES = {"lru": 0, "random": 4, "hotcold": 4}
-#: tree lanes keep one int32 occupancy count per node of levels 0..5
-#: (64 KB to 2 MB nodes): pages/16 + pages/32 + ... + pages/512
-TREE_NODES_PER_PAGE = sum(1.0 / (16 << lv) for lv in range(6))
 
 
 def peaks(device_kind: str) -> Dict[str, float]:
@@ -40,12 +36,17 @@ def lane_bytes(n_accesses: int, working_set_pages: int, prefetcher: str,
                eviction: str) -> int:
     """Least bytes one lane's replay moves: each access record read once,
     the accessed page's state read and written once per access, and the
-    lane's state over its working set moved once per batch."""
+    lane's state over its working set moved once per batch.  What an
+    access reads and what state the prefetcher keeps, its family module
+    declares (``bench/reference/family.py``): constants of the program's
+    lane layout (the int32 page id, the oracle's int32 position, the
+    tree's node counts), kept beside the family's reference so that a
+    family enters as one file; the reference replay itself reads none of
+    them."""
+    fam = family.load(prefetcher)
     per_page = PAGE_STATE_BYTES + POLICY_STATE_BYTES[eviction]
-    state = working_set_pages * per_page
-    if prefetcher == "tree":
-        state += int(working_set_pages * TREE_NODES_PER_PAGE) * 4
-    return (n_accesses * (ACCESS_INPUT_BYTES[prefetcher] + 2 * per_page)
+    state = working_set_pages * per_page + fam.state_bytes(working_set_pages)
+    return (n_accesses * (fam.INPUT_BYTES_PER_ACCESS + 2 * per_page)
             + state)
 
 

@@ -3,9 +3,12 @@
 One process on one chip.  Everything that belongs to one cell is data
 found by name: ``BENCHMARK.json`` names the cell, its configuration file
 (``bench/configs/<config>.json``) and its traffic file
-(``bench/workloads/<cell>.json``: the traces, the grid axes and the
-limits of the comparison); each per-layer metric is a reader in
-``bench/metrics/<metric>.py``.
+(``bench/workloads/<cell>.json``: the traces, the grid axes, the limits
+of the comparison and the size a CPU test holds); each per-layer metric
+is a reader in ``bench/metrics/<metric>.py``.  The plain reference finds
+the configuration's trace generator in
+``bench/reference/traces/<config>.py`` and each prefetcher family of the
+grid in ``bench/reference/prefetchers/<prefetcher>.py``.
 
 The window drives the sweep through its own entry point,
 ``repro.uvm.sweep.run_sweep(cells, workers=1)``, one whole grid after
@@ -20,7 +23,6 @@ boundary.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import itertools
 import json
 import os
@@ -30,6 +32,8 @@ import tempfile
 import time
 from typing import Callable, Dict, List, Optional
 
+from bench.modules import Refused, load_module
+
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 
@@ -37,11 +41,6 @@ ROOT = os.path.dirname(BENCH_DIR)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: JAX's event for one program loaded from the persistent cache
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-
-
-class Refused(Exception):
-    """The run cannot be measured (no chip, a traffic pin that moved, a
-    missing file): the harness exits non-zero and prints no result."""
 
 
 @dataclasses.dataclass
@@ -89,23 +88,30 @@ def cell_from_files(name: str, traffic: str, config: str, chips: int,
     def applies(m: Dict) -> bool:
         return name in m.get("workloads", [name])
 
-    return Cell(name=name, chips=chips,
+    cell = Cell(name=name, chips=chips,
                 config=_read_json(os.path.join(root, conf["file"])),
                 workload=workload,
                 per_layer=[m for m in bm["per_layer"] if applies(m)],
                 end_to_end=[m for m in bm["end_to_end"] if applies(m)])
+    resolve_references(cell)
+    return cell
+
+
+def resolve_references(cell: Cell) -> None:
+    """Load the reference modules the cell's comparison needs, so that a
+    missing one is refused before the run starts: the configuration's
+    trace generator and the family of every prefetcher of its grid."""
+    from bench.reference import family, tracegen
+
+    tracegen.generator(cell.config["name"])
+    for name in sorted({c["prefetcher"] for c in grid(cell, 0)}):
+        family.load(name)
 
 
 def load_reader(metric: str) -> Callable:
     """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    path = os.path.join(BENCH_DIR, "metrics", f"{metric}.py")
-    if not os.path.exists(path):
-        raise Refused(f"no reader for metric {metric!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(os.path.join(BENCH_DIR, "metrics"), metric,
+                       "reader of the metric").read
 
 
 def grid(cell: Cell, seed: int) -> List[Dict]:
@@ -188,6 +194,8 @@ class Window:
     trace_seeds: List[int]        # the trace each grid replayed
     seconds: float                # window start to the end of the last grid
     compiles: int                 # compilations inside the window
+    grid_seconds: List[float]     # host seconds of each grid
+    grid_cpu_seconds: List[float]  # the process's CPU seconds in each grid
 
 
 def run_window(one_pass, seconds: float, compiles: CompileCounter,
@@ -202,14 +210,17 @@ def run_window(one_pass, seconds: float, compiles: CompileCounter,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    grids, seeds = [], []
+    grids, seeds, grid_s, grid_cpu = [], [], [], []
     compiles.reset()
     t0 = time.perf_counter()
     try:
         while True:
             for trace_seed, sweep_cells in one_pass:
+                t_grid, cpu_grid = time.perf_counter(), time.process_time()
                 with jax.profiler.TraceAnnotation("bench.grid"):
                     grids.append(run_sweep(sweep_cells, workers=1))
+                grid_s.append(time.perf_counter() - t_grid)
+                grid_cpu.append(time.process_time() - cpu_grid)
                 seeds.append(trace_seed)
             elapsed = time.perf_counter() - t0
             if elapsed >= seconds:
@@ -217,7 +228,7 @@ def run_window(one_pass, seconds: float, compiles: CompileCounter,
     finally:
         if trace_dir:
             jax.profiler.stop_trace()
-    return Window(grids, seeds, elapsed, compiles.n)
+    return Window(grids, seeds, elapsed, compiles.n, grid_s, grid_cpu)
 
 
 def failed_rows(rows: List[Dict]) -> int:
@@ -262,7 +273,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     mem = devs[0].memory_stats() or {}
     rows = [r for g in win.grids for r in g]
     log(f"bench: window {win.seconds:.3f}s, {len(win.grids)} grids, "
-        f"{len(rows)} rows, {win.compiles} compilations")
+        f"{len(rows)} rows, {win.compiles} compilations; a grid by trace, "
+        f"host seconds/process CPU seconds: " + ", ".join(
+            f"{ts}:{sec:.3f}/{cpu:.3f}" for ts, sec, cpu in
+            zip(win.trace_seeds, win.grid_seconds, win.grid_cpu_seconds)))
 
     result: Dict = {"correct": False,
                     "attempted": len(win.grids) * len(one_pass[0][1]),

@@ -1,0 +1,64 @@
+"""What a reference prefetcher family is, and where the benchmark finds it.
+
+A family is the module ``bench/reference/prefetchers/<name>.py``, found by
+the name a grid gives on its ``prefetcher`` axis.  It defines:
+
+* ``make(trace, cell)``: the prefetcher of one replay, given the whole
+  reference trace (``tracegen.RefTrace``, its records included) and the
+  sweep cell's fields as a dict.  The object has the hooks of
+  :class:`Prefetcher` below;
+* ``INPUT_BYTES_PER_ACCESS``: the bytes of one access's inputs on a lane;
+* ``state_bytes(working_set_pages)``: the lane state the family keeps
+  beside each page's own, over a working set.
+
+The last two are not the reference's own: they describe the family's
+work as the program's lane layout holds it, and give the least bytes a
+lane of the family moves (``bench/costs.py``).  They sit here so that a
+family brings its bytes with it.  Like the rest of the reference, a
+family imports nothing of the program.
+"""
+from __future__ import annotations
+
+import os
+from typing import List
+
+from bench.modules import load_module
+
+PREFETCHER_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "prefetchers")
+#: the paper's 64 KB basic block
+BASIC_BLOCK_PAGES = 16
+
+
+class Prefetcher:
+    """The hooks of the replay loop; this base prefetches nothing."""
+
+    #: cycles added to a prefetch's ready time (a model's inference)
+    extra_latency_cycles = 0.0
+
+    def on_fault(self, index: int, page: int, resident) -> List[int]:
+        """Pages to migrate with the far fault of access ``index``."""
+        return []
+
+    def on_access(self, index: int, resident) -> List[int]:
+        """Pages to migrate after access ``index``, one by one."""
+        return []
+
+    def migrated(self, pages: List[int]) -> None:
+        """``pages`` were made resident."""
+
+    def evicted(self, page: int) -> None:
+        """``page`` left the device."""
+
+
+def block_pages(page: int, resident) -> List[int]:
+    """The other pages of ``page``'s basic block that are not resident."""
+    base = page // BASIC_BLOCK_PAGES * BASIC_BLOCK_PAGES
+    return [p for p in range(base, base + BASIC_BLOCK_PAGES)
+            if p != page and p not in resident]
+
+
+def load(name: str, directory: str = PREFETCHER_DIR):
+    """The family module of prefetcher ``name``; a missing one is
+    :class:`bench.modules.Refused`, naming the file."""
+    return load_module(directory, name, "reference prefetcher family")

@@ -7,7 +7,9 @@ that skip the fault path, MSHR stalls, and eviction under
 oversubscription with the in-flight-victim rule.  It follows the
 simulator's legacy per-access loop operation by operation, so a sound
 program agrees with it exactly in every integer counter and to rounding
-in the float accumulators.  It imports nothing of the program.
+in the float accumulators.  It imports nothing of the program.  The
+prefetcher of a row is its family's module, found by name
+(``bench/reference/family.py``).
 
 ``precise=False`` runs every float of the timing chain in float32 instead
 of float64: that is the benchmark's control, the step below the float64
@@ -18,14 +20,11 @@ from __future__ import annotations
 import heapq
 import math
 from collections import OrderedDict
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
-BASIC_BLOCK_PAGES = 16
-TREE_LEVELS = 5
-ORACLE_LOOKAHEAD = 96
-ORACLE_MAX_EXTRAS = 16
+from bench.reference import family
 
 #: paper Table 9 (GTX 1080 Ti under UVMSmart), GPU core cycles
 CORE_MHZ = 1481.0
@@ -99,83 +98,19 @@ class _Policy:
                 heapq.heapreplace(self.heap, cur)
 
 
-class _Prefetcher:
-    """none / block / tree / oracle, as the paper describes them."""
-
-    def __init__(self, kind: str, pages: np.ndarray) -> None:
-        self.kind = kind
-        self.counts: Dict[tuple, int] = {}
-        if kind == "oracle":
-            _, first = np.unique(np.asarray(pages), return_index=True)
-            order = np.sort(first)
-            self.ft_pages = np.asarray(pages)[order].tolist()
-            self.ft_index = order.tolist()
-            self.pos = 0
-
-    def _block(self, page: int, resident) -> List[int]:
-        base = page // BASIC_BLOCK_PAGES * BASIC_BLOCK_PAGES
-        return [p for p in range(base, base + BASIC_BLOCK_PAGES)
-                if p != page and p not in resident]
-
-    def migrated(self, pages: List[int]) -> None:
-        if self.kind == "tree":
-            for page in pages:
-                for lv in range(TREE_LEVELS + 1):
-                    key = (lv, page // (BASIC_BLOCK_PAGES << lv))
-                    self.counts[key] = self.counts.get(key, 0) + 1
-
-    def evicted(self, page: int) -> None:
-        if self.kind == "tree":
-            for lv in range(TREE_LEVELS + 1):
-                key = (lv, page // (BASIC_BLOCK_PAGES << lv))
-                if key in self.counts:
-                    self.counts[key] -= 1
-                    if self.counts[key] == 0:
-                        del self.counts[key]
-
-    def on_fault(self, index: int, page: int, resident) -> List[int]:
-        if self.kind == "block":
-            return self._block(page, resident)
-        if self.kind == "oracle":
-            return self.on_access(index, resident)
-        if self.kind != "tree":
-            return []
-        out = self._block(page, resident)
-        pending = set(out) | {page}
-        for lv in range(1, TREE_LEVELS + 1):
-            span = BASIC_BLOCK_PAGES << lv
-            lo = page // span * span
-            cnt = self.counts.get((lv, page // span), 0) + sum(
-                1 for p in pending if lo <= p < lo + span)
-            if cnt * 2 <= span:
-                break
-            extra = [p for p in range(lo, lo + span)
-                     if p not in resident and p not in pending]
-            out.extend(extra)
-            pending.update(extra)
-        return out
-
-    def on_access(self, index: int, resident) -> List[int]:
-        if self.kind != "oracle":
-            return []
-        while (self.pos < len(self.ft_index)
-               and self.ft_index[self.pos] <= index):
-            self.pos += 1
-        out = []
-        for p in self.ft_pages[self.pos:self.pos + ORACLE_LOOKAHEAD]:
-            if p not in resident:
-                out.append(p)
-                if len(out) >= ORACLE_MAX_EXTRAS:
-                    break
-        return out
-
-
-def replay(pages: np.ndarray, n_instructions: int, prefetcher: str,
-           device_pages: Optional[int], eviction: str = "lru",
-           precise: bool = True) -> Dict:
-    """Replay one trace; returns the row's statistics by column name."""
+def replay(trace, cell: Dict, precise: bool = True,
+           families: str = family.PREFETCHER_DIR) -> Dict:
+    """Replay reference trace ``trace`` under sweep cell ``cell`` (its
+    fields as a dict); returns the row's statistics by column name.  The
+    prefetcher is the family ``cell["prefetcher"]`` names, from
+    ``families``."""
+    prefetcher, eviction = cell["prefetcher"], cell["eviction"]
+    device_pages = (int(trace.working_set_pages * cell["device_frac"])
+                    if cell.get("device_frac") is not None
+                    else cell.get("device_pages"))
+    n_instructions = trace.n_instructions
     f = float if precise else np.float32
-    page_list = [int(p) for p in np.asarray(pages)]
+    page_list = [int(p) for p in np.asarray(trace.pages)]
     n = len(page_list)
     ff = f(FAR_FAULT_US * CORE_MHZ)
     page_tx = f(PAGE_SIZE / (PCIE_GB_S * 1e9 / (CORE_MHZ * 1e6)))
@@ -185,7 +120,8 @@ def replay(pages: np.ndarray, n_instructions: int, prefetcher: str,
     page_bytes = f(PAGE_SIZE)
     cpa = f(PTW_CYCLES + DRAM_CYCLES + ACCESS_OVERHEAD_CYCLES
             + (n_instructions / max(n, 1)) / ISSUE_IPC)
-    pf = _Prefetcher(prefetcher, pages)
+    pf = family.load(prefetcher, families).make(trace, cell)
+    pf_ready = f(pf.extra_latency_cycles)
     policy = _Policy(eviction)
     cap = device_pages
     track = cap is not None
@@ -200,7 +136,7 @@ def replay(pages: np.ndarray, n_instructions: int, prefetcher: str,
 
     def schedule(extras: List[int], batch: bool) -> None:
         nonlocal pcie_free, migrated, pcie_bytes, issued
-        start = max(pcie_free, clock + pf_over)
+        start = max(pcie_free, clock + pf_over + pf_ready)
         end = start + len(extras) * page_tx
         t = start
         for q in extras:

@@ -1,11 +1,15 @@
 """Plain reference of the trace build for the benchmark's configurations.
 
-An independent copy of the ATAX page-access generator and of the GPU execution model (CTA dispatch, per-SM round-robin bursts, TLB
-filter, GMMU merge) that the simulator uses as its trace source
-(``src/repro/traces/generators.py``, ``gpu_model.py``).  It imports
-nothing of the program: the benchmark builds the reference's trace from
-``--seed`` here, and the rows the program replays on its own trace must
-match the rows this trace gives.
+An independent copy of the GPU execution model (CTA dispatch, per-SM
+round-robin bursts, TLB filter, GMMU merge) that the simulator uses as its
+trace source (``src/repro/traces/gpu_model.py``).  The page-access
+streams of each configuration come from its own module,
+``bench/reference/traces/<config name>.py``, an independent copy of the
+program's generator for that benchmark, found by name: its
+``streams(scale, seed)`` gives the CTA streams and the kernel's
+instruction count.  Nothing here imports the program: the benchmark
+builds the reference's trace from ``--seed`` here, and the rows the
+program replays on its own trace must match the rows this trace gives.
 
 Records are (pc, sm, tpc, cta, warp, kernel, array, page) in GMMU
 arrival order; the evaluation window is the leading fraction of them,
@@ -14,10 +18,17 @@ and the instruction count is that of the whole kernel.
 from __future__ import annotations
 
 import dataclasses
+import os
 import zlib
 from typing import Dict, List, Tuple
 
 import numpy as np
+
+from bench.modules import load_module
+
+#: where each configuration's stream generator lives, by config name
+TRACE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "traces")
 
 PAGE = 4096
 FLOAT = 4
@@ -53,7 +64,7 @@ class RefTrace:
 
 
 @dataclasses.dataclass
-class _Stream:
+class Stream:
     kernel: int
     cta: int
     pcs: np.ndarray
@@ -62,7 +73,7 @@ class _Stream:
     burst: float
 
 
-class _Alloc:
+class Alloc:
     """2 MB-aligned bump allocator from a seeded random heap base."""
 
     def __init__(self, seed: int) -> None:
@@ -78,50 +89,28 @@ class _Alloc:
         self.cursor += -(-pages // ROOT_PAGES) * ROOT_PAGES
 
 
-def _pc(kernel: int, slot: int) -> int:
+def pc(kernel: int, slot: int) -> int:
+    """The PC of static load/store ``slot`` of kernel launch ``kernel``."""
     return 0x400000 + kernel * 0x1000 + slot * 0x20
 
 
-def _interleave(kernel: int, cta: int,
-                parts: List[Tuple[int, int, np.ndarray]],
-                burst: float) -> _Stream:
+def interleave(kernel: int, cta: int,
+               parts: List[Tuple[int, int, np.ndarray]],
+               burst: float) -> Stream:
     """Element-wise interleave of equal-length (pc, array, pages) parts;
     one part is taken as it is."""
     n, k = len(parts[0][2]), len(parts)
     pcs = np.empty(n * k, np.uint32)
     arrs = np.empty(n * k, np.uint16)
     pages = np.empty(n * k, np.int64)
-    for i, (pc, aid, pg) in enumerate(parts):
-        pcs[i::k] = pc
+    for i, (code, aid, pg) in enumerate(parts):
+        pcs[i::k] = code
         arrs[i::k] = aid
         pages[i::k] = pg
-    return _Stream(kernel, cta, pcs, arrs, pages, burst)
+    return Stream(kernel, cta, pcs, arrs, pages, burst)
 
 
-def atax_streams(scale: float, seed: int):
-    """PolyBench ATAX, y = A^T (A x): two thread-per-row matrix-vector
-    kernels, each sweeping A one 4 KB column block at a time."""
-    n = int(4096 * max(scale, 0.05))
-    ppr = max(1, n * FLOAT // PAGE)
-    al = _Alloc(seed + 2)
-    for name in ("A", "x", "y", "tmp"):
-        al.alloc(name, n * n * FLOAT if name == "A" else n * FLOAT)
-    streams = []
-    for kernel in (0, 1):
-        for blk in range(ppr):
-            for cta in range(n // 256):
-                rows = np.arange(cta * 256, cta * 256 + 256, dtype=np.int64)
-                pages = al.bases["A"] + rows * ppr + blk
-                streams.append(_interleave(
-                    kernel, cta, [(_pc(kernel, blk), al.ids["A"], pages)],
-                    512.0))
-    return streams, 2 * n * n
-
-
-GENERATORS = {"ATAX": atax_streams}
-
-
-def _sm_schedule(sm: int, mine: List[_Stream], rng, t_base: float):
+def _sm_schedule(sm: int, mine: List[Stream], rng, t_base: float):
     """Round-robin bursts of the CTAs resident on one SM, in waves."""
     n_total = sum(len(s.pages) for s in mine)
     recs = np.zeros(n_total, dtype=ACCESS_DTYPE)
@@ -170,10 +159,22 @@ def _tlb_filter(recs: np.ndarray, times: np.ndarray):
     return recs[keep], times[keep]
 
 
-def build_trace(bench: str, scale: float, seed: int,
-                window: float) -> RefTrace:
-    """The leading ``window`` share of ``bench``'s GMMU trace."""
-    streams, n_instructions = GENERATORS[bench](scale, seed)
+def generator(config_name: str, directory: str = TRACE_DIR):
+    """The stream generator module of configuration ``config_name``; a
+    missing one is :class:`bench.modules.Refused`, naming the file."""
+    return load_module(directory, config_name, "reference trace generator")
+
+
+def build_trace(config: Dict, seed: int,
+                generators: str = TRACE_DIR) -> RefTrace:
+    """The leading ``window`` share of the GMMU trace of ``config`` (a
+    configuration file's fields: ``name``, ``bench``, ``scale``,
+    ``window``) for trace seed ``seed``.  The merge's rng is keyed on the
+    ``bench`` string, as the program keys it.  The configuration's
+    stream generator is looked up in ``generators``."""
+    bench = config["bench"]
+    streams, n_instructions = generator(config["name"], generators).streams(
+        config["scale"], seed)
     rng = np.random.default_rng(seed ^ (zlib.crc32(bench.encode()) & 0xFFFF))
     chunks = []
     t_base = 0.0
@@ -193,5 +194,5 @@ def build_trace(bench: str, scale: float, seed: int,
         chunks.append(recs[np.argsort(times, kind="stable")])
         t_base = float(times.max())
     accesses = np.concatenate(chunks)
-    return RefTrace(bench, accesses[:int(len(accesses) * window)],
+    return RefTrace(bench, accesses[:int(len(accesses) * config["window"])],
                     n_instructions)

@@ -17,34 +17,25 @@ from bench.reference import tracegen
 
 SEED = 2 ** 31 + 7
 
-
-#: per cell: the scale and the grid a test run holds, on the first two
-#: traces of the cell's pass; every batch has two lanes, so leaving half
-#: of one out is a fault the run can show
-SMALL = {
-    "atax.replay": (0.25, {"prefetcher": ["none", "tree"]}),
-    "atax.evict-mix": (0.25, {"prefetcher": ["tree"]}),
-}
+with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
 
 
 def _small_cell(name: str) -> harness.Cell:
-    """The cell's own files at a size a test run holds."""
-    with open(os.path.join(harness.BENCH_DIR, "workloads",
-                           f"{name}.json")) as f:
-        config = json.load(f)["config"]
-    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        bm = json.load(f)
-    cell = harness.cell_from_files(name, name, config, 1, bm)
-    scale, axes = SMALL[name]
-    config = dict(cell.config, scale=scale)
-    tr = tracegen.build_trace(config["bench"], scale,
-                              cell.workload["trace_seeds"][0],
-                              config["window"])
+    """The cell at the size its traffic file's ``small`` block gives a
+    test run: the scale, grid axes that replace the cell's own, and how
+    many of the pass's traces.  Every batch has two lanes, so leaving
+    half of one out is a fault the run can show."""
+    cell = harness.load_cell(name)
+    small = cell.workload["small"]
+    config = dict(cell.config, scale=small["scale"])
+    tr = tracegen.build_trace(config, cell.workload["trace_seeds"][0])
     config["pins"] = {"n_accesses": len(tr.accesses),
                       "n_instructions": tr.n_instructions}
-    grid = dict(cell.workload["grid"], device_frac=[0.75, 0.5], **axes)
-    workload = dict(cell.workload, grid=grid,
-                    trace_seeds=cell.workload["trace_seeds"][:2])
+    workload = dict(cell.workload,
+                    grid=dict(cell.workload["grid"], **small["grid"]),
+                    trace_seeds=cell.workload["trace_seeds"][
+                        :small["traces"]])
     return dataclasses.replace(cell, config=config, workload=workload,
                                backend="pallas")
 
@@ -55,18 +46,19 @@ def _run(cell):
                             require_chip=False, log=lambda _m: None)
 
 
-@pytest.mark.parametrize("name", list(SMALL))
+@pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct(name):
     cell = _small_cell(name)
     res = _run(cell)
     assert res["correct"], res["checks"]
     assert res["failed"] == 0
-    assert res["attempted"] == 2 * len(harness.grid(cell, SEED))
+    assert res["attempted"] == (len(cell.workload["trace_seeds"])
+                                * len(harness.grid(cell, SEED)))
     assert list(res)[-1] == "checks"
     assert res["checks"]["int_mismatches"]["value"] == 0
 
 
-@pytest.mark.parametrize("name", list(SMALL))
+@pytest.mark.parametrize("name", CELLS)
 def test_control_is_not_correct(name):
     cell = _small_cell(name)
     from repro.uvm.sweep import SweepCell
@@ -98,7 +90,7 @@ def _answer_altered(orig, self, requests):
     return out
 
 
-@pytest.mark.parametrize("name", list(SMALL))
+@pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_batch,
                                    _answer_altered],
                          ids=["state-unchanged", "half-batch",
@@ -113,7 +105,7 @@ def test_broken_timed_path_is_not_correct(monkeypatch, fault, name):
     assert not res["correct"], res["checks"]
 
 
-@pytest.mark.parametrize("name", list(SMALL))
+@pytest.mark.parametrize("name", CELLS)
 def test_a_row_left_out_of_the_grid_is_not_correct(monkeypatch, name):
     from repro.uvm import sweep
 
@@ -125,4 +117,5 @@ def test_a_row_left_out_of_the_grid_is_not_correct(monkeypatch, name):
     assert not res["correct"], res["checks"]
     assert res["checks"]["int_mismatches"]["value"] >= 1
     # attempted counts the rows the window asked for, not those it got
-    assert res["attempted"] == 2 * len(harness.grid(cell, SEED))
+    assert res["attempted"] == (len(cell.workload["trace_seeds"])
+                                * len(harness.grid(cell, SEED)))

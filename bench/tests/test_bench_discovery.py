@@ -3,6 +3,7 @@ the refusals: a trace that moved from its pins, a host without a TPU, a
 directory without the program."""
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -16,6 +17,20 @@ from bench import harness
 ROOT = harness.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BM = json.load(_f)
+
+
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+def _check_reduced(conf, entry):
+    """``reduced`` lists the keys cut from the source, the same in the
+    configuration file and in ``BENCHMARK.json``; the file holds each
+    cut key with its value as run."""
+    assert isinstance(conf["reduced"], list)
+    assert conf["reduced"] == entry["reduced"]
+    for key in conf["reduced"]:
+        assert isinstance(key, str) and NAME.match(key), key
+        assert key in conf, key
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in BM["workloads"]])
@@ -34,7 +49,8 @@ def test_every_cell_resolves_by_name(name):
     entry = next(w for w in BM["workloads"] if w["name"] == name)
     assert cell.config["name"] == entry["config"] == cell.workload["config"]
     assert cell.chips == entry["chips"] == cell.workload["chips"]
-    assert cell.config["reduced"] == []
+    _check_reduced(cell.config,
+                   next(c for c in BM["configs"] if c["name"] == entry["config"]))
     assert set(cell.workload["limits"]) >= {
         "trace_records_differ", "int_mismatches", "float_rel_gap"}
     sweep = harness.grid(cell, 2 ** 31 + 5)
@@ -61,7 +77,7 @@ def test_config_files_are_the_ones_benchmark_json_names():
         with open(os.path.join(ROOT, c["file"])) as f:
             conf = json.load(f)
         assert conf["name"] == c["name"]
-        assert conf["reduced"] == c["reduced"] == []
+        _check_reduced(conf, c)
         assert set(conf["pins"]) == {"n_accesses", "n_instructions"}
 
 
