@@ -8,10 +8,14 @@ cell through the sweep on the chip (``harness.run_window``, after one
 warm-up grid) and compares its rows with the plain reference: the lower
 readings, from the program.  For each of ``--control-seeds`` it puts the
 control in the program's place (``compare.control_checks``: the
-reference's rows replayed with a float32 timing state): the upper
-readings.  Prints one JSON line per seed and a summary (largest lower
-and smallest upper reading of each number, beside the cell's limit).
-The benchmark's own runs never run this.
+reference's rows replayed with a float32 timing state, and each family's
+own control beside the three numbers of the replay): the upper
+readings.  A grid whose predictor the program trains gets a fresh one,
+as in the benchmark's window, and the control of a seed is given what
+the chip trained on it.  Prints one JSON line per seed and a summary
+(largest lower and smallest upper reading of every number, beside the
+cell's limit where it has one).  The benchmark's own runs never run
+this.
 """
 import argparse
 import json
@@ -41,7 +45,7 @@ def main(argv=None) -> int:
     compile_cache.enable()
     compiles = harness.CompileCounter()
     conf = cell.config
-    lines = []
+    lines, trained = [], {}
     for i, seed in enumerate(int(s) for s in args.seeds.split(",") if s):
         sweep = [SweepCell(**c) for c in harness.grid(cell, seed)]
         trace = load_trace(conf["bench"], conf["scale"], seed,
@@ -50,23 +54,33 @@ def main(argv=None) -> int:
         if i == 0:
             harness.run_window([(seed, sweep)], 0.0, compiles)  # compiles
         win = harness.run_window([(seed, sweep)], 0.0, compiles)
-        checks = compare.check_window(conf, {seed: trace},
-                                      [(seed, win.grids[0])], {seed: sweep})
+        trained[seed] = win.trained[0]
+        checks = compare.check_window(
+            conf, {seed: trace}, [(seed, win.grids[0], win.trained[0])],
+            {seed: sweep})
         checks["failed_rows"] = harness.failed_rows(win.grids[0])
         lines.append({"side": "program", "seed": seed, "checks": checks,
                       "grid_s": win.seconds, "compiles": win.compiles})
         print(json.dumps(lines[-1]), flush=True)
     for seed in (int(s) for s in args.control_seeds.split(",") if s):
         sweep = [SweepCell(**c) for c in harness.grid(cell, seed)]
+        if harness.trains(sweep) and seed not in trained:
+            trained[seed] = harness.run_window([(seed, sweep)], 0.0,
+                                               compiles).trained[0]
         lines.append({"side": "control", "seed": seed,
-                      "checks": compare.control_checks(conf, seed, sweep)})
+                      "checks": compare.control_checks(
+                          conf, seed, sweep, trained.get(seed))})
         print(json.dumps(lines[-1]), flush=True)
     summary = {}
-    for k, limit in cell.workload["limits"].items():
-        lo = [ln["checks"][k] for ln in lines if ln["side"] == "program"]
-        up = [ln["checks"][k] for ln in lines if ln["side"] == "control"]
+    limits = cell.workload["limits"]
+    for k in sorted({k for ln in lines for k in ln["checks"]}):
+        lo = [ln["checks"][k] for ln in lines
+              if ln["side"] == "program" and k in ln["checks"]]
+        up = [ln["checks"][k] for ln in lines
+              if ln["side"] == "control" and k in ln["checks"]]
         summary[k] = {"lower": max(lo) if lo else None,
-                      "upper": min(up) if up else None, "limit": limit}
+                      "upper": min(up) if up else None,
+                      "limit": limits.get(k)}
     print(json.dumps({"workload": cell.name, "summary": summary}),
           flush=True)
     if args.out:

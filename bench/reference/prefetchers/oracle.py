@@ -25,9 +25,9 @@ class Oracle(Prefetcher):
         self.pos = 0
 
     def on_fault(self, index, page, resident):
-        return self.on_access(index, resident)
+        return self.on_access(index, resident, None)
 
-    def on_access(self, index, resident):
+    def on_access(self, index, resident, clock):
         while (self.pos < len(self.ft_index)
                and self.ft_index[self.pos] <= index):
             self.pos += 1

@@ -181,7 +181,7 @@ def replay(trace, cell: Dict, precise: bool = True,
             extras = pf.on_fault(i, p, resident)
             if extras:
                 schedule(extras, batch=True)
-        extras = pf.on_access(i, resident)
+        extras = pf.on_access(i, resident, clock)
         if extras:
             schedule(extras, batch=False)
         while len(outstanding) > MSHR_ENTRIES:
