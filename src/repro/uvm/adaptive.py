@@ -9,7 +9,7 @@ sweepable axis: grids and scenarios may request ``eviction="adaptive"``,
 and the sweep resolves it to a *concrete* policy per cell at prepare
 time — the result row records the resolved policy in its ``eviction``
 column (never the literal ``adaptive``), so downstream consumers see
-exactly what replayed and lane batches stay policy-homogeneous.
+exactly what replayed and each lane runs under a concrete policy.
 
 Resolution order:
 

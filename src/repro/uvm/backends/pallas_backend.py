@@ -62,13 +62,16 @@ different per-lane state and inputs):
   ``lookahead`` are different families (the window width is a static
   kernel shape).
 
-Cells are additionally bucketed by **eviction policy**
-(``UVMConfig.eviction``, see ``repro.uvm.eviction``): victim selection
-and the extra per-lane carry (``random`` insert-time priority draws,
-``hotcold`` touch-frequency counts) are static kernel structure, so a
-batch is policy-homogeneous — ``_lane_shape`` is (family, policy,
-length, span) and ``fits_batch`` refuses to co-bucket policies exactly
-like families.
+The **eviction policy** (``UVMConfig.eviction``, see
+``repro.uvm.eviction``) is per-lane, like tenancy: one batch may mix
+policies.  The batch's *set* of policies is static structure — it adds
+the extra per-lane carries (``random`` insert-time priority draws,
+``hotcold`` touch-frequency counts) and the victim keys the eviction
+loop computes — and a mixed batch reads each lane's policy from its
+parameter row and picks that lane's victim with its own key.  A batch of
+one policy builds the single-policy program.  ``_lane_shape`` is
+(family, policy, length, span): ``pack_lanes`` sorts by it, so a batch
+holds as few policies as its lanes allow.
 
 Stateful-prefetcher cells the backend still declines (oversized spans,
 too-long traces, timeline recording) keep their exact NumPy adapters; the
@@ -168,7 +171,9 @@ _N_FPARAMS = 8       # cpa, page_tx, far_fault, ptw, pcie_lat, pfo, extra, page_
 _N_IPARAMS = 9       # n_accesses, device_pages(-1=uncapped), mshr, has_block,
 #                      n_ft, lane-lo mod 2^32 (random-policy priority draws),
 #                      tenant boundary (dense; IMAX = single-tenant lane),
-#                      q0, q1 (per-tenant quota pages; q0 = -1 = shared mode)
+#                      q0, q1 (per-tenant quota pages; q0 = -1 = shared mode);
+#                      a mixed-policy batch appends one more column: the
+#                      lane's index into the batch's sorted policies
 STAT_FIELDS = ("cycles", "hits", "late", "faults", "prefetch_issued",
                "prefetch_used", "pages_migrated", "pages_evicted",
                "pcie_bytes")
@@ -205,6 +210,12 @@ def _family_kind(family: str) -> str:
     return family.split("/")[0]
 
 
+def policy_label(policies: Sequence[str]) -> str:
+    """The eviction policies of a lane batch, sorted and joined by ``+``
+    (``hotcold+random``): the ``policy`` attribute of its spans."""
+    return "+".join(sorted(set(policies)))
+
+
 def _bucket(n: int, floor: int) -> int:
     """Round up to the next power of two (>= floor) so repeated batches of
     similar shape reuse one compiled kernel."""
@@ -215,19 +226,27 @@ def _bucket(n: int, floor: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
-                    span: int, buf_len: int, ft_len: int, lookahead: int,
-                    steps_len: int, mt: bool):
+def _lane_replay_fn(family: str, policies: Tuple[str, ...], n_lanes: int,
+                    t_max: int, span: int, buf_len: int, ft_len: int,
+                    lookahead: int, steps_len: int, mt: bool):
     """Build (and cache) the jitted multi-lane replay for one batch shape.
 
     ``family`` is the kernel kind (demand/tree/learned/oracle); ``ft_len``
     and ``lookahead`` are only meaningful for oracle lanes (0 otherwise).
-    ``policy`` is the eviction policy every lane of the batch runs under
-    (a batch is policy-homogeneous: the victim-selection code and the
-    extra per-lane carry — ``random`` priority draws, ``hotcold``
-    frequency counts — are static kernel structure).  ``n_lanes == 1``
-    builds one lane's own loop over its accesses; a wider batch runs its
-    lanes in lockstep (module docstring, "Lowering").
+    ``policies`` is the sorted tuple of the eviction policies the batch's
+    lanes run under.  The set is static structure: it decides the extra
+    per-lane carries (``random`` priority draws, ``hotcold`` frequency
+    counts) and which victim keys ``ebody`` computes.  The policy of a
+    lane is *per-lane dynamic*, like tenancy: a batch of several policies
+    reads each lane's index into ``policies`` from the column
+    ``_N_IPARAMS`` of its int32 parameter row, and picks each lane's
+    victim with its own policy's key (a per-lane select, then one
+    argmin).  A lane may update a carry its policy never reads; its
+    victims, and so its stats, stay those of the lane alone.  A batch of
+    one policy builds the single-policy program, with no such column.
+    ``n_lanes == 1`` builds one lane's own loop over its accesses; a
+    wider batch runs its lanes in lockstep (module docstring,
+    "Lowering").
 
     ``mt`` enables multi-tenant lane support (``repro.traces.interleave``):
     per-lane tenancy parameters (dense region boundary + per-tenant
@@ -266,8 +285,9 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
     u32 = jnp.uint32
     IMAX_NP = np.iinfo(np.int32).max
     IMAX64_NP = np.iinfo(np.int64).max
-    hotcold = policy == "hotcold"
-    randomp = policy == "random"
+    hotcold = "hotcold" in policies
+    randomp = "random" in policies
+    mixed = len(policies) > 1
     # the random victim key is (prio << 21) | slot: every state slot
     # (span + oracle trash) must fit the low 21 bits or slot indices
     # would bleed into the priority bits and silently reorder victims —
@@ -396,6 +416,9 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 return lane_lo + slots.astype(u32)
         if steps_len:
             sids = L["sids"]
+        if mixed:
+            # the lane's own eviction policy, an index into ``policies``
+            lane_pol = ip[_N_IPARAMS]
 
         # The legacy loop rounds every multiply before the dependent add,
         # but LLVM contracts ``a + b * c`` into a fused multiply-add
@@ -699,17 +722,16 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                     stamp = stamp.at[tgt].set(
                         jnp.where(take, counter + rank, IMAX))
                     pfu = pfu.at[tgt].set(take)
+                    pol = dict(pol)
                     if hotcold:
-                        (freq,) = pol
-                        freq = freq.at[tgt].set(
+                        freq = pol["freq"]
+                        pol["freq"] = freq.at[tgt].set(
                             jnp.where(take, 0, freq[tgt]))
-                        pol = (freq,)
                     if randomp:
-                        (prio,) = pol
+                        prio = pol["prio"]
                         prw = _rand_score(abs_page(win_idx), counter + rank)
-                        prio = prio.at[tgt].set(
+                        pol["prio"] = prio.at[tgt].set(
                             jnp.where(take, prw, prio[tgt]))
-                        pol = (prio,)
                     counter = counter + k
                     resident = resident + k
                     migrated = migrated + k
@@ -718,11 +740,12 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                     return (arrival, stamp, pfu, counter, resident,
                             migrated, issued, pcie_free, pol, rc0)
 
-                pol = ()
+                # the policy carries the scans update
+                pol = {}
                 if hotcold:
-                    pol = (freq,)
+                    pol["freq"] = freq
                 if randomp:
-                    pol = (prio,)
+                    pol["prio"] = prio
                 rc0_c = rc0 if mt else zero
                 (arrival, stamp, pfu, counter, resident, migrated, issued,
                  pcie_free, pol, rc0_c) = scan(arrival, stamp, pfu, counter,
@@ -737,9 +760,9 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                 if mt:
                     rc0 = rc0_c
                 if hotcold:
-                    (freq,) = pol
+                    freq = pol["freq"]
                 if randomp:
-                    (prio,) = pol
+                    prio = pol["prio"]
 
             # MSHR pressure: beyond ``mshr`` outstanding stalls the clock
             # jumps to the oldest completion (single pop suffices: pushes
@@ -786,8 +809,8 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
             cont = track_lru if active is None else track_lru & active
             return out, cont
 
-        # eviction under oversubscription: the policy picks the victim
-        # (lru = min touch stamp, exact OrderedDict order; random =
+        # eviction under oversubscription: the lane's policy picks the
+        # victim (lru = min touch stamp, exact OrderedDict order; random =
         # min insert-time priority draw; hotcold = min (freq, stamp));
         # an in-flight victim is retouched at MRU and stops the loop
         def _allowed(c):
@@ -827,19 +850,32 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
                     ~tsplit | ((slot_iota >= bnd).astype(i32) == u))
             if hotcold:
                 fq = c["freq"]
-                key = jnp.where(
-                    res_mask & (stamp < IMAX),
-                    (fq.astype(jnp.int64) << 32)
-                    | stamp.astype(jnp.int64), IMAX64)
-                vi = jnp.argmin(key)
-            elif randomp:
-                # prio is static while resident: read it from ``s``
-                key = jnp.where(
-                    res_mask & (stamp < IMAX),
-                    (s["prio"].astype(jnp.int64) << 21) | iota64, IMAX64)
+
+            def victim_key(policy):
+                if policy == "hotcold":
+                    return jnp.where(
+                        res_mask & (stamp < IMAX),
+                        (fq.astype(jnp.int64) << 32)
+                        | stamp.astype(jnp.int64), IMAX64)
+                if policy == "random":
+                    # prio is static while resident: read it from ``s``
+                    return jnp.where(
+                        res_mask & (stamp < IMAX),
+                        (s["prio"].astype(jnp.int64) << 21) | iota64,
+                        IMAX64)
+                return jnp.where(res_mask, stamp, IMAX)
+
+            if mixed:
+                # each lane's own policy key, widened to int64 (an order-
+                # and tie-preserving cast of the int32 lru key), then one
+                # argmin: the victim, ties included, of the lane alone
+                key = victim_key(policies[0]).astype(jnp.int64)
+                for j, policy in enumerate(policies[1:], 1):
+                    key = jnp.where(lane_pol == j,
+                                    victim_key(policy).astype(jnp.int64), key)
                 vi = jnp.argmin(key)
             else:
-                vi = jnp.argmin(jnp.where(res_mask, stamp, IMAX))
+                vi = jnp.argmin(victim_key(policies[0]))
             v_arr = arrival[vi]
             in_flight = v_arr > clock
             # retouched (in flight) or evicted, on a lane that evicts
@@ -1012,10 +1048,10 @@ def _lane_replay_fn(family: str, policy: str, n_lanes: int, t_max: int,
 def _lane_shape(request: ReplayRequest) -> Tuple[str, str, int, int]:
     """(family, eviction policy, length, span) of one request's lane.
 
-    The eviction policy is part of the shape because a batch must be
-    policy-homogeneous: victim selection and the extra per-lane carry
-    (random priorities, hotcold frequencies) are static kernel structure,
-    so :meth:`PallasReplayBackend.fits_batch` never co-buckets policies.
+    A batch may mix eviction policies (the policy is a per-lane value of
+    the lane program); the policy stays in the shape so that sorting by
+    it keeps one policy's lanes together, and a batch holds as few
+    policies as its lanes allow.
     """
     lo, hi = dense_bounds(request.trace, request.prefetcher)
     return (lane_family(request.prefetcher) or "unpackable",
@@ -1084,14 +1120,15 @@ class PallasReplayBackend(ReplayBackend):
                    shape: Tuple[str, str, int, int]) -> bool:
         """True if a lane of ``shape`` = (family, policy, length, span) —
         the :func:`_lane_shape` of a request — fits a batch that already
-        holds lanes of ``shapes`` under the family- and
-        policy-homogeneity rules and the lane-count, padded state, and
-        padded access budgets.  The scheduler uses this to flush batches
-        incrementally instead of materializing whole grids.
+        holds lanes of ``shapes`` under the family-homogeneity rule and
+        the lane-count, padded state, and padded access budgets.  Lanes
+        of different eviction policies share a batch.  The scheduler uses
+        this to flush batches incrementally instead of materializing
+        whole grids.
         """
-        fam, pol, t, sp = shape
-        if any(f != fam or p != pol for f, p, _, _ in shapes):
-            return False    # never co-bucket families or eviction policies
+        fam, _, t, sp = shape
+        if any(f != fam for f, _, _, _ in shapes):
+            return False    # never co-bucket families
         n = len(shapes) + 1
         t = max([t] + [s[2] for s in shapes])
         sp = max([sp] + [s[3] for s in shapes])
@@ -1101,13 +1138,13 @@ class PallasReplayBackend(ReplayBackend):
 
     def pack_lanes(self, requests: Sequence[ReplayRequest]
                    ) -> List[List[int]]:
-        """Group request indices into family- and policy-homogeneous lane
-        batches.
+        """Group request indices into family-homogeneous lane batches.
 
         Cells are sorted by (family, policy, length, span) so lanes of
-        one batch share a kernel and pad to similar shapes, then greedily
-        packed under :meth:`fits_batch`'s budgets.  Deterministic in the
-        request order.
+        one batch share a kernel, hold as few eviction policies as they
+        can, and pad to similar shapes, then greedily packed under
+        :meth:`fits_batch`'s budgets.  Deterministic in the request
+        order.
         """
         order = sorted(range(len(requests)),
                        key=lambda i: _lane_shape(requests[i]), reverse=True)
@@ -1151,7 +1188,8 @@ class PallasReplayBackend(ReplayBackend):
     # ------------------------------------------------------------------
     def _replay_batch(self, requests: Sequence[ReplayRequest]
                       ) -> List[UVMStats]:
-        """Replay one family-homogeneous lane batch: pad, launch, unpack."""
+        """Replay one family-homogeneous lane batch: pad, launch, unpack.
+        Its lanes may run under different eviction policies."""
         import jax
 
         families = {lane_family(r.prefetcher) for r in requests}
@@ -1160,13 +1198,14 @@ class PallasReplayBackend(ReplayBackend):
         family = families.pop()
         kind = _family_kind(family)
         lookahead = int(family.split("/")[1]) if kind == "oracle" else 0
-        policies = {r.config.eviction for r in requests}
-        assert len(policies) == 1, \
-            f"lane batch must be policy-homogeneous, got {policies}"
-        policy = policies.pop()
+        policies = tuple(sorted({r.config.eviction for r in requests}))
+        policy = policy_label(policies)
+        mixed = len(policies) > 1
 
         lanes = len(requests)
         obs.count("lane.batches")
+        if mixed:
+            obs.count("lane.mixed_batches")
         obs.count("lane.lanes", lanes)
         obs.count("lane.accesses", sum(len(r.trace.pages) for r in requests))
         # device loop steps: the lanes advance in lockstep, so a batch
@@ -1196,7 +1235,9 @@ class PallasReplayBackend(ReplayBackend):
 
             pages = np.zeros((n_lanes, t_max), dtype=np.int32)
             fparams = np.zeros((n_lanes, _N_FPARAMS), dtype=np.float64)
-            iparams = np.full((n_lanes, _N_IPARAMS), -1, dtype=np.int32)
+            # a mixed-policy batch appends each lane's policy index
+            iparams = np.full((n_lanes, _N_IPARAMS + mixed), -1,
+                              dtype=np.int32)
             iparams[:, 0] = 0                  # padding lanes replay nothing
             iparams[:, 6] = np.iinfo(np.int32).max  # single-tenant boundary
             extra_in: List[np.ndarray] = []
@@ -1243,6 +1284,8 @@ class PallasReplayBackend(ReplayBackend):
                     if tn.split:
                         iparams[l, 7] = int(tn.quotas[0])
                         iparams[l, 8] = int(tn.quotas[1])
+                if mixed:
+                    iparams[l, _N_IPARAMS] = policies.index(cfg.eviction)
                 if kind == "learned":
                     pr = np.asarray(pf.predicted_pages, dtype=np.int64)[:n]
                     preds_in[l, :n] = np.where(pr >= 0, pr - lo, -1)
@@ -1266,7 +1309,7 @@ class PallasReplayBackend(ReplayBackend):
         compile_cache.enable()
         with obs.span("lane.dispatch", family=family, policy=policy), \
                 jax.enable_x64(True):
-            fn = _lane_replay_fn(kind, policy, n_lanes, t_max, span,
+            fn = _lane_replay_fn(kind, policies, n_lanes, t_max, span,
                                  buf_len, ft_len, lookahead, steps_len, mt)
             raw = fn(pages, *extra_in, fparams, iparams)
         with obs.span("lane.fetch"):

@@ -236,8 +236,9 @@ def golden_cell(cell_id: str) -> Tuple[Trace, UVMConfig, Callable[[], Prefetcher
 
 
 def golden_cell_policy(cell_id: str) -> str:
-    """Eviction policy of one golden cell's config (lane batches are
-    policy-homogeneous, so the pallas harness groups by it)."""
+    """Eviction policy of one golden cell's config (the pallas harness
+    groups by it, so each golden lane batch runs a single-policy
+    program)."""
     case_name = cell_id.split("/")[0]
     case = next(c for c in golden_cases() if c.name == case_name)
     return case.config.eviction

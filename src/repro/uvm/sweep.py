@@ -557,7 +557,7 @@ def prepare_cell(cell: SweepCell, *, cache_dir: Optional[str] = None,
     if device_pages is None and cell.device_frac is not None:
         device_pages = int(trace.working_set_pages * cell.device_frac)
     # the adaptive pseudo-policy resolves to a concrete one here, before
-    # the replay config exists: lane batches stay policy-homogeneous and
+    # the replay config exists: each lane runs under a concrete policy and
     # the row's eviction column (from stats.eviction) records what ran
     eviction = adaptive.resolve_eviction(cell.eviction, cell.bench,
                                          trace=trace,
@@ -1280,15 +1280,15 @@ def _run_lane_batches(cells: Sequence[SweepCell],
     ``TransientBackendFault`` that is the retry contract (crash the
     driver, retry on the same backend after resume).
     """
-    from repro.uvm.backends.pallas_backend import _lane_shape
+    from repro.uvm.backends.pallas_backend import _lane_shape, policy_label
 
     backend = get_backend("pallas")
     rows: Dict[int, Dict] = {}
     batch: List[int] = []
     requests: List[ReplayRequest] = []
     caps: List[Optional[int]] = []
-    # (family, policy, length, span) per queued lane — the family/policy
-    # elements make fits_batch refuse to co-bucket families or policies
+    # (family, policy, length, span) per queued lane — the family element
+    # makes fits_batch refuse to co-bucket families
     shapes: List[Tuple[str, str, int, int]] = []
 
     def _replay_batch_rows(n: int, b: List[int], reqs: List[ReplayRequest],
@@ -1298,7 +1298,8 @@ def _run_lane_batches(cells: Sequence[SweepCell],
         """Flush-stage body (runs on the flush thread): replay packed
         batch ``n`` and assemble its rows."""
         with obs.span("lane.batch", batch=n, family=shps[0][0],
-                      policy=shps[0][1], lanes=len(b),
+                      policy=policy_label(sh[1] for sh in shps),
+                      lanes=len(b),
                       accesses=sum(sh[2] for sh in shps)):
             t0 = time.time()
             stats = backend.replay(list(reqs))
@@ -1349,8 +1350,10 @@ def _run_lane_batches(cells: Sequence[SweepCell],
         shapes.clear()
 
     families = _family_of_prefetcher_name()
-    # family- AND policy-major order: lane batches are homogeneous in
-    # both, so interleaved cells would flush half-filled batches
+    # family-major order: lane batches are family-homogeneous, so
+    # interleaved families would flush half-filled batches; policy-major
+    # within a family, so a batch holds as few eviction policies (and
+    # victim keys) as its lanes allow
     order = sorted(range(len(cells)),
                    key=lambda i: (families.get(cells[i].prefetcher, "~"),
                                   cells[i].eviction, i))

@@ -13,6 +13,7 @@ from repro.traces.trace import ROOT_PAGES, Trace, make_records
 from repro.uvm import UVMConfig
 from repro.uvm.backends.pallas_backend import (MAX_LANE_SPAN_PAGES,
                                                MAX_LANES_PER_BATCH,
+                                               _N_IPARAMS,
                                                PallasReplayBackend, _bucket,
                                                lane_family)
 from repro.uvm.golden import make_prefetcher as golden_prefetcher
@@ -247,18 +248,26 @@ def test_fits_batch_never_mixes_families():
                               ("oracle/96", "lru", 100, 512))
 
 
-def test_fits_batch_never_mixes_eviction_policies():
-    """Victim selection and the extra policy carry are static kernel
-    structure: lanes of different eviction policies must never share a
-    batch, whatever the shape budgets say."""
+def test_fits_batch_mixes_policies_not_families():
+    """The eviction policy is a per-lane value of the lane program:
+    lanes of every policy share a batch of their family, and a batch
+    still never takes a lane of another family, whatever its policy."""
     backend = get_backend("pallas")
-    for fam in ("demand", "tree", "learned", "oracle/96"):
-        assert not backend.fits_batch([(fam, "lru", 100, 512)],
-                                      (fam, "random", 100, 512))
-        assert not backend.fits_batch([(fam, "random", 100, 512)],
-                                      (fam, "hotcold", 100, 512))
+    fams = ("demand", "tree", "learned", "oracle/96")
+    for fam in fams:
+        assert backend.fits_batch([(fam, "lru", 100, 512)],
+                                  (fam, "random", 100, 512))
+        assert backend.fits_batch([(fam, "lru", 100, 512),
+                                   (fam, "random", 100, 512)],
+                                  (fam, "hotcold", 100, 512))
         assert backend.fits_batch([(fam, "hotcold", 100, 512)],
                                   (fam, "hotcold", 100, 512))
+        for other in fams:
+            if other != fam:
+                assert not backend.fits_batch([(fam, "lru", 100, 512)],
+                                              (other, "random", 100, 512))
+                assert not backend.fits_batch([(fam, "hotcold", 100, 512)],
+                                              (other, "hotcold", 100, 512))
 
 
 def test_lane_shape_carries_policy():
@@ -272,22 +281,30 @@ def test_lane_shape_carries_policy():
         assert (fam, shape_pol, t) == ("demand", pol, 120)
 
 
-def test_pack_lanes_never_cobuckets_policies():
-    """Interleaved cells of every eviction policy pack into
-    policy-homogeneous batches covering every request exactly once."""
+def test_pack_lanes_cobuckets_policies_not_families():
+    """Interleaved cells of every eviction policy and equal shapes pack
+    into one batch, one policy's lanes side by side; cells of two
+    families never share a batch."""
     backend = PallasReplayBackend()
     pages = np.arange(200) % 64
     policies = ("lru", "random", "hotcold", "lru", "random", "hotcold")
     reqs = [ReplayRequest(_mk_trace(pages), NoPrefetcher(),
                           UVMConfig(device_pages=48, eviction=pol))
             for pol in policies]
+    batch, = backend.pack_lanes(reqs)
+    assert sorted(batch) == list(range(len(reqs)))
+    in_order = [reqs[i].config.eviction for i in batch]
+    assert in_order == sorted(in_order, reverse=True)
+    # a second family: as many batches as families, each of one family
+    reqs += [ReplayRequest(_mk_trace(pages), TreePrefetcher(),
+                           UVMConfig(device_pages=48, eviction=pol))
+             for pol in policies]
     batches = backend.pack_lanes(reqs)
     assert sorted(i for b in batches for i in b) == list(range(len(reqs)))
+    assert len(batches) == 2
     for b in batches:
-        pols = {reqs[i].config.eviction for i in b}
-        assert len(pols) == 1, f"mixed-policy batch: {pols}"
-    # 3 policies, identical shapes -> exactly 3 batches
-    assert len(batches) == 3
+        assert len({lane_family(reqs[i].prefetcher) for i in b}) == 1
+        assert {reqs[i].config.eviction for i in b} == set(policies)
 
 
 def test_policy_lane_batches_match_numpy():
@@ -564,6 +581,102 @@ def test_lockstep_batch_equals_lanes_alone(case):
 
 
 # ---------------------------------------------------------------------------
+# mixed eviction policies: one batch, each lane under its own policy
+# ---------------------------------------------------------------------------
+
+#: the sets of eviction policies a mixed-policy batch runs
+POLICY_SETS = (("lru", "random"), ("hotcold", "lru"), ("hotcold", "random"),
+               ("hotcold", "lru", "random"))
+
+
+def _mixed_policy_cases():
+    """Ragged lanes that all evict, at different steps, taking a set's
+    policies in turn; plus a multi-tenant and a step-clock batch."""
+    lanes = [(pages, 120 if cap is None else cap)
+             for pages, cap in _ragged_lanes()]
+    cases = {}
+    for pf_name in ("none", "block", "tree", "learned", "oracle"):
+        for pols in POLICY_SETS:
+            cases[f"{pf_name}-{'+'.join(pols)}"] = [
+                (_mk_trace(pages), pf_name, cap, pols[i % len(pols)], None,
+                 None) for i, (pages, cap) in enumerate(lanes)]
+    rng = np.random.default_rng(5)
+    a, b = rng.integers(0, 700, 300), np.tile(np.arange(350), 2)
+    cases["tree-mt"] = [
+        (_mk_mt_trace(a, b), "tree", 240, "random", None, (100, 100)),
+        (_mk_mt_trace(b[:500], a), "tree", 180, "hotcold", None, None),
+        (_mk_trace(a), "tree", 120, "lru", None, None)]
+    cases["block-steps"] = [
+        (_mk_trace(pages), "block", cap, pol, bounds, None)
+        for (pages, cap), pol, bounds in zip(
+            lanes, ("hotcold", "random", "lru"),
+            (np.array([0, 40, 40, 300, 600]), None,
+             np.arange(10, 231, 20)))]
+    return cases
+
+
+MIXED_POLICY_CASES = _mixed_policy_cases()
+
+
+@pytest.mark.parametrize("case", list(MIXED_POLICY_CASES))
+def test_mixed_policy_batch_equals_lanes_alone(case, monkeypatch):
+    """Lanes of different eviction policies share one lockstep batch, and
+    each of its rows is bit-equal to that lane replayed alone and agrees
+    with the NumPy backend: each lane evicts the victims its own policy
+    picks.  Only the mixed batch's program reads a per-lane policy
+    column; a single-policy batch builds its program without one."""
+    from repro.uvm.backends import pallas_backend
+
+    lanes = MIXED_POLICY_CASES[case]
+    built = []
+    build_fn = pallas_backend._lane_replay_fn
+
+    def spy(family, policies, *shape):
+        fn = build_fn(family, policies, *shape)
+
+        def call(*args):
+            built.append((policies, args[-1].shape[1]))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(pallas_backend, "_lane_replay_fn", spy)
+
+    def build(i, policy=None):
+        trace, pf_name, cap, pol, bounds, quotas = lanes[i]
+        config = UVMConfig(device_pages=cap, mshr_entries=16,
+                           eviction=policy or pol, tenant_pages=quotas)
+        return ReplayRequest(trace, golden_prefetcher(pf_name, trace,
+                                                      config), config,
+                             step_bounds=bounds)
+
+    backend = get_backend("pallas")
+    requests = [build(i) for i in range(len(lanes))]
+    policies = tuple(sorted({r.config.eviction for r in requests}))
+    assert len(policies) > 1
+    assert all(backend.can_replay(r) for r in requests)
+    assert len(backend.pack_lanes(requests)) == 1, "not one batch"
+    together = backend.replay(requests)
+    assert built == [(policies, _N_IPARAMS + 1)]
+    for i, got in enumerate(together):
+        alone, = backend.replay([build(i)])
+        assert built[-1] == ((requests[i].config.eviction,), _N_IPARAMS)
+        assert _observed(got) == _observed(alone), f"{case} lane {i}"
+        want = dispatch(build(i), "numpy")
+        _assert_equivalent(got, want, context=f"{case} lane {i}")
+        if want.tenant_hits is not None:
+            assert got.tenant_hits == want.tenant_hits, f"{case} lane {i}"
+        if want.step_clocks is not None:
+            np.testing.assert_allclose(got.step_clocks, want.step_clocks,
+                                       rtol=1e-6)
+    evicted = [st.pages_evicted for st in together]
+    assert min(evicted) > 0, f"vacuous: a lane never evicts {evicted}"
+    assert len(set(evicted)) >= 2, f"vacuous: lanes evict alike {evicted}"
+    # the same lanes under one policy: no per-lane policy column
+    backend.replay([build(i, policies[0]) for i in range(len(lanes))])
+    assert built[-1] == ((policies[0],), _N_IPARAMS)
+
+
+# ---------------------------------------------------------------------------
 # property-based lane packing (skipped when hypothesis is absent)
 # ---------------------------------------------------------------------------
 
@@ -591,7 +704,8 @@ if HAVE_HYPOTHESIS:
         prefetcher family and eviction policy — equals N independent
         NumPy replays on every integer counter; ragged lengths and
         oversubscribed (cap=48/200) cells included.  Interleaved families
-        and policies exercise the homogeneous packing."""
+        exercise the family-homogeneous packing, and interleaved policies
+        share batches."""
         def build(spec):
             pages, pf_name, cap, eviction = spec
             tr = _mk_trace(np.asarray(pages, dtype=np.int64))
@@ -606,7 +720,6 @@ if HAVE_HYPOTHESIS:
         for b in backend.pack_lanes(requests):
             assert len({lane_family(requests[i].prefetcher)
                         for i in b}) == 1
-            assert len({requests[i].config.eviction for i in b}) == 1
         got = backend.replay(requests)
         want = [dispatch(build(c), "numpy") for c in cells]
         for i, (g, w) in enumerate(zip(got, want)):

@@ -141,12 +141,13 @@ def test_pallas_grid_records_every_lane_stage():
     assert [r["backend"] for r in rows] == ["pallas"] * len(rows)
 
     batches = rec.named("lane.batch")
-    # family- and policy-homogeneous batches: (none, tree) x (lru, random)
-    assert len(batches) == 4 == rec.counters["lane.batches"]
+    # family-homogeneous batches, each mixing both policies: (none, tree)
+    # x (lru + random)
+    assert len(batches) == 2 == rec.counters["lane.batches"]
     assert {(b.attrs["family"], b.attrs["policy"]) for b in batches} == {
-        ("demand", "lru"), ("demand", "random"), ("tree", "lru"),
-        ("tree", "random")}
-    assert sorted(b.attrs["batch"] for b in batches) == [0, 1, 2, 3]
+        ("demand", "lru+random"), ("tree", "lru+random")}
+    assert rec.counters["lane.mixed_batches"] == 2
+    assert sorted(b.attrs["batch"] for b in batches) == [0, 1]
     assert sum(b.attrs["lanes"] for b in batches) == len(cells) \
         == rec.counters["lane.lanes"]
     assert sum(b.attrs["accesses"] for b in batches) == sum(

@@ -212,8 +212,9 @@ def test_sweep_mixed_family_grid_runs_on_lanes(tmp_path):
 
 def test_sweep_policy_grid_runs_on_lanes(tmp_path):
     """A grid crossing every eviction policy under --backend pallas
-    replays every cell on the lanes (policy-homogeneous batches), rows
-    record the policy, and the numpy backend agrees per cell."""
+    replays every cell on the lanes (one batch of a family holds lanes of
+    every policy), rows record the policy, and the numpy backend agrees
+    per cell."""
     kw = dict(scales=[0.25], device_fracs=[0.5],
               evictions=["lru", "random", "hotcold"])
     cells_p = expand_grid(BENCHES, ["none", "tree"], backend="pallas", **kw)

@@ -48,11 +48,12 @@ def one_chip():
             os.environ.pop("TPU_LOG_DIR", None)
 
 
-def _lane_arg_shapes(family, steps_len):
+def _lane_arg_shapes(family, steps_len, mixed):
     """Argument shapes of a lane program, in the order
     ``PallasReplayBackend._replay_batch`` passes its arrays: pages, the
     family's extra streams, the step-id stream, then the float64 and
-    int32 parameter blocks."""
+    int32 parameter blocks (the latter one column wider in a batch of
+    several eviction policies)."""
     import jax
     import jax.numpy as jnp
 
@@ -66,7 +67,7 @@ def _lane_arg_shapes(family, steps_len):
     return ([i32(T_MAX)] + extra.get(family, [])
             + ([i32(T_MAX)] if steps_len else [])
             + [jax.ShapeDtypeStruct((LANES, _N_FPARAMS), jnp.float64),
-               i32(_N_IPARAMS)])
+               i32(_N_IPARAMS + mixed)])
 
 
 def _oracle_lookahead():
@@ -83,15 +84,16 @@ def _on(sharding, tree):
         tree)
 
 
-@pytest.mark.parametrize("family,policy,steps_len,mt", [
-    ("demand", "lru", STEPS_LEN, False),
-    ("tree", "random", 0, True),
-    ("learned", "hotcold", 0, False),
-    ("oracle", "lru", 0, False),
+@pytest.mark.parametrize("family,policies,steps_len,mt", [
+    ("demand", ("lru",), STEPS_LEN, False),
+    ("tree", ("random",), 0, True),
+    ("learned", ("hotcold",), 0, False),
+    ("oracle", ("lru",), 0, False),
+    ("tree", ("hotcold", "random"), 0, False),
 ], ids=["demand-lru-steps", "tree-random-mt", "learned-hotcold",
-        "oracle-lru"])
-def test_lane_program_compiles_for_v5e(one_chip, family, policy, steps_len,
-                                       mt):
+        "oracle-lru", "tree-hotcold+random"])
+def test_lane_program_compiles_for_v5e(one_chip, family, policies,
+                                       steps_len, mt):
     import jax
 
     from repro.uvm.backends.pallas_backend import _lane_replay_fn
@@ -101,9 +103,10 @@ def test_lane_program_compiles_for_v5e(one_chip, family, policy, steps_len,
     ft_len = SPAN + lookahead if family == "oracle" else 0
     buf_len = UVMConfig().mshr_entries + 1
     with jax.enable_x64(True):
-        fn = _lane_replay_fn(family, policy, LANES, T_MAX, SPAN, buf_len,
+        fn = _lane_replay_fn(family, policies, LANES, T_MAX, SPAN, buf_len,
                              ft_len, lookahead, steps_len, mt)
-        shapes = _on(one_chip, _lane_arg_shapes(family, steps_len))
+        shapes = _on(one_chip, _lane_arg_shapes(family, steps_len,
+                                                len(policies) > 1))
         compiled = fn.lower(*shapes).compile()
     assert compiled.memory_analysis() is not None
     # the lanes are an XLA program, not a Mosaic kernel

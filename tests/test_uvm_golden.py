@@ -101,7 +101,7 @@ def test_fixture_has_no_stale_cells():
 # ---------------------------------------------------------------------------
 # pallas multi-lane backend: every golden cell of each (lane family,
 # eviction policy) bucket in ONE lane batch (demand = none/block, tree,
-# learned (+cached), oracle; batches are policy-homogeneous too)
+# learned (+cached), oracle), so each group runs a single-policy program
 # ---------------------------------------------------------------------------
 
 def _family_of(cell_id):
