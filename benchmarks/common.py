@@ -16,7 +16,7 @@ from repro.core import (
     delta_convergence, revised_config, train_predictor,
 )
 from repro.traces import GPUModel, generate_benchmark
-from repro.uvm import LearnedPrefetcher, UVMConfig
+from repro.uvm import BACKENDS, LearnedPrefetcher, UVMConfig
 from repro.uvm.sweep import (SWEEP_VERSION, SweepCell, run_sweep,
                              simulate_cell)
 
@@ -43,15 +43,15 @@ SWEEP_WORKERS = int(os.environ.get("REPRO_SWEEP_WORKERS", "1"))
 
 # replay backend for every UVM sweep cell (run.py --backend overrides):
 # "auto" = pallas multi-lane kernels on a TPU, the NumPy engine
-# everywhere else; cells record the backend that actually ran in
-# their rows, so fallbacks stay visible in the emitted results.
-# Validated here so a typo fails at import, not mid-sweep after the
-# training suites already burned their wall-clock.
+# everywhere else (the names are replay_core.BACKENDS); cells record the
+# backend that actually ran in their rows, so fallbacks stay visible in
+# the emitted results.  Validated here so a typo fails at import, not
+# mid-sweep after the training suites already burned their wall-clock.
 SWEEP_BACKEND = os.environ.get("REPRO_SWEEP_BACKEND", "auto")
-if SWEEP_BACKEND not in ("auto", "numpy", "pallas"):
+if SWEEP_BACKEND not in BACKENDS:
     raise ValueError(
-        f"REPRO_SWEEP_BACKEND={SWEEP_BACKEND!r}: choose auto, numpy or "
-        "pallas")
+        f"REPRO_SWEEP_BACKEND={SWEEP_BACKEND!r}: choose from "
+        f"{','.join(BACKENDS)}")
 
 ALL_BENCHMARKS = ["AddVectors", "ATAX", "Backprop", "BICG", "Hotspot", "MVT",
                   "NW", "Pathfinder", "Srad-v2", "StreamTriad", "2DCONV"]
@@ -181,8 +181,8 @@ def _eval_cell(bench: str, prefetcher: str, *, prediction_us: float = 1.0,
     return SweepCell(bench=bench, prefetcher=prefetcher,
                      prediction_us=prediction_us, device_pages=device_pages,
                      eviction=eviction,
-                     window=EVAL_WINDOW, engine="vectorized",
-                     backend=SWEEP_BACKEND, service_steps=SERVICE_STEPS)
+                     window=EVAL_WINDOW, backend=SWEEP_BACKEND,
+                     service_steps=SERVICE_STEPS)
 
 
 def _run_cell(cell: SweepCell, timeline: bool = False) -> Dict:

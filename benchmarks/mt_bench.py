@@ -60,7 +60,7 @@ def run() -> List[Dict]:
                         cells.append(SweepCell(
                             bench=bench, prefetcher=pf, scale=SCALE,
                             device_frac=ratio, eviction=ev,
-                            capacity_split=split, engine="vectorized",
+                            capacity_split=split,
                             backend=common.SWEEP_BACKEND))
                         tags.append((bench, ratio, ev, split, pf))
     rows = []
@@ -89,8 +89,7 @@ def run_scenario(name: str) -> List[Dict]:
     rows (per-tenant and interference columns included)."""
     from repro.uvm.scenarios import expand_scenario
 
-    cells = expand_scenario(name, engine="vectorized",
-                            backend=common.SWEEP_BACKEND)
+    cells = expand_scenario(name, backend=common.SWEEP_BACKEND)
     return uvm_sweep(cells)
 
 

@@ -86,8 +86,7 @@ def run_scenario(name: str) -> List[Dict]:
     from benchmarks import common
     from repro.uvm.scenarios import expand_scenario
 
-    cells = expand_scenario(name, engine="vectorized",
-                            backend=common.SWEEP_BACKEND)
+    cells = expand_scenario(name, backend=common.SWEEP_BACKEND)
     return uvm_sweep(cells)
 
 

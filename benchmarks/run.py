@@ -6,13 +6,12 @@ summary CSV at the end (per-table CSVs above it).
     PYTHONPATH=src python -m benchmarks.run --only table1,perf
     PYTHONPATH=src python -m benchmarks.run --only table10,table11,oversub \
         --workers 8                                    # parallel UVM sweeps
-    PYTHONPATH=src python -m benchmarks.run --emit-json BENCH_sweep.json
     PYTHONPATH=src python -m benchmarks.run --scenario oversub-full \
         --workers 8     # full 11-bench x ratio x eviction-policy matrix
 
 The UVM suites (table10/table11/perf/oversub/fig10/fig12) all route through
 ``repro.uvm.sweep``: simulations run on the backend-pluggable replay core
-(``--backend {auto,numpy,pallas}``; pallas packs compatible cells into
+(``--backend {auto,numpy,pallas,legacy}``; pallas packs compatible cells into
 multi-lane kernel batches), non-learned cells fan out over ``--workers``
 processes, and completed cells persist under ``benchmarks/cache/sweep/``
 for resume.  Every sweep row records the backend that actually ran.
@@ -20,7 +19,6 @@ for resume.  Every sweep row records the backend that actually ran.
 from __future__ import annotations
 
 import argparse
-import json
 import time
 import traceback
 
@@ -33,6 +31,7 @@ from benchmarks import (common, family_accuracy, fig5_features,
                         table2_clustering, table3_distance, table4_fc,
                         table5_hlsh, table67_memory, table8_revised,
                         table10_hitrate, table11_unity)
+from repro.uvm import BACKENDS
 
 SUITES = [
     ("table1", table1_transformer.main),
@@ -71,17 +70,13 @@ def main() -> None:
                     help="comma-separated suite names")
     ap.add_argument("--workers", type=int, default=None,
                     help="process fan-out for the UVM sweep suites")
-    ap.add_argument("--backend", default=None,
-                    choices=["auto", "numpy", "pallas"],
+    ap.add_argument("--backend", default=None, choices=list(BACKENDS),
                     help="replay backend for the UVM sweep suites "
                          "(pallas = multi-lane kernel batches; auto "
                          "picks pallas on a TPU and numpy elsewhere; "
                          "every result row records "
                          "the backend that actually ran, so per-cell "
                          "fallbacks are visible)")
-    ap.add_argument("--emit-json", default=None, metavar="PATH",
-                    help="write per-suite wall-clock rows as JSON so "
-                         "future PRs can diff the perf trajectory")
     ap.add_argument("--scenario", default=None,
                     help="run a named repro.uvm.scenarios oversubscription "
                          "matrix (e.g. oversub-full) as the only suite, "
@@ -100,17 +95,12 @@ def main() -> None:
     if args.scenario:
         # scenario routing replaces the suite list: each name is a
         # registry-defined (bench x ratio x eviction x prefetcher) matrix,
-        # resumable; oversub_bench's own --emit-json writes the row-level
-        # JSON (the per-suite wall-clock doc below is still written when
-        # asked).  Comma lists run several matrices as separate suites —
-        # module/argv are bound per iteration via default args so the
-        # closures don't all collapse onto the last scenario.
+        # resumable.  Comma lists run several matrices as separate
+        # suites — module/argv are bound per iteration via default args
+        # so the closures don't all collapse onto the last scenario.
         suites = []
         for scen in args.scenario.split(","):
             scenario_argv = ["--scenario", scen]
-            if args.emit_json:
-                scenario_argv += ["--emit-json",
-                                  f"{args.emit_json}.{scen}.rows.json"]
             # serve-* scenarios route through serve_bench so the printed
             # table carries the SLO latency columns; mt-* through
             # mt_bench for the per-tenant/interference columns
@@ -121,7 +111,6 @@ def main() -> None:
                            lambda m=module, a=scenario_argv: m.main(a)))
         only = None
 
-    t_start = time.time()
     summary = []
     failed = []
     for name, fn in suites:
@@ -141,21 +130,6 @@ def main() -> None:
     print("name,us_per_call,derived")
     for name, us, status in summary:
         print(f"{name},{us:.0f},{status}")
-    if args.emit_json:
-        doc = {
-            "version": 1,
-            "quick": common.QUICK,
-            "workers": common.SWEEP_WORKERS,
-            "backend": common.SWEEP_BACKEND,
-            "scenario": args.scenario,
-            "total_seconds": time.time() - t_start,
-            "rows": [{"suite": name, "seconds": us / 1e6, "status": status}
-                     for name, us, status in summary],
-        }
-        with open(args.emit_json, "w") as f:
-            json.dump(doc, f, indent=1, sort_keys=True)
-            f.write("\n")
-        print(f"wrote {args.emit_json}")
     if failed:
         raise SystemExit(f"failed suites: {failed}")
 

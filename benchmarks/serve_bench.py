@@ -15,7 +15,7 @@ CLI::
 
     PYTHONPATH=src python -m benchmarks.serve_bench
     PYTHONPATH=src python -m benchmarks.serve_bench \
-        --emit-json BENCH_serve.json            # SLO trajectory rows
+        --emit-json serve_rows.json             # SLO rows as JSON
     PYTHONPATH=src python -m benchmarks.serve_bench \
         --scenario serve-smoke                  # registry-routed matrix
 
@@ -69,7 +69,6 @@ def run() -> List[Dict]:
                     cells.append(SweepCell(
                         bench=bench, prefetcher=pf, scale=SCALE,
                         window=None, device_frac=ratio, eviction=ev,
-                        engine="vectorized",
                         backend=common.SWEEP_BACKEND))
                     tags.append((bench, ratio, ev, pf))
     rows = []
@@ -94,8 +93,7 @@ def run_scenario(name: str) -> List[Dict]:
     serve latency columns included)."""
     from repro.uvm.scenarios import expand_scenario
 
-    cells = expand_scenario(name, engine="vectorized",
-                            backend=common.SWEEP_BACKEND)
+    cells = expand_scenario(name, backend=common.SWEEP_BACKEND)
     return uvm_sweep(cells)
 
 

@@ -1,55 +1,35 @@
 """Gate a fresh BENCH_*.json emission against its committed baseline.
 
-    python scripts/check_bench.py BENCH_lanes.json /tmp/new_lanes.json
-    REPRO_BENCH_TOL=0.75 python scripts/check_bench.py BENCH_sweep.json ...
+    python scripts/check_bench.py BENCH_mt.json /tmp/new_mt.json
 
-Three rule classes, applied per row (rows are keyed by their ``name`` —
-or ``suite`` for ``benchmarks.run`` docs):
+Two rule classes, applied per row (rows are keyed by their ``name``):
 
 * **Schema** — the set of row names and the key set of every row must
   match the baseline exactly.  Missing or extra rows/keys fail the run
   unconditionally: schema drift in a trajectory file silently breaks
   every later diff, so it is never tolerated.
-* **Counters** — ``counter_*`` fields (and the exact-match fields
-  ``rows``/``lanes``/``accesses``/``status``) must be identical.  Counter
-  drift is a correctness bug, not a perf regression; no tolerance applies.
-* **Timing** — ``seconds`` and ``*_s`` fields may regress by at most
-  ``REPRO_BENCH_TOL`` (fractional slack over the baseline, default
-  %(tol)s; ``0`` disables the timing gate entirely, e.g. on a host class
-  the baselines were not recorded on).  Speedups always pass — rerecord
-  the baseline to ratchet them in.
+* **Counters** — ``counter_*`` fields must be identical.  Counter drift
+  is a correctness bug, not a perf regression; no tolerance applies.
+
+Speed is not gated here: the benchmark (``bench/run.py``) measures it on
+the chip.
 """
 from __future__ import annotations
 
 import json
 import os
 import sys
-from typing import Dict, List
-
-DEFAULT_TOL = 0.5
-EXACT_FIELDS = ("rows", "lanes", "accesses", "status")
-TIMING_FIELDS = ("seconds",)
+from typing import List
 
 
-def _row_key_field(rows: List[Dict]) -> str:
-    if rows and "name" in rows[0]:
-        return "name"
-    return "suite"
-
-
-def _is_timing(field: str) -> bool:
-    return field in TIMING_FIELDS or field.endswith("_s")
-
-
-def check(baseline_path: str, current_path: str, tol: float) -> List[str]:
+def check(baseline_path: str, current_path: str) -> List[str]:
     with open(baseline_path) as f:
         base_doc = json.load(f)
     with open(current_path) as f:
         cur_doc = json.load(f)
     errors: List[str] = []
-    key = _row_key_field(base_doc["rows"])
-    base = {r[key]: r for r in base_doc["rows"]}
-    cur = {r.get(key): r for r in cur_doc["rows"]}
+    base = {r["name"]: r for r in base_doc["rows"]}
+    cur = {r.get("name"): r for r in cur_doc["rows"]}
 
     missing = sorted(set(base) - set(cur))
     extra = sorted(set(cur) - set(base))
@@ -67,32 +47,23 @@ def check(baseline_path: str, current_path: str, tol: float) -> List[str]:
                           f"{sorted(set(c) - set(b))}")
             continue
         for field in sorted(b):
-            bv, cv = b[field], c[field]
-            if field.startswith("counter_") or field in EXACT_FIELDS:
-                if bv != cv:
-                    errors.append(f"{name}: {field} drifted "
-                                  f"{bv!r} -> {cv!r} (always fatal)")
-            elif _is_timing(field) and tol > 0:
-                if cv > bv * (1.0 + tol):
-                    errors.append(
-                        f"{name}: {field} regressed {bv:.3f}s -> "
-                        f"{cv:.3f}s (> {tol:.0%} over baseline)")
+            if field.startswith("counter_") and b[field] != c[field]:
+                errors.append(f"{name}: {field} drifted "
+                              f"{b[field]!r} -> {c[field]!r} (always fatal)")
     return errors
 
 
 def main(argv: List[str]) -> int:
     if len(argv) != 3 or argv[1] in ("-h", "--help"):
-        print(__doc__ % {"tol": DEFAULT_TOL})
+        print(__doc__)
         return 2
-    tol = float(os.environ.get("REPRO_BENCH_TOL", str(DEFAULT_TOL)))
-    errors = check(argv[1], argv[2], tol)
+    errors = check(argv[1], argv[2])
     tag = os.path.basename(argv[1])
     if errors:
         for e in errors:
             print(f"[check_bench] {tag}: FAIL: {e}")
         return 1
-    gate = "disabled" if tol <= 0 else f"tol {tol:.0%}"
-    print(f"[check_bench] {tag}: ok (timing gate {gate})")
+    print(f"[check_bench] {tag}: ok")
     return 0
 
 

@@ -26,13 +26,14 @@ The replay stack has three layers (see ``repro.uvm.backends/README.md``):
   ``PYTHONPATH=src python scripts/regen_uvm_golden.py``).
 * the scheduler in ``repro.uvm.sweep`` — groups packable sweep cells into
   lane batches, dispatches to the selected backend
-  (``--backend {numpy,pallas,auto}``), falls back per cell to the NumPy
-  path for anything unpackable, and records the backend that actually
-  ran in every result row.
+  (``--backend {auto,numpy,pallas,legacy}``), falls back per cell down
+  the backend's chain for anything unpackable, and records the backend
+  that actually ran in every result row.
 
-``UVMSimulator`` is the reference loop; ``VectorizedUVMSimulator`` is a
-drop-in equivalent on the numpy backend; ``simulate(trace, prefetcher,
-config, engine=..., backend=...)`` picks both per cell.
+``UVMSimulator`` is the reference loop.  One cell replays on a chosen
+backend with ``dispatch(ReplayRequest(trace, prefetcher, config),
+backend=...)``; ``backend`` is the one selector of the replay path, its
+names are ``replay_core.BACKENDS``.
 
 Batched sweeps
 --------------
@@ -58,11 +59,10 @@ in the process that calls ``run_sweep``, before any ``--workers``
 fan-out: one process holds the chip.
 """
 from repro.uvm.config import UVMConfig
-from repro.uvm.engine import VectorizedUVMSimulator, simulate
 from repro.uvm.eviction import EVICTION_POLICIES
 from repro.uvm.metrics import unity
-from repro.uvm.replay_core import (ReplayBackend, ReplayRequest,
-                                   available_backends, get_backend)
+from repro.uvm.replay_core import (BACKENDS, ReplayBackend, ReplayRequest,
+                                   available_backends, dispatch, get_backend)
 from repro.uvm.prefetchers import (
     NoPrefetcher, TreePrefetcher, LearnedPrefetcher, OraclePrefetcher,
     Prefetcher,
@@ -70,9 +70,9 @@ from repro.uvm.prefetchers import (
 from repro.uvm.simulator import UVMSimulator, UVMStats
 
 __all__ = [
-    "UVMConfig", "UVMSimulator", "UVMStats", "VectorizedUVMSimulator",
-    "simulate", "unity", "EVICTION_POLICIES",
-    "ReplayBackend", "ReplayRequest", "available_backends", "get_backend",
+    "UVMConfig", "UVMSimulator", "UVMStats", "unity", "EVICTION_POLICIES",
+    "BACKENDS", "ReplayBackend", "ReplayRequest", "available_backends",
+    "dispatch", "get_backend",
     "Prefetcher", "NoPrefetcher", "TreePrefetcher", "LearnedPrefetcher",
     "OraclePrefetcher",
 ]

@@ -1,9 +1,10 @@
 """The NumPy-chunked replay core as a replay backend.
 
-This is the original ``VectorizedUVMSimulator`` array program (moved into
-``repro.uvm.replay_core.replay_chunked``) behind the ``ReplayBackend``
-interface, unchanged: bit-identical to the legacy loop for every supported
-prefetcher type, pinned by ``tests/test_uvm_golden.py``.
+The chunked array program ``repro.uvm.replay_core.replay_chunked`` behind
+the ``ReplayBackend`` interface: bit-identical to the legacy loop for every
+supported prefetcher type, pinned by ``tests/test_uvm_golden.py``.  Ask
+for it with ``dispatch(request, backend="numpy")``, whose chain falls back
+to ``legacy`` for what it declines.
 """
 from __future__ import annotations
 

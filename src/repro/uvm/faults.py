@@ -78,6 +78,8 @@ import sys
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.uvm.replay_core import BACKENDS
+
 #: environment variable carrying the active plan: inline JSON (starts with
 #: ``{``) or a path to a JSON file
 FAULT_PLAN_ENV = "REPRO_FAULT_PLAN"
@@ -399,11 +401,10 @@ def attempt_budget(plan: FaultPlan, margin: int = 2) -> int:
 def _sweep_argv(out_dir: str, *, scenario: Optional[str] = None,
                 benches: Optional[str] = None,
                 prefetchers: Optional[str] = None,
-                backend: str = "numpy", engine: str = "auto",
+                backend: str = "numpy",
                 workers: int = 1, scale: Optional[float] = None) -> List[str]:
     argv = [sys.executable, "-m", "repro.uvm.sweep", "--out", out_dir,
-            "--backend", backend, "--engine", engine,
-            "--workers", str(workers)]
+            "--backend", backend, "--workers", str(workers)]
     if scenario:
         argv += ["--scenario", scenario]
     else:
@@ -462,7 +463,7 @@ def chaos_converge(argv: List[str], plan: FaultPlan, *,
 def run_chaos_check(out_dir: str, *, scenario: Optional[str] = None,
                     benches: Optional[str] = None,
                     prefetchers: Optional[str] = None,
-                    backend: str = "numpy", engine: str = "auto",
+                    backend: str = "numpy",
                     workers: int = 1, seed: int = 0,
                     scale: Optional[float] = None,
                     plan: Optional[FaultPlan] = None,
@@ -483,7 +484,7 @@ def run_chaos_check(out_dir: str, *, scenario: Optional[str] = None,
         plan = default_chaos_plan(ledger, seed=seed)
 
     kw = dict(scenario=scenario, benches=benches, prefetchers=prefetchers,
-              backend=backend, engine=engine, workers=workers, scale=scale)
+              backend=backend, workers=workers, scale=scale)
     if verbose:
         print(f"[chaos] baseline run -> {base_out}", flush=True)
     proc = subprocess.run(_sweep_argv(base_out, **kw), env=_run_env(None),
@@ -547,10 +548,7 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "alternatively --benches/--prefetchers")
     ap.add_argument("--benches", default=None)
     ap.add_argument("--prefetchers", default=None)
-    ap.add_argument("--backend", default="numpy",
-                    choices=["auto", "numpy", "pallas"])
-    ap.add_argument("--engine", default="auto",
-                    choices=["auto", "vectorized", "legacy"])
+    ap.add_argument("--backend", default="numpy", choices=list(BACKENDS))
     ap.add_argument("--workers", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--plan", default=None,
@@ -568,7 +566,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     report = run_chaos_check(
         args.out, scenario=args.scenario, benches=args.benches,
         prefetchers=args.prefetchers, backend=args.backend,
-        engine=args.engine, workers=args.workers, seed=args.seed,
+        workers=args.workers, seed=args.seed,
         plan=plan, max_restarts=args.max_restarts)
     print(json.dumps(report, sort_keys=True))
 

@@ -3,11 +3,12 @@
 The replay stack is split into three explicit layers:
 
 1. **Replay core (this module).**  The chunk classification /
-   clock-cumsum / event-subsequence state machine that used to live inside
-   ``VectorizedUVMSimulator`` (``repro.uvm.engine``), expressed as a pure
+   clock-cumsum / event-subsequence state machine, expressed as a pure
    array program over a dense page span: :func:`replay_chunked` plus the
    per-prefetcher scan/callback adapters.  It also defines the narrow
-   :class:`ReplayBackend` interface and the backend registry.
+   :class:`ReplayBackend` interface, the backend registry, and the one
+   backend vocabulary (:data:`BACKENDS`, turned into a fallback chain by
+   :func:`backend_chain`) that every caller picks a replay path with.
 2. **Backends (``repro.uvm.backends``).**  Implementations of
    :class:`ReplayBackend`: the reference per-access loop (``legacy``), the
    NumPy-chunked engine (``numpy``, bit-identical to legacy), and a
@@ -205,6 +206,13 @@ def available_backends() -> List[str]:
     return sorted(_REGISTRY)
 
 
+#: the backend names a caller may request: ``auto`` (pallas lanes on a
+#: TPU, numpy elsewhere), ``numpy``, ``pallas``, or ``legacy`` (the
+#: reference per-access loop) — :func:`backend_chain` turns each into
+#: its fallback chain
+BACKENDS = ("auto", "numpy", "pallas", "legacy")
+
+
 def backend_chain(backend: str = "auto") -> List[str]:
     """Fallback order for a requested backend.
 
@@ -228,7 +236,8 @@ def backend_chain(backend: str = "auto") -> List[str]:
         if pallas is not None and pallas.is_native():
             return ["pallas", "numpy", "legacy"]
         return ["numpy", "legacy"]
-    raise ValueError(f"unknown replay backend {backend!r}")
+    raise ValueError(f"unknown replay backend {backend!r}; "
+                     f"choose from {','.join(BACKENDS)}")
 
 
 def resolve_backend(request: ReplayRequest,

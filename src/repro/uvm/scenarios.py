@@ -173,8 +173,7 @@ class Scenario:
         return self
 
     # ------------------------------------------------------------------
-    def cells(self, *, engine: str = "auto",
-              backend: str = "auto") -> List[SweepCell]:
+    def cells(self, *, backend: str = "auto") -> List[SweepCell]:
         """Expand the matrix in deterministic order, each cell stamped
         with the scenario name (the sweep's resume store keys on it)."""
         out = []
@@ -193,7 +192,7 @@ class Scenario:
                                         device_frac=ratio,
                                         eviction=eviction,
                                         capacity_split=split,
-                                        scenario=self.name, engine=engine,
+                                        scenario=self.name,
                                         backend=backend,
                                         service_steps=self.service_steps,
                                         model_family=fam))
@@ -248,11 +247,10 @@ def available_scenarios() -> List[str]:
     return sorted(_SCENARIOS)
 
 
-def expand_scenario(name: str, *, engine: str = "auto",
-                    backend: str = "auto") -> List[SweepCell]:
+def expand_scenario(name: str, *, backend: str = "auto") -> List[SweepCell]:
     """Expand a registered scenario into sweep cells (the CLI entry:
     ``python -m repro.uvm.sweep --scenario <name>``)."""
-    return get_scenario(name).cells(engine=engine, backend=backend)
+    return get_scenario(name).cells(backend=backend)
 
 
 # ---------------------------------------------------------------------------

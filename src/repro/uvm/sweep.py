@@ -28,23 +28,24 @@ CLI::
 Backend scheduling
 ------------------
 
-Each cell names a replay backend (``--backend {numpy,pallas,auto}``; also
-the ``REPRO_SWEEP_BACKEND`` env var).  The scheduler groups pending
-pallas-eligible cells — every paper-facing prefetcher
-(none/block/tree/learned/oracle) whose page span fits a lane — into
-multi-lane batches bucketed by *prefetcher family* in addition to
-span/length (a lane batch is always family-homogeneous: demand, tree,
-learned, and oracle lanes are different kernels with different per-lane
-state) and replays each batch in ONE ``jax_pallas`` kernel launch (one
-lane per cell, padded to the longest trace; see
+Each cell names a replay backend, the one selector of its replay path
+(``--backend {auto,numpy,pallas,legacy}``, the names of
+``repro.uvm.replay_core.BACKENDS``; also the ``REPRO_SWEEP_BACKEND`` env
+var).  The scheduler groups pending pallas-eligible cells — every
+paper-facing prefetcher (none/block/tree/learned/oracle) whose page span
+fits a lane — into multi-lane batches bucketed by *prefetcher family* in
+addition to span/length (a lane batch is always family-homogeneous:
+demand, tree, learned, and oracle lanes are different kernels with
+different per-lane state) and replays each batch in ONE ``jax_pallas``
+kernel launch (one lane per cell, padded to the longest trace; see
 ``repro.uvm.backends.pallas_backend``).  Everything unpackable falls back
 *per cell* down the ``pallas → numpy → legacy`` chain, and every result
 row records the backend that actually ran in its ``backend`` column, so
 fallbacks are visible instead of silently reading as covered.  That
-per-cell fallback covers only what the lanes decline by contract (span
-or length caps, timelines); a lane batch that fails at runtime raises.
-``auto`` resolves to the pallas lanes on a TPU, where they are a
-compiled device program, and to the NumPy engine everywhere else.
+per-cell fallback covers only what the lanes decline by contract (span or
+length caps, timelines); a lane batch that fails at runtime raises.
+``auto`` resolves to the pallas lanes on a TPU, where they are a compiled
+device program, and to the NumPy engine everywhere else.
 
 One process holds the chip
 --------------------------
@@ -62,7 +63,7 @@ Train-once learned cells
 The ``learned`` prefetcher needs the paper's predictor service (jax;
 expensive to train), but its predictions depend only on the *trace content*
 and the *predictor config* — not on the replay knobs (``prediction_us``,
-``device_frac``/``device_pages``, engine, backend) a sensitivity grid
+``device_frac``/``device_pages``, backend) a sensitivity grid
 varies.
 :func:`make_prefetcher` therefore routes predictions through
 ``repro.uvm.predcache``: a grid trains **once per (trace, model) pair** and
@@ -147,12 +148,12 @@ from repro.distributed import fault_tolerance as ft
 from repro.traces.trace import ACCESS_DTYPE, Trace
 from repro.uvm import adaptive, faults
 from repro.uvm.config import UVMConfig
-from repro.uvm.engine import simulate
 from repro.uvm.eviction import EVICTION_POLICIES
 from repro.uvm.prefetchers import (BlockPrefetcher, LearnedPrefetcher,
                                    NoPrefetcher, OraclePrefetcher,
                                    Prefetcher, TreePrefetcher)
-from repro.uvm.replay_core import ReplayRequest, backend_chain, get_backend
+from repro.uvm.replay_core import (BACKENDS, ReplayRequest, backend_chain,
+                                   dispatch, get_backend)
 from repro.uvm.simulator import UVMStats
 
 #: cell-spec prefetcher names to concrete types — the single source the
@@ -163,7 +164,6 @@ _PREFETCHER_TYPES = {"none": NoPrefetcher, "block": BlockPrefetcher,
                      "tree": TreePrefetcher, "learned": LearnedPrefetcher,
                      "oracle": OraclePrefetcher}
 PREFETCHERS = tuple(_PREFETCHER_TYPES)
-BACKENDS = ("auto", "numpy", "pallas")
 
 #: bump on any intentional change to the timing model, trace generators,
 #: prediction pipeline, or row schema — invalidates persisted sweep cells
@@ -200,15 +200,14 @@ MT_FIELDS = (
     "slowdown_t0", "slowdown_t1", "interference_slowdown",
 )
 
-#: columns of the structured results, in CSV order (``engine`` is the
-#: requested replay style, ``backend`` the implementation that actually
-#: ran the cell: legacy / numpy / pallas; ``eviction`` the policy the
-#: cell replayed under, ``scenario`` the scenario-registry entry the
-#: cell expanded from — None for ad-hoc grids)
+#: columns of the structured results, in CSV order (``backend`` is the
+#: implementation that actually ran the cell: legacy / numpy / pallas;
+#: ``eviction`` the policy the cell replayed under, ``scenario`` the
+#: scenario-registry entry the cell expanded from — None for ad-hoc grids)
 ROW_FIELDS = [
     "bench", "prefetcher", "scale", "seed", "window", "prediction_us",
     "device_pages", "device_frac", "eviction", "model_family", "scenario",
-    "engine", "backend", "n_accesses", "n_instructions",
+    "backend", "n_accesses", "n_instructions",
     "cycles", "ipc", "hits", "late", "faults", "hit_rate", "prefetch_issued",
     "prefetch_used", "accuracy", "coverage", "unity", "pages_migrated",
     "pages_evicted", "pcie_bytes", *SERVE_LATENCY_FIELDS, "slo_source",
@@ -257,8 +256,7 @@ class SweepCell:
     eviction: str = "lru"               # lru | random | hotcold | adaptive
     capacity_split: Optional[str] = None  # mt cells: "shared" | "f0/f1"
     scenario: Optional[str] = None      # scenario-registry entry (if any)
-    engine: str = "auto"
-    backend: str = "auto"               # numpy | pallas | auto
+    backend: str = "auto"               # replay_core.BACKENDS
     service_steps: int = 150            # learned-predictor training steps
     model_family: str = "simplified"    # predictor family for learned cells
                                         # (repro.core.families.MODEL_FAMILIES)
@@ -282,7 +280,6 @@ def expand_grid(benches: Sequence[str], prefetchers: Sequence[str], *,
                 model_families: Sequence[str] = ("simplified",),
                 capacity_splits: Sequence[Optional[str]] = (None,),
                 scenario: Optional[str] = None,
-                engine: str = "auto",
                 backend: str = "auto",
                 service_steps: int = 150) -> List[SweepCell]:
     """Cartesian product of the sweep axes, in deterministic order."""
@@ -306,7 +303,6 @@ def expand_grid(benches: Sequence[str], prefetchers: Sequence[str], *,
                                                 eviction=ev,
                                                 capacity_split=split,
                                                 scenario=scenario,
-                                                engine=engine,
                                                 backend=backend,
                                                 service_steps=service_steps,
                                                 model_family=fam))
@@ -808,9 +804,10 @@ def simulate_cell(cell: SweepCell, *, cache_dir: Optional[str] = None,
     serve_bounds = _serve_step_bounds(trace)
     step_bounds = serve_bounds if serve_bounds is not None \
         else _mt_step_bounds(trace)
-    stats = simulate(trace, prefetcher, config, engine=cell.engine,
-                     backend=cell.backend, record_timeline=record_timeline,
-                     step_bounds=step_bounds)
+    request = ReplayRequest(trace=trace, prefetcher=prefetcher,
+                            config=config, record_timeline=record_timeline,
+                            step_bounds=step_bounds)
+    stats = dispatch(request, cell.backend)
     row = _finish_row(cell, stats, device_pages, time.time() - t0,
                       record_timeline)
     if serve_bounds is not None:
@@ -1224,13 +1221,20 @@ def _family_of_prefetcher_name() -> Dict[str, str]:
             if t in FAMILY_BY_TYPE}
 
 
+#: the lane scheduler's pipeline (:func:`_run_lane_batches`): threads of
+#: the prepare pool, how many cells ahead of the batcher it prepares, and
+#: how many lane batches replay at once on the flush pool
+PREP_THREADS = 4
+PREP_WINDOW = 32
+FLUSH_THREADS = 2
+
+
 def _wants_lanes(cell: SweepCell) -> bool:
     """True when this cell's backend chain starts at the pallas lanes (an
     explicit ``backend="pallas"`` or ``auto`` on an accelerator host) and
     its prefetcher can be packed at all — anything else skips
     trace/prefetcher preparation and goes straight to the per-cell path."""
-    return (cell.engine in ("auto", "vectorized")
-            and cell.prefetcher in _packable_prefetcher_names()
+    return (cell.prefetcher in _packable_prefetcher_names()
             and backend_chain(cell.backend)[0] == "pallas")
 
 
@@ -1250,21 +1254,20 @@ def _run_lane_batches(cells: Sequence[SweepCell],
 
     * *prepare* — trace generation/deserialization and predcache
       inference run in a small thread pool a bounded lookahead window
-      ahead of the batcher (``REPRO_SWEEP_PREP_THREADS`` /
-      ``REPRO_SWEEP_PREP_WINDOW``); the trace memo means co-scheduled
+      ahead of the batcher (``PREP_THREADS`` threads, ``PREP_WINDOW``
+      cells ahead); the trace memo means co-scheduled
       cells sharing a trace resolve to one deserialize + one checksum.
     * *pack* — the main thread consumes prepared cells **in scheduler
       order** (results stay deterministic) and packs lanes under
       ``fits_batch``'s budgets, exactly as before.
     * *flush* — each full batch replays on a small flush pool while the
-      main thread packs the next one.  At most ``REPRO_SWEEP_FLUSH_THREADS``
-      batches (default 2 — independent policy/family batches parallelize
+      main thread packs the next one.  At most ``FLUSH_THREADS``
+      batches (2 — independent policy/family batches parallelize
       across cores, XLA releases the GIL) are in flight plus one being
       packed, so batch residency stays O(1) and the whole grid is never
       materialized — the bounded-memory property of the serial scheduler
-      survives (set the knob to 1 for strict one-in-flight residency),
-      shrunk further by the trace memo sharing Trace objects across
-      lanes.
+      survives, shrunk further by the trace memo sharing Trace objects
+      across lanes.
 
     Serve cells carry their decode-step bounds into the lane request, so
     the kernel emits per-step clocks in-band and the row's SLO columns
@@ -1317,8 +1320,7 @@ def _run_lane_batches(cells: Sequence[SweepCell],
                     out[i] = row
             return out
 
-    n_flush = max(1, int(_env_num("REPRO_SWEEP_FLUSH_THREADS", 2)))
-    flush_pool = ThreadPoolExecutor(max_workers=n_flush)
+    flush_pool = ThreadPoolExecutor(max_workers=FLUSH_THREADS)
     inflight: collections.deque = collections.deque()   # FIFO of futures
     n_flushed = 0                            # index of the batch being packed
 
@@ -1339,7 +1341,7 @@ def _run_lane_batches(cells: Sequence[SweepCell],
             print(f"[sweep] pallas lanes: replaying {len(batch)} cells "
                   "in one batch", flush=True)
         faults.fire("lane.flush", f"{len(batch)}:{cells[batch[0]].key()}")
-        _await_inflight(room=n_flush - 1)    # bounded batches in flight
+        _await_inflight(room=FLUSH_THREADS - 1)  # bounded batches in flight
         inflight.append(flush_pool.submit(
             _replay_batch_rows, n_flushed, list(batch), list(requests),
             list(caps), list(shapes)))
@@ -1358,9 +1360,7 @@ def _run_lane_batches(cells: Sequence[SweepCell],
                    key=lambda i: (families.get(cells[i].prefetcher, "~"),
                                   cells[i].eviction, i))
 
-    n_prep = max(1, int(_env_num("REPRO_SWEEP_PREP_THREADS", 4)))
-    prep_window = max(1, int(_env_num("REPRO_SWEEP_PREP_WINDOW", 32)))
-    prep_pool = ThreadPoolExecutor(max_workers=n_prep)
+    prep_pool = ThreadPoolExecutor(max_workers=PREP_THREADS)
     pending = collections.deque()            # (i, future) in scheduler order
     feed = iter(order)
 
@@ -1370,7 +1370,7 @@ def _run_lane_batches(cells: Sequence[SweepCell],
             return prepare_cell(cells[i], cache_dir=cache_dir)
 
     def _top_up() -> None:
-        while len(pending) < prep_window:
+        while len(pending) < PREP_WINDOW:
             try:
                 i = next(feed)
             except StopIteration:
@@ -1624,15 +1624,13 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "repro.uvm.scenarios (e.g. 'oversub-full': the "
                          "full 11-benchmark x ratio x eviction-policy x "
                          "prefetcher matrix) instead of the grid flags; "
-                         "--engine/--backend/--out/--workers still apply "
+                         "--backend/--out/--workers still apply "
                          "and completed cells resume as usual")
-    ap.add_argument("--engine", default="auto",
-                    choices=["auto", "vectorized", "legacy"])
     ap.add_argument("--backend", default=None, choices=list(BACKENDS),
                     help="replay backend: numpy, pallas (multi-lane "
-                         "kernel batches), or auto (pallas on a TPU, "
-                         "numpy otherwise); defaults to "
-                         "$REPRO_SWEEP_BACKEND or auto")
+                         "kernel batches), legacy (the reference loop), "
+                         "or auto (pallas on a TPU, numpy otherwise); "
+                         "defaults to $REPRO_SWEEP_BACKEND or auto")
     ap.add_argument("--out", default=None, help="results directory")
     ap.add_argument("--workers", type=int, default=1)
     ap.add_argument("--no-resume", action="store_true")
@@ -1646,8 +1644,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     if args.scenario:
         from repro.uvm.scenarios import available_scenarios, expand_scenario
         try:
-            cells = expand_scenario(args.scenario, engine=args.engine,
-                                    backend=backend)
+            cells = expand_scenario(args.scenario, backend=backend)
         except KeyError:
             ap.error(f"unknown scenario {args.scenario!r}; choose from "
                      f"{','.join(available_scenarios())}")
@@ -1704,7 +1701,7 @@ def main(argv: Optional[List[str]] = None) -> None:
             prediction_us=[float(x) for x in args.prediction_us.split(",")],
             device_fracs=fracs, evictions=evictions,
             model_families=model_families, capacity_splits=splits,
-            engine=args.engine, backend=backend)
+            backend=backend)
     t0 = time.time()
     rows = run_sweep(cells, out_dir=args.out, workers=args.workers,
                      resume=not args.no_resume, verbose=True)

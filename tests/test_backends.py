@@ -149,7 +149,6 @@ def test_numpy_runtime_failure_propagates(monkeypatch):
     """A numpy-engine crash must surface, not silently serve legacy
     results (which would let the golden equivalence suite pass
     vacuously)."""
-    from repro.uvm import VectorizedUVMSimulator
     from repro.uvm.backends.numpy_backend import NumpyReplayBackend
 
     def _boom(self, requests):
@@ -157,8 +156,8 @@ def test_numpy_runtime_failure_propagates(monkeypatch):
 
     monkeypatch.setattr(NumpyReplayBackend, "replay", _boom)
     with pytest.raises(IndexError, match="synthetic engine bug"):
-        VectorizedUVMSimulator().run(_mk_trace(np.arange(10)),
-                                     NoPrefetcher())
+        dispatch(ReplayRequest(_mk_trace(np.arange(10)), NoPrefetcher(),
+                               UVMConfig()), backend="numpy")
 
 
 def test_pallas_runtime_failure_degrades_with_warning(monkeypatch):

@@ -19,9 +19,10 @@ from repro.traces.interleave import (N_TENANTS, build_mt_trace, is_mt_bench,
                                      tenant_boundary, tenant_counts,
                                      tenant_last_index, tenant_stream)
 from repro.traces.trace import ROOT_PAGES, Trace, make_records
-from repro.uvm import UVMConfig, UVMSimulator, VectorizedUVMSimulator
+from repro.uvm import UVMConfig, UVMSimulator
 from repro.uvm.eviction import resolve_tenancy
 from repro.uvm.prefetchers import NoPrefetcher
+from repro.uvm.replay_core import ReplayRequest, dispatch
 from repro.uvm.sweep import MT_FIELDS, SweepCell, parse_capacity_split, \
     simulate_cell
 
@@ -221,8 +222,8 @@ def _protected_run(co_pages, tenant_pages, eviction="lru"):
     tr = _mk_mt_trace(pages0, co_pages, boundary=BOUNDARY)
     cfg = UVMConfig(device_pages=400, tenant_pages=tenant_pages,
                     eviction=eviction)
-    return VectorizedUVMSimulator(cfg, strict_checks=True).run(
-        tr, NoPrefetcher())
+    return dispatch(ReplayRequest(tr, NoPrefetcher(), cfg, strict_checks=True),
+                    backend="numpy")
 
 
 IDLE = np.arange(10, dtype=np.int64)                        # 10 accesses
@@ -263,8 +264,8 @@ def test_isolation_property_matches_legacy_engine():
     cfg = UVMConfig(device_pages=400, tenant_pages=(250, 100),
                     eviction="hotcold")
     legacy = UVMSimulator(cfg).run(tr, NoPrefetcher())
-    vec = VectorizedUVMSimulator(cfg, strict_checks=True).run(
-        tr, NoPrefetcher())
+    vec = dispatch(ReplayRequest(tr, NoPrefetcher(), cfg, strict_checks=True),
+                   backend="numpy")
     assert tuple(vec.tenant_hits) == tuple(legacy.tenant_hits)
     assert tuple(vec.tenant_accesses) == tuple(legacy.tenant_accesses)
     assert vec.hits == legacy.hits and vec.faults == legacy.faults

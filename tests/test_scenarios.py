@@ -30,7 +30,7 @@ from repro.uvm.adaptive import ADAPTIVE_POLICY
 from repro.uvm.eviction import (EVICTION_POLICIES, eviction_score,
                                 eviction_scores, make_eviction_policy)
 from repro.uvm.golden import make_prefetcher
-from repro.uvm.replay_core import ReplayRequest, get_backend
+from repro.uvm.replay_core import ReplayRequest, dispatch, get_backend
 from repro.uvm.scenarios import (DEFAULT_RATIOS, PAPER_BENCHMARKS, Scenario,
                                  available_scenarios, expand_scenario,
                                  get_scenario, register_scenario,
@@ -301,13 +301,12 @@ if HAVE_HYPOTHESIS:
     def test_invariants_random_cells(pages, eviction, cap, pf_name):
         """Random traces: the conservation laws hold for every policy on
         the numpy engine (strict_checks asserts the internal ones too)."""
-        from repro.uvm import VectorizedUVMSimulator
-
         tr = _mk_trace(np.asarray(pages, dtype=np.int64))
         config = UVMConfig(device_pages=cap, mshr_entries=16,
                            eviction=eviction)
-        st = VectorizedUVMSimulator(config, strict_checks=True).run(
-            tr, make_prefetcher(pf_name, tr, config))
+        st = dispatch(ReplayRequest(tr, make_prefetcher(pf_name, tr, config),
+                                    config, strict_checks=True),
+                      backend="numpy")
         assert st.hits + st.late + st.faults == st.n_accesses
         assert st.pages_migrated >= st.faults
         assert st.pages_migrated - st.pages_evicted >= 0

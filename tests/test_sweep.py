@@ -128,10 +128,10 @@ def test_results_json_csv_roundtrip(tmp_path):
     assert _strip_timing(read_results(out)) == _strip_timing(rows)
 
 
-def test_engine_choice_is_equivalent():
+def test_backend_choice_is_equivalent():
     base = dict(bench="ATAX", prefetcher="tree", scale=0.25)
-    vec = simulate_cell(SweepCell(engine="vectorized", **base))
-    legacy = simulate_cell(SweepCell(engine="legacy", **base))
+    vec = simulate_cell(SweepCell(backend="numpy", **base))
+    legacy = simulate_cell(SweepCell(backend="legacy", **base))
     for f in ("hits", "late", "faults", "pages_migrated", "prefetch_issued"):
         assert vec[f] == legacy[f]
     assert vec["cycles"] == pytest.approx(legacy["cycles"], rel=1e-6)
@@ -430,6 +430,19 @@ def test_cli_exits_nonzero_on_quarantine(tmp_path, monkeypatch, capsys):
         faults.reset()
     assert exc.value.code not in (0, None)
     assert "1 of 1 cells quarantined" in str(exc.value.code)
+
+
+def test_cli_backend_legacy_runs_reference_loop(tmp_path):
+    """``--backend legacy`` on the sweep CLI replays every cell on the
+    reference per-access loop, and the rows say so."""
+    from repro.uvm.sweep import main
+
+    out = str(tmp_path / "out")
+    main(["--benches", "ATAX", "--prefetchers", "none,tree",
+          "--scales", "0.25", "--backend", "legacy", "--out", out])
+    rows = read_results(out)
+    assert [(r["prefetcher"], r["backend"]) for r in rows] == [
+        ("none", "legacy"), ("tree", "legacy")]
 
 
 # ---------------------------------------------------------------------------
@@ -837,8 +850,7 @@ def test_serve_rows_slo_source_kernel(tmp_path):
     side pass only fires when a row arrives without clocks.  Both
     backends must emit bit-identical latency columns."""
     cells = [SweepCell(bench="ServeDecode", prefetcher="none", scale=0.1,
-                       window=None, device_frac=0.5, engine="vectorized",
-                       backend=be)
+                       window=None, device_frac=0.5, backend=be)
              for be in ("numpy", "pallas")]
     rows = run_sweep(cells, out_dir=str(tmp_path / "out"), workers=1)
     assert [r["backend"] for r in rows] == ["numpy", "pallas"]

@@ -4,7 +4,7 @@ Three guarantees, pinned by recorded fixtures (tests/golden/uvm_golden.json):
 
 1. the legacy per-access ``UVMSimulator`` still produces the recorded stats
    (no unintentional timing-model drift),
-2. the NumPy backend (``VectorizedUVMSimulator``) reproduces the legacy
+2. the NumPy backend (``dispatch(..., backend="numpy")``) reproduces the legacy
    engine *exactly* on every integer counter and to 1e-6 relative on the
    float accumulators (bit-equal in practice) for every
    (trace × prefetcher) cell, and
@@ -27,14 +27,14 @@ import numpy as np
 import pytest
 
 from repro.traces.trace import ROOT_PAGES, Trace, make_records
-from repro.uvm import UVMConfig, UVMSimulator, VectorizedUVMSimulator
-from repro.uvm.engine import MAX_SPAN_PAGES
+from repro.uvm import UVMConfig, UVMSimulator
 from repro.uvm.eviction import EVICTION_POLICIES
 from repro.uvm.golden import (FLOAT_FIELDS, INT_FIELDS, golden_cell,
                               golden_cell_ids, golden_cell_policy,
                               stats_to_dict)
 from repro.uvm.prefetchers import Prefetcher, TreePrefetcher
-from repro.uvm.replay_core import ReplayRequest, get_backend
+from repro.uvm.replay_core import (MAX_SPAN_PAGES, ReplayRequest, dispatch,
+                                   get_backend)
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "golden", "uvm_golden.json")
 
@@ -47,6 +47,13 @@ with open(FIXTURE) as _f:
     GOLDEN = json.load(_f)["cells"]
 
 _legacy_cache = {}
+
+
+def _numpy_replay(trace, prefetcher, config=None, **flags):
+    """One cell on the ``numpy`` backend (its chain falls back to
+    ``legacy`` for what it declines)."""
+    request = ReplayRequest(trace, prefetcher, config or UVMConfig(), **flags)
+    return dispatch(request, backend="numpy")
 
 
 def _legacy_stats(cell_id):
@@ -85,8 +92,7 @@ def test_legacy_matches_fixture(cell_id):
 def test_vectorized_matches_legacy(cell_id):
     trace, config, factory = golden_cell(cell_id)
     legacy = stats_to_dict(_legacy_stats(cell_id))
-    stats = VectorizedUVMSimulator(config, strict_checks=True).run(
-        trace, factory())
+    stats = _numpy_replay(trace, factory(), config, strict_checks=True)
     # the comparison is only meaningful if the numpy engine actually ran
     # (a silent legacy fallback would match trivially)
     assert stats.backend == "numpy"
@@ -204,8 +210,8 @@ def test_timeline_equivalence():
     trace, config, factory = golden_cell(cell_id)
     t_legacy = UVMSimulator(config, record_timeline=True).run(
         trace, factory()).timeline
-    t_vec = VectorizedUVMSimulator(config, record_timeline=True).run(
-        trace, factory()).timeline
+    t_vec = _numpy_replay(trace, factory(), config,
+                          record_timeline=True).timeline
     assert t_legacy.shape == t_vec.shape
     np.testing.assert_allclose(t_vec, t_legacy, rtol=1e-9)
 
@@ -241,8 +247,7 @@ def test_generic_prefetcher_fallback_is_exact():
     tr = _mk_trace(np.tile(np.arange(200), 4))
     s1 = stats_to_dict(UVMSimulator().run(tr, _EveryOtherPrefetcher()))
     s2 = stats_to_dict(
-        VectorizedUVMSimulator(strict_checks=True).run(
-            tr, _EveryOtherPrefetcher()))
+        _numpy_replay(tr, _EveryOtherPrefetcher(), strict_checks=True))
     _assert_stats_match(s2, s1, rel=1e-9, context="generic fallback")
 
 
@@ -250,13 +255,13 @@ def test_huge_span_falls_back_to_legacy():
     pages = np.array([0, MAX_SPAN_PAGES * 2, 0, 7], dtype=np.int64)
     tr = _mk_trace(pages)
     s1 = stats_to_dict(UVMSimulator().run(tr, TreePrefetcher()))
-    s2 = stats_to_dict(VectorizedUVMSimulator().run(tr, TreePrefetcher()))
+    s2 = stats_to_dict(_numpy_replay(tr, TreePrefetcher()))
     _assert_stats_match(s2, s1, rel=1e-9, context="span fallback")
 
 
 def test_empty_trace():
     tr = _mk_trace(np.empty(0, dtype=np.int64))
-    st = VectorizedUVMSimulator().run(tr, TreePrefetcher())
+    st = _numpy_replay(tr, TreePrefetcher())
     assert st.n_accesses == 0 and st.cycles == 0.0 and st.faults == 0
 
 
@@ -268,8 +273,7 @@ def test_empty_trace():
 @pytest.mark.parametrize("cell_id", golden_cell_ids())
 def test_invariants(cell_id):
     trace, config, factory = golden_cell(cell_id)
-    st = VectorizedUVMSimulator(config, strict_checks=True).run(
-        trace, factory())
+    st = _numpy_replay(trace, factory(), config, strict_checks=True)
     assert st.hits + st.late + st.faults == st.n_accesses
     assert 0.0 <= st.accuracy <= 1.0
     assert 0.0 <= st.coverage <= 1.0
@@ -311,7 +315,7 @@ if HAVE_HYPOTHESIS:
         """Vectorized per-level count arrays vs the legacy dict on random
         migrate/evict/fault streams: node occupancy and the on_fault extras
         (pages AND order) must agree after every operation."""
-        from repro.uvm.engine import _TreeAdapter
+        from repro.uvm.replay_core import _TreeAdapter
 
         span = 2 * ROOT_PAGES
         arrival = np.full(span, np.inf)
@@ -370,7 +374,7 @@ if HAVE_HYPOTHESIS:
             UVMSimulator(config).run(
                 tr, make_prefetcher(pf_name, tr, config)))
         s2 = stats_to_dict(
-            VectorizedUVMSimulator(config, strict_checks=True).run(
-                tr, make_prefetcher(pf_name, tr, config)))
+            _numpy_replay(tr, make_prefetcher(pf_name, tr, config), config,
+                          strict_checks=True))
         _assert_stats_match(s2, s1, rel=1e-9,
                             context=f"property [{pf_name} cap={cap}]")
